@@ -17,6 +17,13 @@ Walkthrough:
      column THROUGH the running server.
 
 Run:  python examples/serving_quickstart.py      (CPU, ~30 seconds)
+
+This walkthrough PINS THE CPU BACKEND unless ``JAX_PLATFORMS`` is already
+set: it documents the host-side API, and step 3 checks a server bucket
+bit for bit against the offline batch — which holds between the CPU's
+programs here and is not promised between differently shaped programs on
+a chip (``chip_smoke.py`` makes that comparison there, to a stated
+tolerance).  Export ``JAX_PLATFORMS=tpu`` to run it on a chip anyway.
 """
 
 from __future__ import annotations
@@ -32,7 +39,7 @@ import numpy as np
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ.setdefault("JAX_PLATFORMS", "cpu")  # see the header
 
 from sparkdl_tpu import serving  # noqa: E402
 from sparkdl_tpu.frame import DataFrame  # noqa: E402

@@ -6,8 +6,9 @@ no weights materialized, no device memory touched), ``.lower()``
 produces StableHLO on the CPU backend, and the rules read three cheap
 artifacts of the lowering:
 
-* the StableHLO text (op dtype mix, ``tf.aliasing_output`` donation
-  attrs, ``mhlo.sharding`` annotations),
+* the StableHLO text (op dtype mix, ``tf.aliasing_output`` aliases
+  and bare ``jax.buffer_donor`` marks, Shardy ``sdy.sharding``
+  annotations),
 * ``lowered.cost_analysis()`` (FLOPs / bytes accessed on the
   UNOPTIMIZED module — no XLA compile, milliseconds even for the zoo),
 * the flat input avals (shape/dtype/weak-type — the executable cache
@@ -56,6 +57,12 @@ _F32_RESULT = re.compile(r"->\s*tensor<[^>]*xf32>")
 #: f32-operand dot/conv under bf16 compute is a real upcast leak
 _OPERAND_DTYPE = re.compile(
     r":\s*\(tensor<[^>]*?x?(bf16|f16|f32|f64)>")
+#: one unusable donation in jax's warning ("... not usable:
+#: float32[2,16], int32[8].")
+_DROPPED_AVAL = re.compile(r"\b[a-z][a-z0-9_]*\[[\d,]*\]")
+#: a sharding that reached the lowered program: an argument/result
+#: attribute or a ``with_sharding_constraint`` op (Shardy dialect)
+_SHARDING_ANNOTATION = re.compile(r"sdy\.sharding\s*=|sdy\.sharding_constraint")
 
 
 @dataclass
@@ -135,7 +142,8 @@ def _lower(spec: ProgramSpec):
     with warnings.catch_warnings(record=True) as wlist:
         warnings.simplefilter("always")
         lowered = jitted.lower(*args)
-    dropped = sum(str(w.message).count("ShapedArray") for w in wlist
+    dropped = sum(len(_DROPPED_AVAL.findall(str(w.message)))
+                  for w in wlist
                   if "donated buffers were not usable" in str(w.message))
     return lowered, args, dropped
 
@@ -171,6 +179,10 @@ def audit_program(spec: ProgramSpec) -> Dict[str, Any]:
     flops = float(cost.get("flops", 0.0))
     nbytes = float(cost.get("bytes accessed", 0.0))
     aliased = text.count("tf.aliasing_output")
+    # a donated arg jax could pair only by element count (same size,
+    # other dtype/shape) is lowered as a bare donor with NO warning and
+    # left to XLA — not an established alias, so it counts as dropped
+    dropped += text.count("jax.buffer_donor")
     donated_leaves = sum(len(_tree_leaves(args[i])) for i in spec.donate)
     dtype_counts = _scan_op_dtypes(text)
     sigs = [_aval_signature(a) for a in _tree_leaves(lowered.in_avals)]
@@ -330,7 +342,7 @@ def _sharding_summary(spec: ProgramSpec, args: tuple,
         "batch_args": batch_args,
         "replicated_bytes": replicated_bytes,
         "largest_replicated_leaf_bytes": largest_leaf,
-        "annotated": text.count("mhlo.sharding"),
+        "annotated": len(_SHARDING_ANNOTATION.findall(text)),
     }
     if param_shards is not None:
         summary["param_shards"] = param_shards
@@ -356,7 +368,7 @@ def _rule_gc001(spec: ProgramSpec, record: Dict[str, Any]) -> List[Finding]:
             "GC001", spec.name, 0,
             f"donation silently dropped: {d['donated_leaves']} donated "
             f"aval(s) but only {d['aliased']} established an "
-            f"input/output alias ({d['dropped']} reported unusable by "
+            f"input/output alias ({d['dropped']} left without one by "
             f"jax) — a dtype/layout mismatch is eating the donation")]
     return []
 
@@ -387,7 +399,7 @@ def _rule_gc005(spec: ProgramSpec, record: Dict[str, Any], args: tuple,
     if summary["annotated"] == 0:
         findings.append(Finding(
             "GC005", spec.name, 0,
-            "no mhlo.sharding annotation reached the lowered program — "
+            "no sdy.sharding annotation reached the lowered program — "
             "the declared NamedShardings were lost before XLA"))
     for i, dim in summary["batch_args"]:
         for leaf in _tree_leaves(args[i]):

@@ -335,9 +335,10 @@ def generic_dispatch_specs(feature_dim: int = 16,
 #: the wide-dense proof model's shape: a (in, out) f32 kernel of 64 MB
 #: — over the 32 MB GC005 replicated budget — with a SMALL contraction
 #: dim and a WIDE output dim, so the tensor-parallel split (output
-#: columns across the model axis) leaves every output element's
-#: accumulation order untouched and sharded serving is BIT-IDENTICAL
-#: to the single-device replicated oracle (tests pin this at runtime)
+#: columns across the model axis) puts no reduction across shards: each
+#: output element stays one 128-term dot product, and at this shape the
+#: sharded rows equal the single-device replicated oracle bit for bit
+#: (tests pin it at runtime; other shapes may differ in summation order)
 WIDE_DENSE_IN = 128
 WIDE_DENSE_OUT = 131072
 
@@ -357,7 +358,7 @@ def sharded_dispatch_specs(feature_dim_in: int = WIDE_DENSE_IN,
     topology supports: ``dp1tp8`` (pure tensor parallel) and
     ``dp2tp4`` (mixed).  GC005 then verifies the claim: no replicated
     leaf above budget (the kernel now costs bytes/model_axis per
-    chip), every split dim divides, mhlo.sharding present — where the
+    chip), every split dim divides, sdy.sharding present — where the
     same program under ``shardings=("replicated", "batch")`` is the
     budget-buster negative fixture the tests pin.  The batch is
     donated (f32 in, f32 out — but note the output is WIDER than the

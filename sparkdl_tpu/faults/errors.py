@@ -1,4 +1,4 @@
-"""Injected-fault error taxonomy.
+"""Injected-fault error classes.
 
 Every exception the fault harness raises is a distinct type so the code
 under test can be asserted to ROUTE it correctly: transient faults must

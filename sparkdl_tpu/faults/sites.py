@@ -73,8 +73,6 @@ SITE_HELP = {
                      "fleet — a transient error rule drops that "
                      "arrival at the door (scored as a shed, the "
                      "scenario replay stays deterministic)"),
-    "probe.device": "__graft_entry__ device-count relay probe",
-    "bench.relay_probe": "bench.py relay profile probe",
     "io.decode": "host image decode, per row",
     "cost.attr": ("cost-ledger attribution of a settled batch or cache "
                   "hit (observability: callers degrade to an error "

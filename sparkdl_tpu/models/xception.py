@@ -21,6 +21,9 @@ from flax import linen as nn
 
 from sparkdl_tpu.models.layers import (BNAffine, SeparableConv2D,
                                        global_avg_pool)
+from sparkdl_tpu.utils.logging import get_logger
+
+logger = get_logger(__name__)
 
 # (block index, filters) of the three entry-flow residual blocks.
 _ENTRY_BLOCKS = ((2, 128), (3, 256), (4, 728))
@@ -55,8 +58,9 @@ def _pick_row_tile(h: int, w: int, channels: int):
 class Xception(nn.Module):
     """``fused_inference`` routes every separable conv through the pallas
     fused kernel (``ops/sepconv.py``) when not training: None = auto (on
-    for single-device TPU backends), True = always (CPU falls back to the
-    jax reference path — used by parity tests), False = never.  Both
+    for a TPU backend with ONE device, off — and logged — on a
+    multi-chip host), True = always (CPU falls back to the jax reference
+    path — used by parity tests), False = never.  Both
     paths declare identical variables, so weights import/persist the same
     way regardless."""
 
@@ -79,7 +83,17 @@ class Xception(nn.Module):
 
         from sparkdl_tpu.ops.sepconv import _on_tpu
 
-        return _on_tpu() and jax.device_count() == 1
+        if not _on_tpu():
+            return False
+        if jax.device_count() != 1:
+            # the engine's mesh spans every device and Mosaic refuses to
+            # partition a kernel automatically ("wrap the call in a
+            # shard_map"), so a multi-chip host serves the XLA lowering
+            logger.info("Xception: %d devices, separable convs take the "
+                        "XLA path (the Pallas kernel is single-device)",
+                        jax.device_count())
+            return False
+        return True
 
     @nn.compact
     def __call__(self, x: jnp.ndarray, train: bool = False,

@@ -7,12 +7,18 @@ compiles ``sparkdl_native.cpp`` (threaded fused JPEG/PNG decode+resize) on
 first use with the system toolchain and binds it via ctypes (no pybind11 in
 the image).  Everything degrades to the PIL path if the toolchain or
 libjpeg/libpng are unavailable — the framework never hard-requires the
-native core.
+native core; :func:`library_info` says which one a process got.
+
+The built library is keyed on the CONTENT of the source: its file name
+carries the source's sha256, so a library built from another revision of
+``sparkdl_native.cpp`` is never loaded, whatever the files' timestamps
+say (a copied tree does not preserve them).
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 from typing import List, Optional, Sequence, Tuple
@@ -26,18 +32,30 @@ logger = get_logger(__name__)
 
 _SRC = os.path.join(os.path.dirname(__file__), "sparkdl_native.cpp")
 _LIB_DIR = os.path.join(os.path.dirname(__file__), "_build")
-_LIB_PATH = os.path.join(_LIB_DIR, "libsparkdl_native.so")
 
 _lock = named_lock("native.load")
 _lib = None
+_lib_info: Optional[dict] = None   # what library_info() answers once loaded
 _load_attempted = False
 
 
-def _build() -> bool:
+def _source_digest() -> str:
+    with open(_SRC, "rb") as fh:
+        return hashlib.sha256(fh.read()).hexdigest()
+
+
+def _lib_path_for(digest: str) -> str:
+    return os.path.join(_LIB_DIR, f"libsparkdl_native-{digest[:16]}.so")
+
+
+def _build(lib_path: str) -> bool:
     os.makedirs(_LIB_DIR, exist_ok=True)
+    # build beside the target and rename: a concurrent process never
+    # loads a half-written library
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
     cmd = [
         "g++", "-O3", "-shared", "-fPIC", "-pthread", "-std=c++17",
-        _SRC, "-ljpeg", "-lpng", "-o", _LIB_PATH,
+        _SRC, "-ljpeg", "-lpng", "-o", tmp,
     ]
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True,
@@ -49,11 +67,12 @@ def _build() -> bool:
         logger.warning("native build failed; using PIL path:\n%s",
                        proc.stderr[-2000:])
         return False
+    os.replace(tmp, lib_path)
     return True
 
 
 def _load():
-    global _lib, _load_attempted
+    global _lib, _lib_info, _load_attempted
     with _lock:
         if _load_attempted:
             return _lib
@@ -61,13 +80,12 @@ def _load():
         if os.environ.get("SPARKDL_TPU_DISABLE_NATIVE"):
             logger.info("native IO disabled by SPARKDL_TPU_DISABLE_NATIVE")
             return None
-        src_mtime = os.path.getmtime(_SRC) if os.path.exists(_SRC) else 0
-        needs_build = (not os.path.exists(_LIB_PATH)
-                       or os.path.getmtime(_LIB_PATH) < src_mtime)
-        if needs_build and not _build():
+        digest = _source_digest()
+        lib_path = _lib_path_for(digest)
+        if not os.path.exists(lib_path) and not _build(lib_path):
             return None
         try:
-            lib = ctypes.CDLL(_LIB_PATH)
+            lib = ctypes.CDLL(lib_path)
         except OSError as e:
             logger.warning("native library load failed (%s); using PIL path",
                            e)
@@ -88,12 +106,23 @@ def _load():
             ctypes.POINTER(ctypes.c_uint8), ctypes.c_int,
         ]
         _lib = lib
-        logger.info("native IO core loaded (%s)", _LIB_PATH)
+        _lib_info = {"decoder": "native", "path": lib_path,
+                     "source_sha256": digest}
+        logger.info("native IO core loaded (%s)", lib_path)
         return _lib
 
 
 def native_available() -> bool:
     return _load() is not None
+
+
+def library_info() -> dict:
+    """Which decoder this process runs: ``{"decoder": "native", "path",
+    "source_sha256"}`` once the core loaded (building it if need be),
+    ``{"decoder": "pil"}`` when it could not."""
+    if _load() is None:
+        return {"decoder": "pil"}
+    return dict(_lib_info)
 
 
 def _default_threads() -> int:

@@ -18,17 +18,31 @@ dropped donation is GC001, an f32 upcast is GC002, and so on), so an
 operator reading the ``compile.invalidate`` flight event knows WHY the
 cold-start got slow again.
 
-Gate: ``SPARKDL_COMPILE_CACHE`` (the ``SPARKDL_BLACKBOX`` grammar)
-  * ``""``/``0``/``false``/``off``/``no`` — DISABLED (the default:
-    nothing about compilation changes, and the per-engine probe is one
-    module-global read).
-  * ``1``/``true``/``on``/``yes`` — enabled at the default directory
-    (``~/.cache/sparkdl_tpu/compile``).
-  * anything else — treated as the cache DIRECTORY.
+Where the cache lives, in order:
+  * ``JAX_COMPILATION_CACHE_DIR`` set — the installation PLACED the
+    cache: that directory is the cache for every entry point, JAX
+    already reads it, and no code here points JAX anywhere else (the
+    manifest, the hit/miss listener and the persist-everything
+    thresholds still apply there).  A placed directory may be shared
+    with other checkouts and other commits, so nothing in it is ever
+    deleted: drift is classified and reported, and JAX's own
+    content-addressed key is what keeps a stale executable unserved.
+  * else ``SPARKDL_COMPILE_CACHE`` (the ``SPARKDL_BLACKBOX`` grammar):
+    ``""``/``0``/``false``/``off``/``no`` — DISABLED (the library
+    default: nothing about compilation changes, and the per-engine
+    probe is one module-global read); ``1``/``true``/``on``/``yes`` —
+    enabled at :data:`DEFAULT_DIR`; anything else — the cache
+    DIRECTORY.
+  * the repo's own entry points (``chip_smoke.py``, ``bench.py``,
+    ``tools/``) call :func:`configure_default`, which falls back to
+    :data:`DEFAULT_DIR` — ONE fixed, git-ignored directory inside the
+    checkout, never a temporary name, a pid or a timestamp, so two
+    runs of one checkout always meet in the same place.
 
 Resolution is the faults-pattern process singleton: the first
 :class:`~sparkdl_tpu.parallel.engine.InferenceEngine` construction
-consults the env exactly once (:func:`ensure_from_env`, serialized
+(or an entry point's :func:`configure_default` before it) consults the
+env exactly once (:func:`ensure_from_env`, serialized
 under the configure lock) and every later engine sees the resolved
 state.  Configuration failures — unwritable directory, corrupt
 manifest, the injected ``compile.cache`` fault — degrade to DISABLED
@@ -43,10 +57,11 @@ ZERO fresh compiles (``misses == 0``) with bit-identical outputs; a
 tampered manifest fingerprint forces a purge + clean recompile instead
 of ever serving a stale executable.
 
-Sharing contract (ISSUE 14): one cache directory serves ONE
-deployment configuration.  The manifest's ``sharding_policies`` set
-accumulates every engine policy the deployment's processes note
-(restart-order-independent reuse), but a process whose FIRST policy
+Sharing contract (ISSUE 14), for a directory this module chose (not a
+placed one): one cache directory serves ONE deployment configuration.
+The manifest's ``sharding_policies`` set accumulates every engine
+policy the deployment's processes note (restart-order-independent
+reuse), but a process whose FIRST policy
 the set has never held purges the whole population — so two
 *unrelated* deployments with different sharding policies pointing at
 the same directory would purge each other's executables on every
@@ -73,9 +88,10 @@ logger = get_logger(__name__)
 __all__ = [
     "MANIFEST_NAME",
     "DEFAULT_DIR",
+    "PLACED_DIR_ENV",
     "dir_from_env",
     "configure",
-    "configure_from_env",
+    "configure_default",
     "ensure_from_env",
     "state",
     "stats",
@@ -87,8 +103,13 @@ __all__ = [
 MANIFEST_NAME = "SPARKDL_COMPILE_CACHE_MANIFEST.json"
 MANIFEST_SCHEMA = 1
 
-DEFAULT_DIR = os.path.join(os.path.expanduser("~"), ".cache",
-                           "sparkdl_tpu", "compile")
+#: the fixed in-checkout cache directory (git-ignored)
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".compile_cache")
+
+#: JAX's own variable: where it is set, the installation placed the cache
+PLACED_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
 
 _OFF = ("", "0", "false", "off", "no")
 _ON = ("1", "true", "on", "yes")
@@ -101,9 +122,17 @@ _counts = {"hits": 0, "misses": 0}
 _listener = [False]
 
 
+def _placed_dir() -> Optional[str]:
+    return os.environ.get(PLACED_DIR_ENV, "").strip() or None
+
+
 def dir_from_env() -> Optional[str]:
-    """The cache directory per the ``SPARKDL_COMPILE_CACHE`` grammar
-    (module docstring), or None when the knob is off/unset."""
+    """The cache directory the environment asks for (module docstring):
+    the placed one, else per the ``SPARKDL_COMPILE_CACHE`` grammar, else
+    None (off)."""
+    placed = _placed_dir()
+    if placed is not None:
+        return placed
     raw = os.environ.get("SPARKDL_COMPILE_CACHE", "").strip()
     low = raw.lower()
     if low in _OFF:
@@ -155,7 +184,8 @@ def _purge(dir_path: str) -> int:
 
 def _validate_manifest(dir_path: str,
                        lockfile_path: Optional[str],
-                       policy: Optional[str] = None
+                       policy: Optional[str] = None,
+                       may_purge: bool = True
                        ) -> Tuple[Dict[str, Any], List[Tuple[str, dict]]]:
     """Compare the cache directory's manifest against the live
     committed lockfile AND the process's mesh/partition-rule policy
@@ -170,7 +200,9 @@ def _validate_manifest(dir_path: str,
     instead of serving/accumulating executables compiled for a layout
     this deployment no longer uses.  ``policy=None`` (test/CLI
     configures) is a wildcard: it never invalidates a populated set.
-    Returns the state fields and the flight events to emit AFTER the
+    ``may_purge=False`` (a placed directory) classifies and reports the
+    same drift but deletes nothing: other checkouts may own what is
+    there.  Returns the state fields and the flight events to emit AFTER the
     configure lock is released (the recorder never runs under the locks
     it observes)."""
     import jax
@@ -225,17 +257,21 @@ def _validate_manifest(dir_path: str,
                     # the executables were compiled for layouts this
                     # deployment no longer uses
                     drift_rules = ["GC005"]
-            purged = _purge(dir_path)
+            if may_purge:
+                purged = _purge(dir_path)
             events.append(("compile.invalidate", {
                 "dir": dir_path, "purged_entries": purged,
                 "drift_rules": drift_rules or ["manifest"],
             }))
             logger.warning(
-                "persistent compile cache at %s invalidated: %s; purged "
-                "%d stale entries (fresh compiles ahead)", dir_path,
+                "persistent compile cache at %s invalidated: %s; %s",
+                dir_path,
                 (f"lockfile drift classified {drift_rules}"
                  if drift_rules else "unreadable/foreign manifest"),
-                purged)
+                (f"purged {purged} stale entries (fresh compiles ahead)"
+                 if may_purge else
+                 f"nothing deleted, the directory was placed by "
+                 f"{PLACED_DIR_ENV} and may be shared"))
     doc = {"schema_version": MANIFEST_SCHEMA, **env,
            "sharding_policies": policies, "programs": programs}
     tmp = manifest_path + ".tmp"
@@ -262,27 +298,34 @@ def _configure_locked(dir_path: Optional[str],
                                  List[Tuple[str, dict]]]:
     """Resolve the cache state (called under the configure lock);
     returns (state, flight events to emit after release).  Any failure
-    degrades to DISABLED — the cache must never take down serving."""
+    degrades to DISABLED — the cache must never take down serving (an
+    entry point that needs it checks for the ``None``)."""
     if dir_path is None:
         return None, []
+    placed = _placed_dir()
+    if placed is not None:
+        dir_path = placed
     try:
         # chaos hook: an injected error here is a corrupt cache
         # dir/manifest the configure path must absorb (degrade to
         # fresh compiles), never propagate into engine construction
         inject("compile.cache")
         os.makedirs(dir_path, exist_ok=True)
-        fields, events = _validate_manifest(dir_path, lockfile_path, policy)
+        fields, events = _validate_manifest(dir_path, lockfile_path, policy,
+                                            may_purge=placed is None)
         import jax
 
         jax.config.update("jax_enable_compilation_cache", True)
-        jax.config.update("jax_compilation_cache_dir", dir_path)
+        if placed is None:  # a placed directory is already JAX's own
+            jax.config.update("jax_compilation_cache_dir", dir_path)
         # cold-start elimination wants EVERY dispatch program persisted,
         # not only the slow-to-compile ones jax's defaults target
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           0.0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         _install_listener()
-        return {"dir": dir_path, **fields}, events
+        return {"dir": dir_path, "placed": placed is not None,
+                **fields}, events
     # graftlint: allow=SDL003 reason=the cache is an optimization: any configure failure (unwritable dir, corrupt manifest, injected fault) is logged and degrades to fresh compiles
     except Exception as e:  # noqa: BLE001
         logger.warning("persistent compile cache disabled: %s: %s "
@@ -309,14 +352,16 @@ def configure(dir_path: Optional[str],
     return st
 
 
-def configure_from_env() -> Optional[Dict[str, Any]]:
-    """(Re-)configure from ``SPARKDL_COMPILE_CACHE``."""
-    return configure(dir_from_env())
+def configure_default() -> Optional[Dict[str, Any]]:
+    """What the repo's own entry points call before they compile: the
+    cache the environment asks for, else the fixed in-checkout
+    :data:`DEFAULT_DIR`."""
+    return configure(dir_from_env() or DEFAULT_DIR)
 
 
 def ensure_from_env(policy: Optional[str] = None
                     ) -> Optional[Dict[str, Any]]:
-    """The per-engine probe: resolve ``SPARKDL_COMPILE_CACHE`` exactly
+    """The per-engine probe: resolve :func:`dir_from_env` exactly
     once per process (first engine construction), then one
     module-global read (plus a policy-set membership check) forever
     after.  Every engine passes its ``compile_policy()`` string: the
@@ -409,9 +454,7 @@ def _reset_for_tests() -> None:
         _state = _UNSET
         _counts["hits"] = 0
         _counts["misses"] = 0
-    try:
+    if _placed_dir() is None:
         import jax
 
         jax.config.update("jax_compilation_cache_dir", None)
-    except Exception:  # noqa: BLE001 — best-effort test cleanup
-        logger.info("compile cache reset: could not clear jax cache dir")

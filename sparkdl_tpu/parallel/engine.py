@@ -796,8 +796,8 @@ class InferenceEngine:
         # a bf16 model result upcast to f32 on device carries no extra
         # information, but doubles the D2H bytes of every gather — casting
         # host-side after the fetch is bit-identical and halves transfer
-        # (minimise host<->device traffic; D2H is the narrow direction on
-        # relayed links — PERF.md).  None = return outputs as produced.
+        # (minimise host<->device traffic).  None = return outputs as
+        # produced.
         self.output_host_dtype = (np.dtype(output_host_dtype)
                                   if output_host_dtype is not None else None)
 
@@ -822,7 +822,7 @@ class InferenceEngine:
         # — the inference analog of the train loop's steps_per_execution.
         # Identical per-batch math (lax.map is a scan, not a vmap, so
         # nothing about the batch dimension the model sees changes); wins
-        # whenever dispatch/fetch latency rivals compute (relayed links,
+        # whenever dispatch/fetch latency rivals compute (small models,
         # multi-host pods).  k=1 is the plain program.  map_batches scales
         # its in-flight window to max(1, window // k) GROUPS so grouping
         # does not silently multiply peak device residency by ~k.
@@ -873,8 +873,8 @@ class InferenceEngine:
                            float(self._sharding_stats["param_bytes_total"]))
         self.metrics.gauge("engine.param_bytes_per_chip",
                            float(self._sharding_stats["param_bytes_per_chip"]))
-        # Persistent compile cache (ISSUE 13): resolve the
-        # SPARKDL_COMPILE_CACHE knob once per process BEFORE any
+        # Persistent compile cache (ISSUE 13): resolve where it lives
+        # (compile_cache.dir_from_env) once per process BEFORE any
         # program of this engine compiles, so fleet deploys and
         # serving cold-starts across restarts reuse on-disk
         # executables keyed on the committed lockfile.  Disabled path

@@ -70,6 +70,19 @@ def get_mesh(num_devices: Optional[int] = None, model_parallel: int = 1,
     return Mesh(grid, (DATA_AXIS, MODEL_AXIS))
 
 
+def device_stamp() -> dict:
+    """The device of THIS process as JAX reports it: ``{"platform",
+    "kind", "count"}``.  Every measured line (``bench.py``,
+    ``chip_smoke.py``) carries it, so a number can never be read as a
+    device metric of a device it did not run on.  Initialises the
+    backend."""
+    import jax
+
+    devices = jax.devices()
+    return {"platform": devices[0].platform,
+            "kind": devices[0].device_kind, "count": len(devices)}
+
+
 def batch_sharding(mesh, ndim: int = 1):
     """NamedSharding that splits axis 0 (the batch) across the data axis and
     replicates everything else."""
@@ -139,7 +152,8 @@ def default_partition_rules(mesh) -> List[Tuple[str, Any]]:
     """The per-zoo-family default rule set: dense/conv ``kernel`` (and
     ``embedding``) leaves split their LAST dimension — output features /
     channels, so no cross-shard reduction enters the math and sharded
-    outputs stay bit-identical to replicated ones — across the mesh's
+    outputs match replicated ones up to the order XLA's shape-chosen
+    dot/conv emitter sums one element's products — across the mesh's
     ``model`` axis, iff that axis is >1 and the dim divides it (the
     SNIPPETS [3] divisibility fallback); everything else (biases, BN
     scales/stats, scalars) stays replicated."""

@@ -15,7 +15,7 @@ Public surface:
   into a running server.
 * ``register_serving_udf`` (``sparkdl_tpu.udf``) — expose a running
   server as a column UDF, so offline scoring shares the online queue.
-* The error taxonomy: :class:`QueueFullError` (backpressure, carries
+* The error classes: :class:`QueueFullError` (backpressure, carries
   ``retry_after_s``), :class:`DeadlineExceededError` (shed before
   dispatch), :class:`DispatchTimeoutError` (stalled model),
   :class:`ServiceUnavailableError` (shed at submit while the dispatch

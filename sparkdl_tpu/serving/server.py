@@ -157,7 +157,7 @@ def _deadline_guard(inner: Future, timeout_s: float) -> Future:
     requests ever become a measured hot spot."""
     out: Future = Future()
 
-    def _relay(f: Future) -> None:
+    def _forward(f: Future) -> None:
         timer.cancel()
         try:
             if f.cancelled():
@@ -183,7 +183,7 @@ def _deadline_guard(inner: Future, timeout_s: float) -> Future:
     timer = threading.Timer(timeout_s, _expire)
     timer.daemon = True
     timer.start()
-    inner.add_done_callback(_relay)
+    inner.add_done_callback(_forward)
     return out
 
 
@@ -771,7 +771,7 @@ class Server:
             # stored because only a SUCCESSFUL dispatch settles
             try:
                 value = f.result()
-            # graftlint: allow=SDL003 reason=the leader error is relayed to every follower via cache.fail and the caller future; re-raising in a done-callback would only hit the executor's swallow
+            # graftlint: allow=SDL003 reason=the leader error is forwarded to every follower via cache.fail and the caller future; re-raising in a done-callback would only hit the executor's swallow
             except BaseException as e:  # noqa: BLE001
                 self._cache.fail(flight, e)
                 if not out.done():
@@ -1524,7 +1524,7 @@ class HeadFanoutServer:
                 feats = f.result()
                 row = self._bank.dispatch(
                     np.asarray(feats)[None], [tenant])[0]
-            # graftlint: allow=SDL003 reason=relayed to the caller's future; raising in a done-callback would only hit the executor's swallow
+            # graftlint: allow=SDL003 reason=forwarded to the caller's future; raising in a done-callback would only hit the executor's swallow
             except BaseException as e:  # noqa: BLE001
                 if not out.done():
                     out.set_exception(e)

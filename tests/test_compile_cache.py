@@ -185,9 +185,12 @@ print(json.dumps({{"state": compile_cache.state(),
 """
 
 
-def _run_restart(cache_dir):
+def _run_restart(cache_dir, placed=False):
     env = dict(os.environ)
-    env["SPARKDL_COMPILE_CACHE"] = str(cache_dir)
+    env.pop("SPARKDL_COMPILE_CACHE", None)
+    env.pop(compile_cache.PLACED_DIR_ENV, None)
+    env[compile_cache.PLACED_DIR_ENV if placed
+        else "SPARKDL_COMPILE_CACHE"] = str(cache_dir)
     env.pop("SPARKDL_FAULTS", None)
     r = subprocess.run(
         [sys.executable, "-c", _CHILD.format(repo=_REPO)],
@@ -197,7 +200,10 @@ def _run_restart(cache_dir):
 
 
 @pytest.fixture
-def _fresh_compile_cache_state():
+def _fresh_compile_cache_state(monkeypatch):
+    # these tests choose their own directories: an installation that
+    # placed the cache would (rightly) override every one of them
+    monkeypatch.delenv(compile_cache.PLACED_DIR_ENV, raising=False)
     yield
     compile_cache._reset_for_tests()
 
@@ -239,6 +245,7 @@ def test_restart_serves_lockfile_pinned_programs_with_zero_fresh_compiles(
 
 
 def test_compile_cache_env_grammar(monkeypatch):
+    monkeypatch.delenv(compile_cache.PLACED_DIR_ENV, raising=False)
     monkeypatch.delenv("SPARKDL_COMPILE_CACHE", raising=False)
     assert compile_cache.dir_from_env() is None
     for off in ("0", "false", "off", "no"):
@@ -248,6 +255,110 @@ def test_compile_cache_env_grammar(monkeypatch):
     assert compile_cache.dir_from_env() == compile_cache.DEFAULT_DIR
     monkeypatch.setenv("SPARKDL_COMPILE_CACHE", "/somewhere/else")
     assert compile_cache.dir_from_env() == "/somewhere/else"
+    # a placed directory wins over the module's own knob, on or off
+    monkeypatch.setenv(compile_cache.PLACED_DIR_ENV, "/placed")
+    assert compile_cache.dir_from_env() == "/placed"
+    monkeypatch.setenv("SPARKDL_COMPILE_CACHE", "0")
+    assert compile_cache.dir_from_env() == "/placed"
+
+
+def test_default_dir_is_fixed_inside_the_checkout():
+    """The fallback directory the entry points share: inside the
+    checkout, git-ignored, and a constant — two runs of one checkout
+    meet in it (a name made from a pid, the time or mkdtemp never
+    hits)."""
+    assert compile_cache.DEFAULT_DIR == os.path.join(_REPO,
+                                                     ".compile_cache")
+    ignored = open(os.path.join(_REPO, ".gitignore")).read().split()
+    assert ".compile_cache/" in ignored
+
+
+def test_configure_default_uses_the_fixed_dir_when_nothing_is_placed(
+        monkeypatch, tmp_path, _fresh_compile_cache_state):
+    import jax
+
+    monkeypatch.delenv("SPARKDL_COMPILE_CACHE", raising=False)
+    fixed = str(tmp_path / "fixed")
+    monkeypatch.setattr(compile_cache, "DEFAULT_DIR", fixed)
+    st = compile_cache.configure_default()
+    assert st["dir"] == fixed and st["placed"] is False
+    assert jax.config.jax_compilation_cache_dir == fixed
+    # the module's own knob still names a directory for entry points
+    monkeypatch.setenv("SPARKDL_COMPILE_CACHE", str(tmp_path / "knob"))
+    assert compile_cache.configure_default()["dir"] == str(
+        tmp_path / "knob")
+
+
+def test_placed_dir_is_the_cache_and_jax_is_pointed_nowhere_else(
+        monkeypatch, tmp_path, _fresh_compile_cache_state):
+    """``JAX_COMPILATION_CACHE_DIR`` set: every way in — the engine
+    probe, an entry point, an explicit ``configure(other)`` — resolves
+    to that directory, and no ``jax.config.update`` names a cache
+    directory at all (JAX read the variable itself)."""
+    import jax
+
+    placed = str(tmp_path / "placed")
+    monkeypatch.setenv(compile_cache.PLACED_DIR_ENV, placed)
+    updates = []
+    real_update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update",
+        lambda k, v: (updates.append(k), real_update(k, v))[1])
+    for way in (compile_cache.configure_default,
+                lambda: compile_cache.configure(str(tmp_path / "other"))):
+        st = way()
+        assert st["dir"] == placed and st["placed"] is True
+    compile_cache._reset_for_tests()
+    assert compile_cache.ensure_from_env(policy="mesh=1x1")["dir"] == placed
+    assert "jax_compilation_cache_dir" not in updates
+    assert os.path.isfile(os.path.join(placed, compile_cache.MANIFEST_NAME))
+    assert not (tmp_path / "other").exists()
+    compile_cache._reset_for_tests()
+    assert "jax_compilation_cache_dir" not in updates
+
+
+def test_placed_dir_is_never_purged(monkeypatch, tmp_path,
+                                    _fresh_compile_cache_state):
+    """A placed directory may be shared between the parent commit and
+    the change: lockfile or policy drift is classified and reported
+    exactly as in a chosen directory, but nothing in it is deleted —
+    where the same drift in a directory the module chose purges."""
+    def populate(d):
+        st = compile_cache.configure(str(d), policy="mesh=1x1")
+        assert st["invalidated"] is False
+        (d / "jit_other_checkout-entry").write_bytes(b"executable")
+        manifest = d / compile_cache.MANIFEST_NAME
+        doc = json.loads(manifest.read_text())
+        name = sorted(doc["programs"])[0]
+        doc["programs"][name]["fingerprint"] = "0" * 64
+        manifest.write_text(json.dumps(doc))
+
+    chosen = tmp_path / "chosen"
+    populate(chosen)
+    st = compile_cache.configure(str(chosen), policy="mesh=1x1")
+    assert st["invalidated"] and st["purged_entries"] == 1
+    assert not (chosen / "jit_other_checkout-entry").exists()
+
+    placed = tmp_path / "placed"
+    monkeypatch.setenv(compile_cache.PLACED_DIR_ENV, str(placed))
+    populate(placed)
+    for policy in ("mesh=1x1", "mesh=2x2|params=abc"):  # lockfile, policy
+        st = compile_cache.configure(str(placed), policy=policy)
+        assert st["invalidated"] and st["drift_rules"]
+        assert st["purged_entries"] == 0
+        assert (placed / "jit_other_checkout-entry").exists()
+
+
+def test_placed_restart_hits_without_fresh_compiles(tmp_path):
+    """The cross-process proof again, through the placed variable
+    alone: a second process finds what the first compiled."""
+    placed = tmp_path / "placed"
+    a = _run_restart(placed, placed=True)
+    assert a["state"]["dir"] == str(placed) and a["state"]["placed"]
+    assert a["stats"]["misses"] > 0 and a["stats"]["hits"] == 0
+    b = _run_restart(placed, placed=True)
+    assert b["stats"]["misses"] == 0 and b["stats"]["hits"] > 0, b
+    assert b["digest"] == a["digest"]
 
 
 def test_compile_cache_disabled_by_default(monkeypatch,

@@ -11,8 +11,7 @@ contracts with the :mod:`sparkdl_tpu.faults` harness:
   failing stage + piece, clean drain (no wedged threads/queues);
 * serving: queue-full storms, breaker-open shed with ``retry_after``,
   ``health()`` ready/degraded/closed transitions, wedged-model drain;
-* host I/O decode errors ride the drop-to-null contract; the device
-  probe falls back fast on a hanging relay;
+* host I/O decode errors ride the drop-to-null contract;
 * the chaos e2e acceptance run and the kill-the-driver bench-artifact
   test (SIGKILL mid-run -> valid JSONL for every completed config).
 """
@@ -451,7 +450,7 @@ def test_close_drain_returns_within_timeout_with_wedged_model(model):
     _no_stack_threads(("sparkdl-serving",))
 
 
-# -- host I/O + probe sites ------------------------------------------------
+# -- host I/O site ---------------------------------------------------------
 
 def test_io_decode_fault_rides_drop_to_null(fixture_images):
     from sparkdl_tpu.image.io import decodeResizeBatch
@@ -466,18 +465,6 @@ def test_io_decode_fault_rides_drop_to_null(fixture_images):
     assert not out[1].any() and out[0].any() and out[2].any()
     out2, ok2 = decodeResizeBatch(blobs, 16, 16)  # plan gone: all decode
     assert list(ok2) == [True, True, True]
-
-
-def test_probe_device_fault_falls_back_fast():
-    sys.path.insert(0, REPO)
-    try:
-        import __graft_entry__
-    finally:
-        sys.path.remove(REPO)
-    with faults.active(FaultPlan.parse("probe.device:error:every=1")):
-        t0 = time.perf_counter()
-        assert __graft_entry__._probe_local_device_count() is None
-        assert time.perf_counter() - t0 < 1.0  # no child, no 120s wait
 
 
 def test_bench_lines_stamp_faults_spec(monkeypatch):
@@ -569,27 +556,27 @@ def test_chaos_e2e_serving_plus_map_batches(model):
 def test_bench_artifact_survives_sigkill(tmp_path):
     """ISSUE 4 acceptance: SIGKILL bench.py mid-run; the incremental
     fsync'd JSONL artifact still holds a valid line for every completed
-    config — an empty BENCH_*.json is no longer possible for any run
-    that completed at least one config.  The relay is killed via the
-    ``bench.relay_probe`` fault site, which also drives the real
-    dead-relay path (chipless configs first)."""
+    config — a driver that lost its stdout capture to the kill still has
+    every config that finished.  Two chip-free configs, so the run needs
+    no accelerator: the kill lands once the first one's line is on disk,
+    while the second (a ~15 s virtual day) is still under way."""
     artifact = tmp_path / "bench_lines.jsonl"
     env = dict(os.environ)
     env.update({
         "JAX_PLATFORMS": "cpu",
-        "SPARKDL_BENCH_CONFIGS": "pipeline,serving",
+        "SPARKDL_BENCH_CONFIGS": "pipeline,cache",
         "SPARKDL_BENCH_ARTIFACT": str(artifact),
         "SPARKDL_BENCH_TRACE": "0",
-        "SPARKDL_FAULTS": "bench.relay_probe:error:every=1",
-        "SPARKDL_RELAY_CACHE": str(tmp_path / "relay.json"),
+        "SPARKDL_FAULTS": "seed=5;io.decode:error:at=1000000",
     })
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    env["SPARKDL_COMPILE_CACHE"] = str(tmp_path / "cc")
     proc = subprocess.Popen(
         [sys.executable, os.path.join(REPO, "bench.py")],
         cwd=REPO, env=env, stdout=subprocess.DEVNULL,
         stderr=subprocess.DEVNULL, start_new_session=True)
     try:
         # wait for the first COMPLETED config line, then kill mid-run
-        # (the serving config is underway or about to start)
         deadline = time.monotonic() + 240
         seen_pipeline = False
         while time.monotonic() < deadline and not seen_pipeline:
@@ -611,9 +598,9 @@ def test_bench_artifact_survives_sigkill(tmp_path):
     lines = artifact.read_text().splitlines()
     assert lines, "artifact empty — the crash-safe contract failed"
     recs = [json.loads(ln) for ln in lines]  # every line is valid JSON
-    # the injected dead relay left explicit diagnostics, not silence
-    assert any(r.get("config") == "relay" and "error" in r for r in recs)
-    # and the completed config's full record survived the SIGKILL
+    # the completed config's full record survived the SIGKILL, stamped
+    # with the chaos plan it ran under and the device that measured it
     pipeline = [r for r in recs if r.get("config") == "pipeline"]
     assert pipeline and "value" in pipeline[0]
-    assert pipeline[0]["faults"].endswith("bench.relay_probe:error:every=1")
+    assert pipeline[0]["faults"].endswith("io.decode:error:at=1000000")
+    assert pipeline[0]["device"]["platform"] == "cpu"

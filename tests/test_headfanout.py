@@ -262,6 +262,23 @@ def test_stacked_bank_evicts_departed_tenant():
 
 
 # -- degraded modes ---------------------------------------------------------
+def _head_oracle(head, feats):
+    """The per-tenant oracle for one head: ``dense_head_row`` on ONE
+    unbatched row through its own jit — like :func:`_oracle`, never the
+    fan-out pipeline.  Compiled, not eager, and that is the contract:
+    the bit-identity ``dense_head_row`` promises is between COMPILED
+    programs (vmapped bank vs unbatched oracle share one reduction
+    order).  Evaluated eagerly, op by op, each of the K=16 products is
+    rounded to f32 before it is summed, while under the installed XLA
+    every compiled form fuses the multiply into the accumulation and
+    keeps it unrounded — the two differ in the last bit (one f32 ulp on
+    two of four outputs here), in the oracle, not in the bank."""
+    import jax
+
+    # no donation: the caller's head and feature row are reused
+    return np.asarray(jax.jit(dense_head_row, donate_argnums=())(head, feats))
+
+
 def test_indivisible_head_falls_back_per_tenant_not_crash():
     """A head whose pytree cannot stack with the bank flips the bank to
     per-tenant fallback: every tenant (old shape and new) keeps serving
@@ -280,10 +297,10 @@ def test_indivisible_head_falls_back_per_tenant_not_crash():
     feats = np.random.default_rng(3).normal(
         size=(D_FEAT,)).astype(np.float32)
     got_a = np.asarray(bank.dispatch(feats[None], ["a"]))[0]
-    ref_a = np.asarray(dense_head_row(h0, feats))
+    ref_a = _head_oracle(h0, feats)
     assert got_a.tobytes() == ref_a.tobytes()
     got_w = np.asarray(bank.dispatch(feats[None], ["weird"]))[0]
-    ref_w = np.asarray(dense_head_row(odd, feats))
+    ref_w = _head_oracle(odd, feats)
     assert got_w.shape == (CLASSES + 3,)
     assert got_w.tobytes() == ref_w.tobytes()
 
@@ -305,8 +322,7 @@ def test_oversized_bank_falls_back_within_budget():
         size=(2, D_FEAT)).astype(np.float32)
     out = bank.dispatch(feats, ["a", "c"])
     for i, t in enumerate(("a", "c")):
-        ref = np.asarray(dense_head_row(_head({"a": 1, "c": 3}[t]),
-                                        feats[i]))
+        ref = _head_oracle(_head({"a": 1, "c": 3}[t]), feats[i])
         assert np.asarray(out[i]).tobytes() == ref.tobytes()
 
 

@@ -9,8 +9,11 @@ The contract under test, end to end:
   iff the axis is >1 and the dim divides (the divisibility fallback),
   collapsing to the classic replicate-everything layout otherwise —
   byte-identical programs on every model-axis-1 mesh;
-* sharded outputs are BIT-IDENTICAL to the replicated oracle on the
-  same mesh (the split rides output dims, no cross-shard reductions);
+* sharded outputs equal the replicated oracle on the same mesh up to
+  the order in which one output element's products are summed (the
+  split rides output dims, so no reduction crosses shards; XLA still
+  picks its dot emitter by the per-shard shape — bit-identical where
+  the emitters coincide, a stated K*eps bound where they do not);
 * graftcheck GC005 proves the HBM claim chip-free: a synthetic
   wide-dense model whose 64 MB kernel busts the 32 MB replicated-param
   budget on a model-axis mesh audits CLEAN once sharded by the default
@@ -116,7 +119,7 @@ def test_resolve_collapses_replicated_on_model_axis_1():
 
 
 # ---------------------------------------------------------------------------
-# engine parity: sharded == replicated, bit for bit
+# engine parity: sharded == replicated (bit for bit at these shapes)
 # ---------------------------------------------------------------------------
 
 def test_engine_sharded_vs_replicated_bit_identical_tp8():
@@ -172,8 +175,9 @@ def test_engine_explicit_param_shardings_and_grouped_dispatch():
 
 def test_server_sharded_parity_dp2tp4():
     """The serving path end to end on a mixed dp2 x tp4 mesh: sharded
-    vs replicated servers on the SAME mesh serve bit-identical rows,
-    and varz reports the layout."""
+    vs replicated servers on the SAME mesh serve the same rows up to
+    summation order (tolerance and its reason below), and varz reports
+    the layout."""
     from sparkdl_tpu.serving.server import Server
 
     rng = np.random.default_rng(3)
@@ -191,7 +195,25 @@ def test_server_sharded_parity_dp2tp4():
 
     tp_outs, tp_info = run(mesh_lib.default_partition_rules)
     rep_outs, rep_info = run(None)
-    assert all(np.array_equal(a, b) for a, b in zip(tp_outs, rep_outs))
+    # Equal up to summation order, not bit for bit.  The split rides the
+    # kernel's OUTPUT dim, so every output element is still ONE K=8-term
+    # dot product and no reduction crosses shards; but the per-shard dot
+    # is (rows, 8) x (8, 2) where the replicated one is (rows, 8) x
+    # (8, 8), and XLA picks its dot emitter — and with it the order in
+    # which the K products are summed and fused — by shape.  On this
+    # backend the 4-row bucket (2 rows per data shard) differs in the
+    # last bits (<= 6e-7 on the dot, <= 3.3e-7 after tanh, values O(1));
+    # the 8-row bucket coincides.  Two summation orders of a K-term f32
+    # dot differ by at most 2*gamma_K*sum|x_i*w_i| ~= K*eps*sum|x_i*w_i|;
+    # the bias add and the 1-Lipschitz tanh add a few ulp at |y| <= 1.
+    # A real precision drop (a bf16 operand) is ~1e-2, four orders above.
+    eps = np.finfo(np.float32).eps
+    k_terms = v["dense"]["kernel"].shape[0]
+    s_max = max(float((np.abs(r) @ np.abs(v["dense"]["kernel"])).max())
+                for r in rows)
+    atol = k_terms * eps * s_max + 4 * eps
+    for a, b in zip(tp_outs, rep_outs):
+        np.testing.assert_allclose(a, b, rtol=0, atol=atol)
     assert tp_info["sharded"] and not rep_info["sharded"]
     assert tp_info["mesh_shape"] == {"data": 2, "model": 4}
     assert (tp_info["param_bytes_per_chip"]
@@ -537,8 +559,11 @@ def test_gc005_unknown_axis_in_declaration_fires():
 # compile-cache manifest carries the sharding policy
 # ---------------------------------------------------------------------------
 
-def test_compile_cache_policy_flip_purges_classified_gc005(tmp_path):
+def test_compile_cache_policy_flip_purges_classified_gc005(tmp_path,
+                                                           monkeypatch):
     from sparkdl_tpu.parallel import compile_cache
+
+    monkeypatch.delenv(compile_cache.PLACED_DIR_ENV, raising=False)
 
     d = str(tmp_path / "cc")
     rng = np.random.default_rng(6)
@@ -568,7 +593,8 @@ def test_compile_cache_policy_flip_purges_classified_gc005(tmp_path):
         compile_cache._reset_for_tests()
 
 
-def test_compile_cache_policy_set_is_order_independent(tmp_path):
+def test_compile_cache_policy_set_is_order_independent(tmp_path,
+                                                       monkeypatch):
     """A deployment whose engines use SEVERAL policies (a fleet mixing
     sharded and replicated entries) must reuse across restarts no
     matter which engine constructs first: every engine's policy joins
@@ -577,6 +603,7 @@ def test_compile_cache_policy_set_is_order_independent(tmp_path):
     used purges."""
     from sparkdl_tpu.parallel import compile_cache
 
+    monkeypatch.delenv(compile_cache.PLACED_DIR_ENV, raising=False)
     d = str(tmp_path / "cc")
     a, b, c = ("mesh=1x8|params=aaa", "mesh=8x1|params=replicated",
                "mesh=2x4|params=ccc")

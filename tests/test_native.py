@@ -7,6 +7,8 @@ produce identical-shape results when the native core is unavailable.
 """
 
 import io as _io
+import os
+import shutil
 
 import numpy as np
 import pytest
@@ -95,3 +97,70 @@ def test_files_to_model_batch(fixture_images):
     out, ok = filesToModelBatch(paths, 32, 32)
     assert out.shape == (len(paths), 32, 32, 3)
     assert ok.tolist() == [True] * 3 + [False, False]
+
+
+# -- the loader: which library a process gets ------------------------------
+
+@pytest.fixture
+def fresh_loader(monkeypatch, tmp_path):
+    """The loader over its own copy of the source and an empty build
+    dir, with no memory of an earlier load; returns the source path and
+    the list of library paths ``_build`` was asked for."""
+    src = tmp_path / "sparkdl_native.cpp"
+    shutil.copy(native._SRC, src)
+    monkeypatch.setattr(native, "_SRC", str(src))
+    monkeypatch.setattr(native, "_LIB_DIR", str(tmp_path / "_build"))
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_lib_info", None)
+    monkeypatch.delenv("SPARKDL_TPU_DISABLE_NATIVE", raising=False)
+    built = []
+    real_build = native._build
+    monkeypatch.setattr(native, "_build",
+                        lambda path: (built.append(path),
+                                      real_build(path))[1])
+
+    def load():
+        monkeypatch.setattr(native, "_load_attempted", False)
+        return native.library_info()
+
+    return src, built, load
+
+
+@needs_native
+def test_loader_rebuilds_on_source_content_not_mtime(fresh_loader):
+    """A copied tree keeps no timestamps, so the rebuild is keyed on what
+    the source SAYS: the library's name carries the source's sha256.  An
+    unchanged source never rebuilds, however old the library looks; an
+    edited source never loads the library built from the old text,
+    however new that one looks."""
+    src, built, load = fresh_loader
+    first = load()
+    assert first["decoder"] == "native" and built == [first["path"]]
+    assert first["source_sha256"][:16] in os.path.basename(first["path"])
+    # the library looks older than its source: still the right one
+    os.utime(first["path"], (1, 1))
+    assert load() == first and len(built) == 1
+    # the source changes, and the stale library looks NEWER than it
+    with open(src, "a") as fh:
+        fh.write("\n// a later revision\n")
+    os.utime(src, (2, 2))
+    os.utime(first["path"], None)
+    second = load()
+    assert second["decoder"] == "native"
+    assert second["path"] != first["path"] and built[-1] == second["path"]
+    assert second["source_sha256"] != first["source_sha256"]
+    # no half-written build product is left beside the libraries
+    assert sorted(os.listdir(os.path.dirname(second["path"]))) == sorted(
+        os.path.basename(p) for p in (first["path"], second["path"]))
+
+
+def test_loader_reports_pil_when_the_core_cannot_build(fresh_loader,
+                                                       monkeypatch):
+    """The fallback stays, but it is visible: a toolchain failure is a
+    ``{"decoder": "pil"}`` answer, which chip_smoke.py treats as a
+    failed phase."""
+    _, built, load = fresh_loader
+    monkeypatch.setattr(native, "_build",
+                        lambda path: (built.append(path), False)[1])
+    assert load() == {"decoder": "pil"}
+    assert len(built) == 1

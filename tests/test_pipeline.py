@@ -257,7 +257,7 @@ def test_stage_metrics_recorded(setup):
 
 def test_synthetic_overlap_benchmark_speedup():
     """THE tier-1 overlap contract: with a simulated 100 ms blocking
-    dispatch (the relayed-link regime) and 100 ms host prepare per batch,
+    dispatch (a slow-device regime) and 100 ms host prepare per batch,
     the pipelined path must be >= 1.5x the serial path on the CPU backend
     (ideal is 2x; the bound leaves headroom for thread scheduling noise).
     Deterministic: sleep-dominated, parity-checked inside."""

@@ -19,9 +19,18 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 @pytest.fixture(scope="module")
 def wheel_path(tmp_path_factory):
+    # build from a COPY of the checkout: setuptools leaves build/ and an
+    # egg-info beside the sources it builds, and build/lib/sparkdl_tpu
+    # is a second copy of the package that a stray PYTHONPATH imports
+    import shutil
+
     d = tmp_path_factory.mktemp("wheel")
+    src = d / "src"
+    shutil.copytree(REPO, src, ignore=shutil.ignore_patterns(
+        ".git", "build", "*.egg-info", "__pycache__", "_build",
+        "artifacts", ".compile_cache", "chiprun_out", ".pytest_cache"))
     proc = subprocess.run(
-        [sys.executable, "-m", "pip", "wheel", REPO, "--no-deps",
+        [sys.executable, "-m", "pip", "wheel", str(src), "--no-deps",
          "--no-build-isolation", "-w", str(d)],
         capture_output=True, text=True, timeout=300)
     assert proc.returncode == 0, proc.stderr[-2000:]

@@ -1,6 +1,6 @@
 """Capture an xplane device trace of one zoo-model forward (the round-4
-committed artifact's recipe, parameterized) — run when the chip is
-reachable to refresh `artifacts/profile_r*/`.
+committed artifact's recipe, parameterized) — run on the chip machine
+to refresh `artifacts/profile_r*/`; without an accelerator it fails.
 
 Usage: python tools/capture_profile.py [model] [out_dir] [batch]
        (defaults: InceptionV3 artifacts/profile_r05 128)
@@ -33,8 +33,11 @@ def main() -> None:
     import jax
 
     import bench
+    from sparkdl_tpu.parallel import compile_cache
     from sparkdl_tpu.utils.metrics import Metrics
 
+    compile_cache.configure_default()
+    device = bench.require_accelerator()  # a CPU trace is not a profile
     fn, variables, (h, w) = bench._zoo_fn(model, featurize=True)
     # no donation: the same device batch is re-dispatched every profile
     # iteration below
@@ -55,7 +58,7 @@ def main() -> None:
     print(json.dumps({
         "model": model, "batch": batch, "trace_dir": trace_dir,
         "in_trace_wall_s": round(wall, 4),
-        "implied_img_s": round(batch / wall, 1)}))
+        "implied_img_s": round(batch / wall, 1), "device": device}))
 
 
 if __name__ == "__main__":

@@ -1,11 +1,12 @@
 """Crash-safe driver dryrun: ``__graft_entry__`` with incremental JSONL.
 
-Round-5's dead relay left ``MULTICHIP_r05.json`` as a bare rc=124 — the
-driver's only record of the dryrun was its stdout capture, so a hang or
-kill mid-run erased every stage that HAD completed.  This CLI runs the
-same entry points (``entry()`` single-chip compile check,
-``dryrun_multichip(n)`` full sharded train/score step) but appends one
-fsync'd JSONL record per stage to an on-disk artifact as it goes —
+A driver whose only record of the dryrun is its stdout capture loses
+every stage that HAD completed when the run hangs or is killed.  This
+CLI runs the same entry points (``dryrun_multichip(n)`` — the sharded
+train/score step on n VIRTUAL CPU devices, in a CPU-pinned child, run
+FIRST so that no child starts once this process holds a backend — then
+the ``entry()`` compile check on this process's default device) and
+appends one fsync'd JSONL record per stage to an on-disk artifact as it goes —
 ``started`` / ``ok`` / ``error`` with wall seconds — so a SIGKILL at any
 instant leaves a valid, stage-resolved partial record (atexit cannot
 survive SIGKILL; incremental flush can).
@@ -87,7 +88,9 @@ def main(argv=None) -> int:
     log = StageLog(args.artifact)
     import __graft_entry__
 
-    ok = True
+    ok = _run_stage(
+        log, f"dryrun_multichip[{args.devices}]",
+        lambda: __graft_entry__.dryrun_multichip(args.devices))
     if not args.skip_entry:
         def run_entry():
             import jax
@@ -96,13 +99,12 @@ def main(argv=None) -> int:
             fn, (variables, batch) = __graft_entry__.entry()
             # no donation: one-shot smoke dispatch of caller-owned arrays
             out = jax.jit(fn, donate_argnums=())(variables, batch)
-            return {"output_shape": list(np.asarray(out).shape)}
+            from sparkdl_tpu.parallel.mesh import device_stamp
+
+            return {"output_shape": list(np.asarray(out).shape),
+                    "device": device_stamp()}
 
         ok = _run_stage(log, "entry", run_entry) and ok
-
-    ok = _run_stage(
-        log, f"dryrun_multichip[{args.devices}]",
-        lambda: __graft_entry__.dryrun_multichip(args.devices)) and ok
     log.write(stage="summary", status="ok" if ok else "error")
     return 0 if ok else 1
 

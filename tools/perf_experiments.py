@@ -8,8 +8,10 @@ Levers measured (results recorded in PERF.md):
   * ResNet50 fused downsample shortcut (SPARKDL_RN_FUSED_SHORTCUT=1 vs 0)
   * MobileNetV2 fused inverted-residual tail (SPARKDL_MNV2_FUSED=1 vs 0)
 
-Method: ``bench.measure_scan`` (steps-in-one-program, relay-artifact-free);
-models build fresh per run so the env knobs bind at build time.
+Method: ``bench.measure_scan`` (steps-in-one-program); models build
+fresh per run so the env knobs bind at build time.  Everything runs in
+this one process; without an accelerator it fails, and every line is
+stamped with the device that measured it.
 
 Run: python tools/perf_experiments.py [xception|inception|resnet|mobilenet|batch]...
 """
@@ -40,7 +42,8 @@ def run(name, featurize, batch, steps, **env):
             else:
                 os.environ[k] = v
     print(json.dumps({"model": name, "batch": batch, "env": env,
-                      "ips": round(ips, 1)}), flush=True)
+                      "ips": round(ips, 1),
+                      "device": bench.device_stamp()}), flush=True)
     return ips
 
 
@@ -86,6 +89,10 @@ def inception_batch_sweep(steps=40):
 
 
 if __name__ == "__main__":
+    from sparkdl_tpu.parallel import compile_cache
+
+    compile_cache.configure_default()
+    bench.require_accelerator()
     wanted = sys.argv[1:] or ["xception", "inception", "batch"]
     if "xception" in wanted:
         xception_ab()
