@@ -18,8 +18,9 @@ this process, one after another (configs 1-5, "serving", "fleet").  The
 chip-free configs ("pipeline", "streaming", "cache", "ragged", "twin",
 "headfanout" — a deterministic sleep stands in for their device, and
 their metric names say so) run in children pinned to ``JAX_PLATFORMS=
-cpu``, and all of them run FIRST: no child is ever started once this
-process has initialised an accelerator backend
+cpu``, and all of them run FIRST: nothing this process does before its
+first chip config initialises a JAX backend (configuring the compile
+cache included), and no child is ever started once a chip config has
 (``_run_json_subprocess`` refuses).
 
 Failure policy: a measurement path that finds no accelerator FAILS — no
@@ -521,26 +522,24 @@ class NoAcceleratorError(RuntimeError):
     """A config that measures the chip found none."""
 
 
+#: Set once a chip config has started in this process: from then on it
+#: holds (or has tried to take) the chip, and starts no child.  Nothing
+#: before that point may initialise a JAX backend.
+_CHIP_CONFIGS_STARTED = [False]
+
+
 def require_accelerator():
     """Gate of every chip config: a device metric is never measured on,
     or printed from, the CPU backend.  ``device_stamp`` initialises the
-    backend, which is why main() starts every chip-free child first."""
+    backend, which is why main() starts every chip-free child first and
+    why this closes the door on children before it asks."""
+    _CHIP_CONFIGS_STARTED[0] = True
     stamp = device_stamp()
     if stamp["platform"] == "cpu":
         raise NoAcceleratorError(
             f"JAX reports {stamp['count']} {stamp['kind']!r} device(s) on "
             f"platform 'cpu': no accelerator, so no device metric")
     return stamp
-
-
-def _holds_accelerator() -> bool:
-    """Has THIS process initialised an accelerator backend?  From then
-    on the chip is taken, and a child that needs it fails or hangs."""
-    import jax
-    from jax._src import xla_bridge
-
-    return (xla_bridge.backends_are_initialized()
-            and jax.default_backend() != "cpu")
 
 
 #: appended to every child's code: stamp the child's own device and
@@ -557,7 +556,7 @@ def _run_json_subprocess(code: str, timeout_s: int, env=None):
     ``out``) in a child Python pinned to the CPU backend; return the
     dict, stamped with the child's device.
 
-    Refuses once this process holds an accelerator backend: main() runs
+    Refuses once a chip config has started in this process: main() runs
     every child first.
 
     Popen + bounded reap, not subprocess.run: run()'s post-timeout
@@ -567,10 +566,10 @@ def _run_json_subprocess(code: str, timeout_s: int, env=None):
     init eventually) and the timeout propagates."""
     import subprocess
 
-    if _holds_accelerator():
+    if _CHIP_CONFIGS_STARTED[0]:
         raise RuntimeError(
-            "bench child refused: this process already holds an "
-            "accelerator backend (chip-free configs run first)")
+            "bench child refused: a chip config has started in this "
+            "process, which holds the chip (chip-free configs run first)")
     env = dict(os.environ if env is None else env)
     env["JAX_PLATFORMS"] = "cpu"
     # the child imports the package beside this file, whatever the cwd

@@ -53,32 +53,35 @@ PLATFORM = "tpu"
 SEED = 0
 
 # -- tolerances, each with its reason --------------------------------------
-#: One bf16 rounding step, relative to the feature scale.  Two programs
-#: that compute the same f32 math in differently shaped pieces — a server
-#: bucket of 8 against a batch of 64, a weight shard against the whole
-#: kernel, a quarter of a batch against all of it — keep every contraction
-#: whole, so they are equal up to the order XLA's shape-chosen emitters
-#: sum one element's products in: not bit for bit.  On the TPU that
-#: last-bit difference does not stay in the last bit: the default matmul
-#: precision rounds each conv's f32 operands to bf16, and an activation
-#: that differs in its last f32 bit can land on the other side of that
-#: rounding (a 2^-8 relative step) in the next layer.  A few such flips
-#: per layer, through 50-100 layers, measured 1e-4 to 8e-4 of the feature
-#: scale on a v5e (PR 21); no element may be off by more than one whole
-#: step.  A wrong weight, a missing all-gather or a transposed image is
-#: O(1).
-BF16_STEP = 2.0 ** -8
+#: Two programs that compute the same f32 math in differently shaped
+#: pieces — a server bucket of 8 against a batch of 64, a weight shard
+#: against the whole kernel, a quarter of a batch against all of it — keep
+#: every contraction whole, so they are equal up to the order XLA's
+#: shape-chosen emitters sum one element's products in: not bit for bit.
+#: On the TPU that last-bit difference does not stay in the last bit: the
+#: default matmul precision rounds each conv's f32 operands to bf16, and
+#: an activation that differs in its last f32 bit can land on the other
+#: side of that rounding (a 2^-8 relative step) in the next layer.  A few
+#: such flips per layer, through 50-100 layers, measured 3.3e-4 (serving)
+#: and 8.2e-4 (dp2 x tp2) of the feature scale on a v5e (my chip runs,
+#: PR 21).  The bound is 2.4x the worst of those and has to stay BELOW
+#: what running the whole model in bf16 costs — 3.6e-3 for ResNet50 in
+#: the same run — or a path that silently computed in bf16 would pass.  A
+#: wrong weight, a missing all-gather or a transposed image is O(1).
 #: A server bucket (8/16/32 rows) vs the transform's batch (64 rows).
-SERVING_VS_BATCH_RTOL = BF16_STEP
+SERVING_VS_BATCH_RTOL = 2e-3
 #: Sharded (dp2 x tp2, default partition rules: OUTPUT dims split, no
 #: reduction crosses shards) or data-parallel (dp4) vs one device — the
 #: words and the reasoning of tests/test_mesh_shard.py::
 #: test_server_sharded_parity_dp2tp4, compounded through ResNet50's 53
 #: convolutions.
-SHARDED_VS_ONE_RTOL = BF16_STEP
-#: Data-parallel training all-reduces per-shard gradient sums: one more
-#: reassociation per step on top of the forward's.
-TRAIN_DP_VS_ONE_RTOL = BF16_STEP
+SHARDED_VS_ONE_RTOL = 2e-3
+#: Data-parallel training of a linear head: one matmul deep, so nothing
+#: compounds.  All that differs from one device is that each step's
+#: gradient is an all-reduce of four per-shard sums, i.e. one
+#: reassociation of an f32 sum per step; measured 0.0 (weights) and 1e-7
+#: (losses) on four v5e chips (my chip run, PR 21).  100x that.
+TRAIN_DP_VS_ONE_RTOL = 1e-5
 #: The kernel path STORES every separable conv's output in bf16 (the
 #: kernels' storage dtype) where the XLA lowering keeps f32: a 2^-9
 #: relative rounding per layer over 34 chained layers, on every element.
@@ -168,33 +171,27 @@ def make_images(root: str, hw: int, n: int) -> str:
 
 def seed_zoo_weights(name: str) -> None:
     """Serve the spec's seeded ``init_variables`` for ``name`` in this
-    process.  The zoo stages resolve weights through one process-wide
-    cache (normally filled by a Keras import, which needs a weights file
-    or the network); filling it first is how the tests inject models
-    too."""
+    process, where a Keras import (a weights file, or the network) would
+    otherwise fill the zoo's weight cache."""
     import jax
 
-    from sparkdl_tpu.models import get_model_spec, model_variant_key
-    from sparkdl_tpu.transformers import named_image
+    from sparkdl_tpu.models import get_model_spec
+    from sparkdl_tpu.transformers.named_image import set_zoo_model
 
     spec = get_model_spec(name)
-    named_image._MODEL_CACHE[(spec.name, model_variant_key(spec.name))] = (
-        spec.build(), spec.init_variables(rng=jax.random.PRNGKey(SEED)))
+    set_zoo_model(name, spec.build(),
+                  spec.init_variables(rng=jax.random.PRNGKey(SEED)))
 
 
-def _zoo_engine_in_use(name: str, dtype: str):
-    """The engine ``DeepImageFeaturizer`` ran (name, dtype) on — resolved
-    the way the stage resolves it, and required to come out of the
-    stage's process-wide engine cache, so that what is inspected IS what
-    ran."""
+def _engine_that_ran(name: str, dtype: str):
+    """The engine ``DeepImageFeaturizer`` ran (name, dtype) on: the
+    stage's own, process-wide — and its row counter must say that it has
+    run, so that what is inspected IS what ran."""
     from sparkdl_tpu import DeepImageFeaturizer
-    from sparkdl_tpu.transformers import named_image
 
-    batch = DeepImageFeaturizer(modelName=name).getBatchSize()
-    cached = len(named_image._ENGINE_CACHE)
     with _env("SPARKDL_ZOO_COMPUTE_DTYPE", dtype):
-        eng = named_image._zoo_engine(name, True, batch)
-    check(len(named_image._ENGINE_CACHE) == cached,
+        eng = DeepImageFeaturizer(modelName=name).engine()
+    check(eng.metrics.counters.get("engine.rows", 0) > 0,
           f"no {name}/{dtype} featurize engine had run")
     return eng
 
@@ -294,7 +291,7 @@ def phase_featurize(name: str, dtype: str, image_dir: str,
                         if r["features"] is not None], np.float32)
     check(np.array_equal(feats, again),
           "the same program on the same images gave different features")
-    eng = _zoo_engine_in_use(spec.name, dtype)
+    eng = _engine_that_ran(spec.name, dtype)
     obs = {"model": spec.name, "dtype": dtype,
            "batch": eng.device_batch_size, "images": n_files - 1,
            "feature_width": int(feats.shape[1]),
@@ -323,14 +320,14 @@ def phase_xception_kernel(image_dir: str, features) -> dict:
 
     spec = get_model_spec("Xception")
     h, w = spec.input_size
-    eng = _zoo_engine_in_use("Xception", "float32")
-    compiled = eng._compiled.lower(
-        eng.variables, jax.ShapeDtypeStruct(
-            (eng.device_batch_size, h, w, 3), np.uint8)).compile()
-    calls = compiled.as_text().count("tpu_custom_call")
+    eng = _engine_that_ran("Xception", "float32")
+    calls = eng.compiled_text(jax.ShapeDtypeStruct(
+        (eng.device_batch_size, h, w, 3), np.uint8)).count("tpu_custom_call")
     check(calls > 0, "Xception's compiled program holds no Pallas kernel: "
           "the reference path ran instead")
-    _, variables = named_image._cached_model("Xception")
+    with _env("SPARKDL_ZOO_COMPUTE_DTYPE", "float32"):
+        _, variables, _ = named_image.zoo_serving_bundle("Xception",
+                                                         featurize=True)
     xla = InferenceEngine(
         named_image.zoo_model_fn("Xception", featurize=True,
                                  module=Xception(fused_inference=False)),
@@ -545,8 +542,10 @@ def phase_chips_featurize(sizes: Sizes, model: str = "ResNet50") -> dict:
     n = len(jax.devices())
     spec = get_model_spec(model)
     h, w = spec.input_size
-    module, variables = named_image._cached_model(spec.name)
-    fn = named_image.zoo_model_fn(spec.name, featurize=True, module=module)
+    # the zoo's own resolution: seeded weights, fn through zoo_model_fn
+    with _env("SPARKDL_ZOO_COMPUTE_DTYPE", "float32"):
+        fn, variables, _ = named_image.zoo_serving_bundle(spec.name,
+                                                          featurize=True)
     rng = np.random.default_rng(SEED)
     b = sizes.chips_batch
     x = rng.integers(0, 256, (b, h, w, 3)).astype(np.uint8)

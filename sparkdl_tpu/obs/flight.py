@@ -97,7 +97,9 @@ EVENT_HELP = {
     "compile.persist": ("persistent XLA compile cache enabled and "
                         "validated against the committed program "
                         "lockfile (attrs name the dir and whether an "
-                        "existing population was reused)"),
+                        "existing population was reused; a directory "
+                        "placed by JAX_COMPILATION_CACHE_DIR keeps no "
+                        "manifest: reused null)"),
     "compile.invalidate": ("program-lockfile drift invalidated the "
                            "persistent compile cache — stale entries "
                            "purged, drift classified back to the GC "

@@ -22,17 +22,19 @@ Where the cache lives, in order:
   * ``JAX_COMPILATION_CACHE_DIR`` set — the installation PLACED the
     cache: that directory is the cache for every entry point, JAX
     already reads it, and no code here points JAX anywhere else (the
-    manifest, the hit/miss listener and the persist-everything
-    thresholds still apply there).  A placed directory may be shared
-    with other checkouts and other commits, so nothing in it is ever
-    deleted: drift is classified and reported, and JAX's own
-    content-addressed key is what keeps a stale executable unserved.
+    hit/miss listener and the persist-everything thresholds still
+    apply there).  A placed directory may be shared with other
+    checkouts and other commits, so this module OWNS nothing in it: it
+    keeps no manifest there (two commits would rewrite each other's on
+    every start and report a cold start that is not one), deletes
+    nothing, and leaves staleness to JAX's own content-addressed key.
   * else ``SPARKDL_COMPILE_CACHE`` (the ``SPARKDL_BLACKBOX`` grammar):
     ``""``/``0``/``false``/``off``/``no`` — DISABLED (the library
     default: nothing about compilation changes, and the per-engine
     probe is one module-global read); ``1``/``true``/``on``/``yes`` —
-    enabled at :data:`DEFAULT_DIR`; anything else — the cache
-    DIRECTORY.
+    enabled at :data:`DEFAULT_DIR`; anything else — a cache DIRECTORY
+    this module owns (manifest, purge on drift), which is what tells
+    it from a placed one.
   * the repo's own entry points (``chip_smoke.py``, ``bench.py``,
     ``tools/``) call :func:`configure_default`, which falls back to
     :data:`DEFAULT_DIR` — ONE fixed, git-ignored directory inside the
@@ -44,7 +46,11 @@ Resolution is the faults-pattern process singleton: the first
 (or an entry point's :func:`configure_default` before it) consults the
 env exactly once (:func:`ensure_from_env`, serialized
 under the configure lock) and every later engine sees the resolved
-state.  Configuration failures — unwritable directory, corrupt
+state.  Configuring NEVER initialises a JAX backend: ``bench.py``
+configures the cache and then starts CPU-pinned children, which it may
+only do while it does not hold the chip (the platform is part of JAX's
+own cache key, so the manifest does not record it).  Configuration
+failures — unwritable directory, corrupt
 manifest, the injected ``compile.cache`` fault — degrade to DISABLED
 (fresh compiles, a warning, never a serving outage): the cache is an
 optimization, not a dependency.
@@ -89,7 +95,6 @@ __all__ = [
     "MANIFEST_NAME",
     "DEFAULT_DIR",
     "PLACED_DIR_ENV",
-    "dir_from_env",
     "configure",
     "configure_default",
     "ensure_from_env",
@@ -120,26 +125,26 @@ _state: Any = _UNSET    # None = disabled; dict = the resolved snapshot
 _lock = named_lock("parallel.compile_cache")
 _counts = {"hits": 0, "misses": 0}
 _listener = [False]
+#: JAX's own cache directory from before this module first re-pointed it
+#: (at most one element): what ``_reset_for_tests`` puts back
+_jax_dir_before: List[Optional[str]] = []
 
 
-def _placed_dir() -> Optional[str]:
-    return os.environ.get(PLACED_DIR_ENV, "").strip() or None
-
-
-def dir_from_env() -> Optional[str]:
-    """The cache directory the environment asks for (module docstring):
-    the placed one, else per the ``SPARKDL_COMPILE_CACHE`` grammar, else
-    None (off)."""
-    placed = _placed_dir()
-    if placed is not None:
-        return placed
+def _resolve_env() -> Tuple[Optional[str], bool]:
+    """``(directory, placed)`` the environment asks for (module
+    docstring): the placed one, else per the ``SPARKDL_COMPILE_CACHE``
+    grammar, else ``(None, False)`` (off).  The ONE place the two
+    variables are read."""
+    placed = os.environ.get(PLACED_DIR_ENV, "").strip()
+    if placed:
+        return placed, True
     raw = os.environ.get("SPARKDL_COMPILE_CACHE", "").strip()
     low = raw.lower()
     if low in _OFF:
-        return None
+        return None, False
     if low in _ON:
-        return DEFAULT_DIR
-    return os.path.expanduser(raw)
+        return DEFAULT_DIR, False
+    return os.path.expanduser(raw), False
 
 
 def _install_listener() -> None:
@@ -184,8 +189,7 @@ def _purge(dir_path: str) -> int:
 
 def _validate_manifest(dir_path: str,
                        lockfile_path: Optional[str],
-                       policy: Optional[str] = None,
-                       may_purge: bool = True
+                       policy: Optional[str] = None
                        ) -> Tuple[Dict[str, Any], List[Tuple[str, dict]]]:
     """Compare the cache directory's manifest against the live
     committed lockfile AND the process's mesh/partition-rule policy
@@ -200,11 +204,10 @@ def _validate_manifest(dir_path: str,
     instead of serving/accumulating executables compiled for a layout
     this deployment no longer uses.  ``policy=None`` (test/CLI
     configures) is a wildcard: it never invalidates a populated set.
-    ``may_purge=False`` (a placed directory) classifies and reports the
-    same drift but deletes nothing: other checkouts may own what is
-    there.  Returns the state fields and the flight events to emit AFTER the
-    configure lock is released (the recorder never runs under the locks
-    it observes)."""
+    Only ever called on a directory this module owns (never a placed
+    one), and never touches a JAX backend.  Returns the state fields and
+    the flight events to emit AFTER the configure lock is released (the
+    recorder never runs under the locks it observes)."""
     import jax
 
     from sparkdl_tpu.analysis.program.lockfile import (DEFAULT_LOCKFILE,
@@ -216,8 +219,9 @@ def _validate_manifest(dir_path: str,
     if os.path.isfile(lock_path):
         programs = read_lockfile(lock_path).get("programs", {})
     manifest_path = os.path.join(dir_path, MANIFEST_NAME)
-    env = {"jax_version": jax.__version__,
-           "backend": jax.default_backend()}
+    # no platform here: asking JAX for it would initialise the backend
+    # (and take the chip), and JAX's own cache key already carries it
+    env = {"jax_version": jax.__version__}
     reused = False
     invalidated = False
     drift_rules: List[str] = []
@@ -237,7 +241,6 @@ def _validate_manifest(dir_path: str,
         if (stored is not None
                 and stored.get("schema_version") == MANIFEST_SCHEMA
                 and stored.get("jax_version") == env["jax_version"]
-                and stored.get("backend") == env["backend"]
                 and policy_ok
                 and _norm(stored.get("programs", {})) == _norm(programs)):
             reused = True
@@ -257,21 +260,17 @@ def _validate_manifest(dir_path: str,
                     # the executables were compiled for layouts this
                     # deployment no longer uses
                     drift_rules = ["GC005"]
-            if may_purge:
-                purged = _purge(dir_path)
+            purged = _purge(dir_path)
             events.append(("compile.invalidate", {
                 "dir": dir_path, "purged_entries": purged,
                 "drift_rules": drift_rules or ["manifest"],
             }))
             logger.warning(
-                "persistent compile cache at %s invalidated: %s; %s",
-                dir_path,
+                "persistent compile cache at %s invalidated: %s; purged "
+                "%d stale entries (fresh compiles ahead)", dir_path,
                 (f"lockfile drift classified {drift_rules}"
                  if drift_rules else "unreadable/foreign manifest"),
-                (f"purged {purged} stale entries (fresh compiles ahead)"
-                 if may_purge else
-                 f"nothing deleted, the directory was placed by "
-                 f"{PLACED_DIR_ENV} and may be shared"))
+                purged)
     doc = {"schema_version": MANIFEST_SCHEMA, **env,
            "sharding_policies": policies, "programs": programs}
     tmp = manifest_path + ".tmp"
@@ -293,30 +292,39 @@ def _validate_manifest(dir_path: str,
 
 def _configure_locked(dir_path: Optional[str],
                       lockfile_path: Optional[str],
-                      policy: Optional[str] = None
+                      policy: Optional[str] = None,
+                      placed: bool = False
                       ) -> Tuple[Optional[Dict[str, Any]],
                                  List[Tuple[str, dict]]]:
     """Resolve the cache state (called under the configure lock);
-    returns (state, flight events to emit after release).  Any failure
-    degrades to DISABLED — the cache must never take down serving (an
-    entry point that needs it checks for the ``None``)."""
+    returns (state, flight events to emit after release).  ``placed``:
+    ``dir_path`` is the one JAX already read from
+    :data:`PLACED_DIR_ENV` — no manifest, no purge, no re-pointing.
+    Any failure degrades to DISABLED — the cache must never take down
+    serving (an entry point that needs it checks for the ``None``)."""
     if dir_path is None:
         return None, []
-    placed = _placed_dir()
-    if placed is not None:
-        dir_path = placed
     try:
         # chaos hook: an injected error here is a corrupt cache
         # dir/manifest the configure path must absorb (degrade to
         # fresh compiles), never propagate into engine construction
         inject("compile.cache")
         os.makedirs(dir_path, exist_ok=True)
-        fields, events = _validate_manifest(dir_path, lockfile_path, policy,
-                                            may_purge=placed is None)
+        if placed:
+            # no manifest: whether an earlier population is reused
+            # here is JAX's to know
+            fields = {"reused": None, "invalidated": False}
+            events = [("compile.persist", {"dir": dir_path, "reused": None,
+                                           "placed": True})]
+        else:
+            fields, events = _validate_manifest(dir_path, lockfile_path,
+                                                policy)
         import jax
 
         jax.config.update("jax_enable_compilation_cache", True)
-        if placed is None:  # a placed directory is already JAX's own
+        if not placed:  # a placed directory is already JAX's own
+            if not _jax_dir_before:
+                _jax_dir_before.append(jax.config.jax_compilation_cache_dir)
             jax.config.update("jax_compilation_cache_dir", dir_path)
         # cold-start elimination wants EVERY dispatch program persisted,
         # not only the slow-to-compile ones jax's defaults target
@@ -324,8 +332,7 @@ def _configure_locked(dir_path: Optional[str],
                           0.0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         _install_listener()
-        return {"dir": dir_path, "placed": placed is not None,
-                **fields}, events
+        return {"dir": dir_path, "placed": placed, **fields}, events
     # graftlint: allow=SDL003 reason=the cache is an optimization: any configure failure (unwritable dir, corrupt manifest, injected fault) is logged and degrades to fresh compiles
     except Exception as e:  # noqa: BLE001
         logger.warning("persistent compile cache disabled: %s: %s "
@@ -336,16 +343,21 @@ def _configure_locked(dir_path: Optional[str],
 
 def configure(dir_path: Optional[str],
               lockfile_path: Optional[str] = None,
-              policy: Optional[str] = None) -> Optional[Dict[str, Any]]:
+              policy: Optional[str] = None,
+              placed: bool = False) -> Optional[Dict[str, Any]]:
     """Install (or disable, with ``None``) the persistent compile cache
-    at ``dir_path``, validating its manifest against ``lockfile_path``
-    (default: the committed ``PROGRAMS.lock.json``) and the process's
+    at ``dir_path`` — that directory, whatever the environment says —
+    validating its manifest against ``lockfile_path`` (default: the
+    committed ``PROGRAMS.lock.json``) and the process's
     mesh/partition-rule ``policy`` (ISSUE 14; ``None`` = no policy
     recorded — a later engine-driven configure with a real policy
-    invalidates such a manifest once, classified GC005)."""
+    invalidates such a manifest once, classified GC005).  ``placed``
+    is for the two callers that resolved the environment
+    (:func:`configure_default`, :func:`ensure_from_env`)."""
     global _state
     with _lock:
-        st, events = _configure_locked(dir_path, lockfile_path, policy)
+        st, events = _configure_locked(dir_path, lockfile_path, policy,
+                                       placed)
         _state = st
     for name, attrs in events:
         flight_emit(name, **attrs)
@@ -356,12 +368,13 @@ def configure_default() -> Optional[Dict[str, Any]]:
     """What the repo's own entry points call before they compile: the
     cache the environment asks for, else the fixed in-checkout
     :data:`DEFAULT_DIR`."""
-    return configure(dir_from_env() or DEFAULT_DIR)
+    dir_path, placed = _resolve_env()
+    return configure(dir_path or DEFAULT_DIR, placed=placed)
 
 
 def ensure_from_env(policy: Optional[str] = None
                     ) -> Optional[Dict[str, Any]]:
-    """The per-engine probe: resolve :func:`dir_from_env` exactly
+    """The per-engine probe: resolve the environment exactly
     once per process (first engine construction), then one
     module-global read (plus a policy-set membership check) forever
     after.  Every engine passes its ``compile_policy()`` string: the
@@ -379,7 +392,8 @@ def ensure_from_env(policy: Optional[str] = None
         return _state if isinstance(_state, dict) else None
     with _lock:
         if _state is _UNSET:
-            st, events = _configure_locked(dir_from_env(), None, policy)
+            dir_path, placed = _resolve_env()
+            st, events = _configure_locked(dir_path, None, policy, placed)
             _state = st
         else:
             st, events = _state, []
@@ -393,17 +407,20 @@ def ensure_from_env(policy: Optional[str] = None
 def note_policy(policy: str) -> None:
     """Record one engine's mesh/partition policy in the manifest's
     policy SET (no purge — adding a layout to a live deployment only
-    widens what a restart may reuse).  No-op while disabled or when
-    the policy is already recorded (the per-engine fast path)."""
+    widens what a restart may reuse).  No-op while disabled, in a
+    placed directory (no manifest) or when the policy is already
+    recorded (the per-engine fast path)."""
     global _state
-    st = _state
-    if (not isinstance(st, dict)
-            or policy in st.get("sharding_policies", [])):
+
+    def noted(st: Any) -> bool:
+        return (not isinstance(st, dict) or st["placed"]
+                or policy in st["sharding_policies"])
+
+    if noted(_state):
         return
     with _lock:
         st = _state
-        if (not isinstance(st, dict)
-                or policy in st.get("sharding_policies", [])):
+        if noted(st):
             return
         manifest_path = os.path.join(st["dir"], MANIFEST_NAME)
         try:
@@ -447,14 +464,16 @@ def enabled() -> bool:
 
 def _reset_for_tests() -> None:
     """Forget the resolved state (tests re-resolve under a different
-    env); jax's own cache-dir config is cleared too so later engines
-    in this process stop persisting."""
+    env); where a configure pointed JAX at a directory of the module's
+    own, JAX goes back to the one it had, so later engines in this
+    process stop persisting there."""
     global _state
     with _lock:
         _state = _UNSET
         _counts["hits"] = 0
         _counts["misses"] = 0
-    if _placed_dir() is None:
-        import jax
+        if _jax_dir_before:
+            import jax
 
-        jax.config.update("jax_compilation_cache_dir", None)
+            jax.config.update("jax_compilation_cache_dir",
+                              _jax_dir_before.pop())

@@ -874,7 +874,7 @@ class InferenceEngine:
         self.metrics.gauge("engine.param_bytes_per_chip",
                            float(self._sharding_stats["param_bytes_per_chip"]))
         # Persistent compile cache (ISSUE 13): resolve where it lives
-        # (compile_cache.dir_from_env) once per process BEFORE any
+        # (compile_cache.ensure_from_env) once per process BEFORE any
         # program of this engine compiles, so fleet deploys and
         # serving cold-starts across restarts reuse on-disk
         # executables keyed on the committed lockfile.  Disabled path
@@ -1054,6 +1054,14 @@ class InferenceEngine:
         return dict(self._sharding_stats,
                     sharding_digest=self.sharding_digest,
                     sharded=self.param_shardings is not None)
+
+    def compiled_text(self, batch) -> str:
+        """The per-batch program as XLA compiled it for ``batch`` (one
+        device batch, or its ``jax.ShapeDtypeStruct``) on this engine's
+        mesh and weights: the text a bring-up check searches for custom
+        calls and collectives."""
+        return self._compiled.lower(self.variables,
+                                    batch).compile().as_text()
 
     def run_padded(self, batch):
         """Run one already-padded device batch (array or pytree of arrays

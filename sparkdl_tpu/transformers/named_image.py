@@ -57,6 +57,18 @@ def _cached_model(name: str):
     return _MODEL_CACHE[key]
 
 
+def set_zoo_model(name: str, module, variables) -> None:
+    """Serve ``(module, variables)`` as zoo model ``name`` in this
+    process, in place of the weights import (which needs a weights file
+    or the network): every zoo stage and server resolves its weights
+    through the one process cache this fills.  Engines built on the
+    model's earlier weights are dropped."""
+    name = get_model_spec(name).name
+    _MODEL_CACHE[(name, model_variant_key(name))] = (module, variables)
+    for key in [k for k in _ENGINE_CACHE if k[0] == name]:
+        del _ENGINE_CACHE[key]
+
+
 def zoo_compute_dtype_name() -> str:
     """Canonicalized ``SPARKDL_ZOO_COMPUTE_DTYPE`` ("float32" or
     "bfloat16"); raises on unsupported values.  One parser for the engine
@@ -329,14 +341,18 @@ class _NamedImageTransformer(_ImageInputStage, HasModelName):
             SUPPORTED_MODELS)
         self._setDefault(batchSize=64)
 
+    def engine(self) -> InferenceEngine:
+        """The engine ``transform`` runs this stage on under the current
+        environment — process-wide, one per (model, cut, batch, compute
+        dtype), built on first use."""
+        return _zoo_engine(self.getModelName(), self.featurize,
+                           self.getBatchSize())
+
     def _run_model(self, dataset) -> Tuple[np.ndarray, list, int]:
         name = self.getModelName()
         spec = get_model_spec(name)
         h, w = spec.input_size
-        out, valid_idx = self._run_streaming(
-            dataset,
-            lambda: _zoo_engine(name, self.featurize, self.getBatchSize()),
-            h, w)
+        out, valid_idx = self._run_streaming(dataset, self.engine, h, w)
         if out is None:
             dim = spec.feature_size if self.featurize else 1000
             return np.zeros((0, dim), np.float32), valid_idx, len(dataset)

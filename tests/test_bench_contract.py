@@ -19,6 +19,13 @@ import bench
 CPU_STAMP = {"platform": "cpu", "kind": "cpu"}
 
 
+@pytest.fixture(autouse=True)
+def _no_chip_config_has_started(monkeypatch):
+    """The flag is the process's, and the real gate sets it for good:
+    each test starts as a fresh bench process does."""
+    monkeypatch.setattr(bench, "_CHIP_CONFIGS_STARTED", [False])
+
+
 @pytest.fixture()
 def captured(monkeypatch, tmp_path):
     from sparkdl_tpu.utils.jsonl import CrashSafeJsonlWriter
@@ -245,17 +252,51 @@ def test_every_line_carries_the_device_stamp(captured, monkeypatch,
 
 # -- one process per chip ---------------------------------------------------
 
-def test_no_child_is_started_while_the_parent_holds_an_accelerator(
-        monkeypatch):
-    """A chip belongs to one process: once this one has initialised an
-    accelerator backend, the child runner refuses before it spawns."""
-    assert bench._holds_accelerator() is False  # tier-1: CPU backend only
-    monkeypatch.setattr(bench, "_holds_accelerator", lambda: True)
+def test_no_child_is_started_once_a_chip_config_has_started(monkeypatch):
+    """A chip belongs to one process: the gate every chip config passes
+    closes the door on children BEFORE it asks JAX for the device (which
+    is what takes the chip), whatever the answer; from then on the child
+    runner refuses before it spawns."""
+    assert bench._CHIP_CONFIGS_STARTED == [False]
+    with pytest.raises(bench.NoAcceleratorError):
+        bench.require_accelerator()
+    assert bench._CHIP_CONFIGS_STARTED == [True]
     monkeypatch.setattr(
         subprocess, "Popen",
         lambda *a, **kw: pytest.fail("a child was started"))
     with pytest.raises(RuntimeError, match="refused"):
         bench._run_json_subprocess("out = {}", timeout_s=5)
+
+
+def test_bench_entry_touches_no_backend_through_its_chipfree_children(
+        tmp_path):
+    """``python bench.py`` as the driver runs it, on a platform JAX
+    cannot initialise: everything the parent does before and during its
+    chip-free children — configuring the compile cache, provisioning
+    traces, stamping and printing the child's line — must leave JAX's
+    backend alone, or on the chip machine the parent would hold the
+    chip before its first child and (rightly) refuse every one of them.
+    Here any backend access in the parent raises, so exit 0 with the
+    child's stamped line is the proof."""
+    import os
+    import sys
+
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("SPARKDL_FAULTS", "SPARKDL_COMPILE_CACHE")}
+    env.update(JAX_PLATFORMS="no_such_platform",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cc"),
+               SPARKDL_BENCH_CONFIGS="cache",
+               SPARKDL_BENCH_CACHE_REQUESTS="24",
+               SPARKDL_BENCH_CACHE_UNIVERSE="6",
+               SPARKDL_BENCH_CACHE_DISPATCH_MS="5.0",
+               SPARKDL_BENCH_TRACE_DIR=str(tmp_path / "traces"),
+               SPARKDL_BENCH_ARTIFACT=str(tmp_path / "lines.jsonl"))
+    r = subprocess.run([sys.executable, bench.__file__],
+                       capture_output=True, text=True, timeout=300, env=env)
+    assert r.returncode == 0, r.stderr[-2000:]
+    rec = json.loads(r.stdout.strip().splitlines()[-1])
+    assert rec["config"] == "cache" and "error" not in rec
+    assert rec["device"]["platform"] == "cpu"  # the child's, not ours
 
 
 def test_children_are_cpu_pinned_and_stamped(monkeypatch):

@@ -41,16 +41,15 @@ class _TinyZoo:
 
 
 def _seed_tiny(name):
-    from sparkdl_tpu.models import get_model_spec, model_variant_key
-    from sparkdl_tpu.transformers import named_image
+    from sparkdl_tpu.models import get_model_spec
+    from sparkdl_tpu.transformers.named_image import set_zoo_model
 
     spec = get_model_spec(name)
     rng = np.random.default_rng(0)
     variables = {"params": {"head": {
         "kernel": rng.normal(size=(3, spec.feature_size)).astype(np.float32),
         "bias": np.zeros((spec.feature_size,), np.float32)}}}
-    named_image._MODEL_CACHE[(spec.name, model_variant_key(spec.name))] = (
-        _TinyZoo(), variables)
+    set_zoo_model(name, _TinyZoo(), variables)
 
 
 @pytest.fixture()
@@ -218,7 +217,7 @@ def test_xception_kernel_phase_fails_where_the_reference_path_ran(
 
     eng = InferenceEngine(lambda v, x: x.astype("float32").mean(axis=(1, 2)),
                           {}, mesh=None, device_batch_size=8)
-    monkeypatch.setattr(chip_smoke, "_zoo_engine_in_use",
+    monkeypatch.setattr(chip_smoke, "_engine_that_ran",
                         lambda name, dtype: eng)
     assert jax.default_backend() == "cpu"
     with pytest.raises(chip_smoke.CheckFailed, match="no Pallas kernel"):

@@ -201,8 +201,8 @@ def _run_restart(cache_dir, placed=False):
 
 @pytest.fixture
 def _fresh_compile_cache_state(monkeypatch):
-    # these tests choose their own directories: an installation that
-    # placed the cache would (rightly) override every one of them
+    # the tests that resolve the environment start from one that places
+    # nothing (an explicit ``configure(dir)`` means ``dir`` regardless)
     monkeypatch.delenv(compile_cache.PLACED_DIR_ENV, raising=False)
     yield
     compile_cache._reset_for_tests()
@@ -245,21 +245,24 @@ def test_restart_serves_lockfile_pinned_programs_with_zero_fresh_compiles(
 
 
 def test_compile_cache_env_grammar(monkeypatch):
+    """``(directory, placed)`` from the two variables, read in ONE
+    place."""
+    resolve = compile_cache._resolve_env
     monkeypatch.delenv(compile_cache.PLACED_DIR_ENV, raising=False)
     monkeypatch.delenv("SPARKDL_COMPILE_CACHE", raising=False)
-    assert compile_cache.dir_from_env() is None
+    assert resolve() == (None, False)
     for off in ("0", "false", "off", "no"):
         monkeypatch.setenv("SPARKDL_COMPILE_CACHE", off)
-        assert compile_cache.dir_from_env() is None
+        assert resolve() == (None, False)
     monkeypatch.setenv("SPARKDL_COMPILE_CACHE", "1")
-    assert compile_cache.dir_from_env() == compile_cache.DEFAULT_DIR
+    assert resolve() == (compile_cache.DEFAULT_DIR, False)
     monkeypatch.setenv("SPARKDL_COMPILE_CACHE", "/somewhere/else")
-    assert compile_cache.dir_from_env() == "/somewhere/else"
+    assert resolve() == ("/somewhere/else", False)
     # a placed directory wins over the module's own knob, on or off
     monkeypatch.setenv(compile_cache.PLACED_DIR_ENV, "/placed")
-    assert compile_cache.dir_from_env() == "/placed"
+    assert resolve() == ("/placed", True)
     monkeypatch.setenv("SPARKDL_COMPILE_CACHE", "0")
-    assert compile_cache.dir_from_env() == "/placed"
+    assert resolve() == ("/placed", True)
 
 
 def test_default_dir_is_fixed_inside_the_checkout():
@@ -291,39 +294,62 @@ def test_configure_default_uses_the_fixed_dir_when_nothing_is_placed(
 
 def test_placed_dir_is_the_cache_and_jax_is_pointed_nowhere_else(
         monkeypatch, tmp_path, _fresh_compile_cache_state):
-    """``JAX_COMPILATION_CACHE_DIR`` set: every way in — the engine
-    probe, an entry point, an explicit ``configure(other)`` — resolves
-    to that directory, and no ``jax.config.update`` names a cache
-    directory at all (JAX read the variable itself)."""
+    """``JAX_COMPILATION_CACHE_DIR`` set: both ways in that read the
+    environment — an entry point, the engine probe — resolve to that
+    directory, whatever the module's own knob says, and no
+    ``jax.config.update`` names a cache directory at all (JAX read the
+    variable itself)."""
     import jax
 
     placed = str(tmp_path / "placed")
     monkeypatch.setenv(compile_cache.PLACED_DIR_ENV, placed)
+    monkeypatch.setenv("SPARKDL_COMPILE_CACHE", str(tmp_path / "knob"))
     updates = []
     real_update = jax.config.update
     monkeypatch.setattr(
         jax.config, "update",
         lambda k, v: (updates.append(k), real_update(k, v))[1])
-    for way in (compile_cache.configure_default,
-                lambda: compile_cache.configure(str(tmp_path / "other"))):
-        st = way()
-        assert st["dir"] == placed and st["placed"] is True
+    st = compile_cache.configure_default()
+    assert st["dir"] == placed and st["placed"] is True
     compile_cache._reset_for_tests()
-    assert compile_cache.ensure_from_env(policy="mesh=1x1")["dir"] == placed
-    assert "jax_compilation_cache_dir" not in updates
-    assert os.path.isfile(os.path.join(placed, compile_cache.MANIFEST_NAME))
-    assert not (tmp_path / "other").exists()
+    st = compile_cache.ensure_from_env(policy="mesh=1x1")
+    assert st["dir"] == placed and st["placed"] is True
     compile_cache._reset_for_tests()
+    assert "jax_enable_compilation_cache" in updates  # the spy works
     assert "jax_compilation_cache_dir" not in updates
+    assert not (tmp_path / "knob").exists()
 
 
-def test_placed_dir_is_never_purged(monkeypatch, tmp_path,
-                                    _fresh_compile_cache_state):
+def test_explicit_configure_means_that_directory(
+        monkeypatch, tmp_path, _fresh_compile_cache_state):
+    """The environment is resolved in one place, by the two callers
+    that ask it: ``configure(dir)`` is ``dir``, owned by the module,
+    even where the installation placed another — and forgetting it puts
+    JAX back where it was."""
+    import jax
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv(compile_cache.PLACED_DIR_ENV, str(tmp_path / "placed"))
+    st = compile_cache.configure(str(tmp_path / "mine"))
+    assert st["dir"] == str(tmp_path / "mine") and st["placed"] is False
+    assert jax.config.jax_compilation_cache_dir == str(tmp_path / "mine")
+    assert (tmp_path / "mine" / compile_cache.MANIFEST_NAME).is_file()
+    assert not (tmp_path / "placed").exists()
+    compile_cache._reset_for_tests()
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_placed_dir_holds_no_manifest_and_is_never_purged(
+        monkeypatch, tmp_path, _fresh_compile_cache_state):
     """A placed directory may be shared between the parent commit and
-    the change: lockfile or policy drift is classified and reported
-    exactly as in a chosen directory, but nothing in it is deleted —
-    where the same drift in a directory the module chose purges."""
-    def populate(d):
+    the change, so the module owns nothing in it: no manifest is
+    written (two commits would overwrite each other's on every start
+    and each report the other as an invalidation), whatever is there
+    stays — even a manifest that has drifted from the lockfile, which
+    in a directory the module chose purges — and no
+    ``compile.invalidate`` event tells an operator of a cold start
+    that is not one."""
+    def drifted(d):
         st = compile_cache.configure(str(d), policy="mesh=1x1")
         assert st["invalidated"] is False
         (d / "jit_other_checkout-entry").write_bytes(b"executable")
@@ -332,21 +358,68 @@ def test_placed_dir_is_never_purged(monkeypatch, tmp_path,
         name = sorted(doc["programs"])[0]
         doc["programs"][name]["fingerprint"] = "0" * 64
         manifest.write_text(json.dumps(doc))
+        return manifest.read_text()
 
     chosen = tmp_path / "chosen"
-    populate(chosen)
+    drifted(chosen)
     st = compile_cache.configure(str(chosen), policy="mesh=1x1")
     assert st["invalidated"] and st["purged_entries"] == 1
     assert not (chosen / "jit_other_checkout-entry").exists()
 
     placed = tmp_path / "placed"
+    foreign = drifted(placed)  # as another checkout might have left it
     monkeypatch.setenv(compile_cache.PLACED_DIR_ENV, str(placed))
-    populate(placed)
-    for policy in ("mesh=1x1", "mesh=2x2|params=abc"):  # lockfile, policy
-        st = compile_cache.configure(str(placed), policy=policy)
-        assert st["invalidated"] and st["drift_rules"]
-        assert st["purged_entries"] == 0
+    events = []
+    monkeypatch.setattr(compile_cache, "flight_emit",
+                        lambda name, **attrs: events.append(name))
+    for policy in ("mesh=1x1", "mesh=2x2|params=abc"):
+        compile_cache._reset_for_tests()
+        st = compile_cache.ensure_from_env(policy=policy)
+        assert st["placed"] and st["reused"] is None
+        assert st["invalidated"] is False
         assert (placed / "jit_other_checkout-entry").exists()
+        assert (placed / compile_cache.MANIFEST_NAME).read_text() == foreign
+    assert events == ["compile.persist"] * 2
+    empty = tmp_path / "empty"
+    monkeypatch.setenv(compile_cache.PLACED_DIR_ENV, str(empty))
+    compile_cache._reset_for_tests()
+    assert compile_cache.configure_default()["dir"] == str(empty)
+    assert os.listdir(empty) == []
+
+
+def test_configuring_the_cache_initialises_no_backend(tmp_path):
+    """``bench.py`` configures the cache and THEN starts its chip-free
+    children, which it may only do while it does not hold the chip: a
+    configure that asks JAX for its platform takes the chip first.  A
+    platform JAX cannot initialise proves it — any backend access in
+    this child raises — for a directory the module owns (manifest
+    written) and for a placed one."""
+    code = (
+        "import json, sys\n"
+        f"sys.path.insert(0, {_REPO!r})\n"
+        "from sparkdl_tpu.parallel import compile_cache\n"
+        "st = compile_cache.configure_default()\n"
+        "assert st is not None, 'configure degraded: it touched JAX'\n"
+        "import jax\n"
+        "try:\n"
+        "    jax.devices()\n"
+        "except RuntimeError:\n"
+        "    print(json.dumps(st))\n"
+        "else:\n"
+        "    raise SystemExit('the bogus platform initialised?')\n")
+    for var in ("SPARKDL_COMPILE_CACHE", compile_cache.PLACED_DIR_ENV):
+        env = {k: v for k, v in os.environ.items()
+               if k not in ("SPARKDL_COMPILE_CACHE",
+                            compile_cache.PLACED_DIR_ENV, "SPARKDL_FAULTS")}
+        env["JAX_PLATFORMS"] = "no_such_platform"
+        env[var] = str(tmp_path / var)
+        r = subprocess.run([sys.executable, "-c", code],
+                           capture_output=True, text=True, timeout=120,
+                           env=env)
+        assert r.returncode == 0, r.stderr[-2000:]
+        st = json.loads(r.stdout.strip().splitlines()[-1])
+        assert st["dir"] == str(tmp_path / var)
+        assert st["placed"] is (var == compile_cache.PLACED_DIR_ENV)
 
 
 def test_placed_restart_hits_without_fresh_compiles(tmp_path):

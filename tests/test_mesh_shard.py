@@ -559,11 +559,8 @@ def test_gc005_unknown_axis_in_declaration_fires():
 # compile-cache manifest carries the sharding policy
 # ---------------------------------------------------------------------------
 
-def test_compile_cache_policy_flip_purges_classified_gc005(tmp_path,
-                                                           monkeypatch):
+def test_compile_cache_policy_flip_purges_classified_gc005(tmp_path):
     from sparkdl_tpu.parallel import compile_cache
-
-    monkeypatch.delenv(compile_cache.PLACED_DIR_ENV, raising=False)
 
     d = str(tmp_path / "cc")
     rng = np.random.default_rng(6)
@@ -593,8 +590,7 @@ def test_compile_cache_policy_flip_purges_classified_gc005(tmp_path,
         compile_cache._reset_for_tests()
 
 
-def test_compile_cache_policy_set_is_order_independent(tmp_path,
-                                                       monkeypatch):
+def test_compile_cache_policy_set_is_order_independent(tmp_path):
     """A deployment whose engines use SEVERAL policies (a fleet mixing
     sharded and replicated entries) must reuse across restarts no
     matter which engine constructs first: every engine's policy joins
@@ -603,7 +599,6 @@ def test_compile_cache_policy_set_is_order_independent(tmp_path,
     used purges."""
     from sparkdl_tpu.parallel import compile_cache
 
-    monkeypatch.delenv(compile_cache.PLACED_DIR_ENV, raising=False)
     d = str(tmp_path / "cc")
     a, b, c = ("mesh=1x8|params=aaa", "mesh=8x1|params=replicated",
                "mesh=2x4|params=ccc")
