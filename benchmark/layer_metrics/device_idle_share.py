@@ -1,0 +1,8 @@
+"""Share of the traced window in which no operation ran on the device:
+1 - (union of the device operations' intervals) / window."""
+
+
+def read(obs):
+    if obs.trace is None or obs.trace.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - obs.trace.busy_s / obs.trace.window_s)
