@@ -22,6 +22,7 @@ from sparkdl_tpu.image.schema import (
     imageStructToArray,
     imageTypeByMode,
 )
+from sparkdl_tpu.obs.trace import get_tracer
 
 
 def PIL_decode(raw_bytes: bytes) -> Optional[np.ndarray]:
@@ -407,16 +408,22 @@ def iterFileBatches(path: str, batch_size: int = 64,
     ``transformStream``."""
     files = _list_files(path, recursive=recursive)
     batch_size = max(1, int(batch_size))
+    tracer = get_tracer()
     for off in range(0, len(files), batch_size):
         chunk = files[off:off + batch_size]
-        data = []
-        for f in chunk:
-            with open(f, "rb") as fh:
-                data.append(fh.read())
-        yield pa.record_batch({
-            "filePath": pa.array(chunk, type=pa.string()),
-            "fileData": pa.array(data, type=pa.binary()),
-        })
+        # every span here and in iterImageBatches closes before the
+        # yield: a generator must not leave one open on the puller's stack
+        with tracer.span("io.read", files=len(chunk)) as sp:
+            data = []
+            for f in chunk:
+                with open(f, "rb") as fh:
+                    data.append(fh.read())
+            sp.annotate(bytes=sum(map(len, data)))
+            rb = pa.record_batch({
+                "filePath": pa.array(chunk, type=pa.string()),
+                "fileData": pa.array(data, type=pa.binary()),
+            })
+        yield rb
 
 
 def iterImageBatches(path: str, batch_size: int = 64, recursive: bool = False,
@@ -426,21 +433,28 @@ def iterImageBatches(path: str, batch_size: int = 64, recursive: bool = False,
     (null structs for undecodable files).  Peak host memory is one batch of
     decoded images, not the dataset."""
     decode = decode_f if decode_f is not None else PIL_decode
+    tracer = get_tracer()
     for rb in iterFileBatches(path, batch_size=batch_size,
                               recursive=recursive):
         files = rb.column(0).to_pylist()
         blobs = rb.column(1).to_pylist()
-        structs = []
-        for f, blob in zip(files, blobs):
-            arr = decode(blob)
-            if arr is None:
-                structs.append(None)
-            elif isinstance(arr, dict):
-                structs.append(arr)
-            else:
-                structs.append(
-                    imageArrayToStruct(np.asarray(arr), origin=f))
-        yield pa.record_batch({"image": pa.array(structs, type=imageSchema)})
+        with tracer.span("io.decode", rows=len(blobs)) as sp:
+            decoded = [decode(blob) for blob in blobs]
+            sp.annotate(failed=sum(arr is None for arr in decoded))
+        with tracer.span("io.to_arrow", rows=len(decoded)) as sp:
+            structs = []
+            for i, f in enumerate(files):
+                # freed as its struct is built: the peak stays one batch
+                arr, decoded[i] = decoded[i], None
+                if arr is None or isinstance(arr, dict):
+                    structs.append(arr)
+                else:
+                    structs.append(
+                        imageArrayToStruct(np.asarray(arr), origin=f))
+            out = pa.record_batch(
+                {"image": pa.array(structs, type=imageSchema)})
+            sp.annotate(bytes=out.nbytes)
+        yield out
 
 
 def filesToDF(path: str, numPartitions: Optional[int] = None,
@@ -474,14 +488,20 @@ def readImagesWithCustomFn(path: str, decode_f: Callable[[bytes], Optional[np.nd
     ``transformStream`` instead of materializing a frame."""
     from sparkdl_tpu.frame import DataFrame
 
+    tracer = get_tracer()
     schema = pa.schema([pa.field("image", imageSchema)])
-    table = pa.Table.from_batches(
-        list(iterImageBatches(path, batch_size=256, recursive=recursive,
-                              decode_f=decode_f)),
-        schema=schema)
-    df = DataFrame(table)
-    if numPartitions:
-        df = df.repartition(numPartitions)
+    with tracer.span("io.read_images") as root:
+        batches = list(iterImageBatches(path, batch_size=256,
+                                        recursive=recursive,
+                                        decode_f=decode_f))
+        with tracer.span("io.repartition") as sp:
+            df = DataFrame(pa.Table.from_batches(batches, schema=schema))
+            if numPartitions:
+                df = df.repartition(numPartitions)
+            sp.annotate(rows=len(df), partitions=df.num_partitions)
+        root.annotate(files=len(df), rows=len(df),
+                      null_rows=df.table.column("image").null_count,
+                      partitions=df.num_partitions)
     return df
 
 

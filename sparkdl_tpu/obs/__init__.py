@@ -36,13 +36,26 @@ spans, and every run can export a machine-readable record.
 Instrumented surfaces: ``serving.Server``/``DynamicBatcher`` (request +
 micro-batch spans; shed/drain flight events; ``batch.topoff`` events +
 ``serving.topoff_rows``/``serving.batch_fill_ratio`` metrics for the
-ragged top-off path), ``parallel.engine.
-InferenceEngine`` (call/dispatch spans; breaker open/half-open/close
-flight events; the ``engine.rows``/``engine.pad_rows`` pad ledger),
+ragged top-off path), ``image.io`` (``io.read_images`` per
+``readImages`` call over ``io.read``/``io.decode``/``io.to_arrow`` per
+record batch and ``io.repartition``; attrs ``files``/``rows``/
+``null_rows``/``partitions``/``bytes``/``failed``),
+``transformers.named_image`` (``transform.run`` per zoo-stage
+``transform`` — ``rows``/``valid_rows``/``model``/``batch_size`` — over
+``transform.pack_in`` per chunk — ``rows``/``valid`` — and
+``transform.pack_out`` — ``rows``/``values``), ``parallel.engine.
+InferenceEngine`` (call/dispatch spans; ``engine.pad`` —
+``rows``/``pad_rows`` — where a piece is padded and ``engine.h2d`` —
+``bytes`` — around the dispatch's ``device_put``, host side only;
+breaker open/half-open/close flight events; the
+``engine.rows``/``engine.pad_rows`` pad ledger; ``engine.call_wall_s``,
+host wall seconds of ``__call__``, the cost ledger's conservation
+reference),
 ``parallel.compile_cache`` (``compile.persist``/``compile.invalidate``
 flight events + hit/miss counters for the persistent executable
 store), ``parallel.pipeline.PipelinedRunner`` (per-stage spans
-with ``block_until_ready``-bracketed device time),
+with ``block_until_ready``-bracketed device time; ``pipeline.gather``
+carries ``rows``/``bytes`` beside ``device_us``),
 ``serving.fleet.Fleet`` (rollout start/promote/rollback + tenant-shed
 flight events), ``serving.cache.InferenceCache`` (hit/miss/coalesced/
 evict/invalidate flight events + ``cache.*`` metrics),

@@ -163,6 +163,8 @@ class Span:
             self.t1 = t1
             if status != "ok":
                 self.status = status
+            if len(tracer._ring) == tracer.capacity:
+                tracer.dropped += 1
             tracer._ring.append(self)
         return self
 
@@ -235,6 +237,9 @@ class Tracer:
         # against appends and hit "deque mutated during iteration".
         self._ring: deque = deque(maxlen=self.capacity)
         self._ring_lock = named_lock("obs.trace.ring")
+        #: spans evicted from the full ring since ``clear()``: a reader
+        #: that sums spans must refuse a ring that overflowed
+        self.dropped = 0
         self._ids = itertools.count(1)  # next() is atomic in CPython
         self._local = threading.local()
 
@@ -317,6 +322,7 @@ class Tracer:
     def clear(self) -> None:
         with self._ring_lock:
             self._ring.clear()
+            self.dropped = 0
 
     # -- flush ---------------------------------------------------------
     def flush(self, out_dir: Optional[str] = None) -> List[str]:

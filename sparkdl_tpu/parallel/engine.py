@@ -1063,6 +1063,20 @@ class InferenceEngine:
         return self._compiled.lower(self.variables,
                                     batch).compile().as_text()
 
+    @staticmethod
+    def _h2d(host, sharding):
+        """``device_put`` under an ``engine.h2d`` span: the HOST side of
+        the transfer (staging and enqueue) — nothing here waits for the
+        device, the dispatch path stays asynchronous."""
+        import jax
+
+        tracer = get_tracer()
+        with tracer.span("engine.h2d") as sp:
+            if tracer.enabled:
+                sp.annotate(bytes=sum(
+                    a.nbytes for a in jax.tree_util.tree_leaves(host)))
+            return jax.device_put(host, sharding)
+
     def run_padded(self, batch):
         """Run one already-padded device batch (array or pytree of arrays
         sharing the leading batch axis); returns device output(s)."""
@@ -1079,7 +1093,7 @@ class InferenceEngine:
         def attempt():
             with get_tracer().span("engine.dispatch",
                                    rows=self.device_batch_size):
-                x = jax.device_put(batch, self._batch_sharding)
+                x = self._h2d(batch, self._batch_sharding)
                 return self._compiled(self.variables, x)
 
         return self._run_dispatch(attempt)
@@ -1096,13 +1110,14 @@ class InferenceEngine:
         self.metrics.incr("engine.rows", n)
         if n == self.device_batch_size:
             return chunk
-        self.metrics.incr("engine.pad_rows", self.device_batch_size - n)
+        pad_rows = self.device_batch_size - n
+        self.metrics.incr("engine.pad_rows", pad_rows)
 
         def pad_leaf(a):
-            pad = [(0, self.device_batch_size - n)] + [(0, 0)] * (a.ndim - 1)
-            return np.pad(a, pad)
+            return np.pad(a, [(0, pad_rows)] + [(0, 0)] * (a.ndim - 1))
 
-        return jax.tree_util.tree_map(pad_leaf, chunk)
+        with get_tracer().span("engine.pad", rows=n, pad_rows=pad_rows):
+            return jax.tree_util.tree_map(pad_leaf, chunk)
 
     def _trim(self, out, n: int):
         import jax
@@ -1205,8 +1220,9 @@ class InferenceEngine:
         self.metrics.record_time("engine_call", elapsed)
         # unbounded float accumulator (timing series are capped): THE
         # conservation reference the cost ledger's totals are proved
-        # against
-        self.metrics.incr("engine.device_time_s", elapsed)
+        # against.  Host wall time of this call (perf_counter), not time
+        # measured on the device — hence the name
+        self.metrics.incr("engine.call_wall_s", elapsed)
         if on_metered is not None:
             on_metered(elapsed)
         return result
@@ -1235,7 +1251,7 @@ class InferenceEngine:
             with get_tracer().span("engine.dispatch",
                                    group=self.batches_per_dispatch):
                 return self._compiled_group(self.variables,
-                                            jax.device_put(stacked, sh))
+                                            self._h2d(stacked, sh))
 
         return self._run_dispatch(attempt)
 

@@ -177,6 +177,8 @@ class PipelinedRunner:
     def run(self, batches: Iterable[Any]) -> Iterator[Any]:
         """Yield per-piece host outputs, bit-identical to (and in the same
         order as) the serial path."""
+        import jax
+
         eng = self.engine
         m = self.metrics
         stop = threading.Event()
@@ -280,6 +282,12 @@ class PipelinedRunner:
                                      kind=kind) as sp:
                         parts = eng._force_parts(
                             ns, dev, block=sp.block_until_ready)
+                        if tracer.enabled:
+                            sp.annotate(
+                                rows=ns if kind == "plain" else sum(ns),
+                                bytes=sum(
+                                    a.nbytes for a in
+                                    jax.tree_util.tree_leaves(parts)))
                     for part in parts:
                         if not self._put(out_q, part, stop, "gather",
                                          "out_q"):

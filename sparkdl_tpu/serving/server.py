@@ -901,7 +901,7 @@ class Server:
         (and embedders) substitute plain ``fn(batch)`` callables for the
         engine; those still serve — they just don't feed the cost
         ledger's device-time meter (they don't tick the engine's
-        ``engine.device_time_s`` counter either, so conservation holds).
+        ``engine.call_wall_s`` counter either, so conservation holds).
         The signature probe is cached on the callable."""
         if on_metered is None:
             return {}

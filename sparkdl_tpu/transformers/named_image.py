@@ -23,6 +23,7 @@ from sparkdl_tpu.image.io import arrowStructsToBatch
 from sparkdl_tpu.image.schema import imageArrayToStruct, imageSchema
 from sparkdl_tpu.models import get_model_spec, load_model, model_variant_key
 from sparkdl_tpu.models.imagenet import decode_predictions
+from sparkdl_tpu.obs.trace import get_tracer
 from sparkdl_tpu.param.converters import SparkDLTypeConverters
 from sparkdl_tpu.param.params import Param, TypeConverters, keyword_only
 from sparkdl_tpu.param.shared import (HasBatchSize, HasInputCol, HasModelName,
@@ -241,19 +242,26 @@ class _ImageInputStage(Transformer, HasInputCol, HasOutputCol, HasBatchSize):
         name = self.getInputCol()
         col_idx = dataset.table.column_names.index(name)
         offset = 0
+        tracer = get_tracer()
         for rb in dataset.iter_batches(chunk_rows):
             col = rb.column(col_idx)
-            # zero-copy struct packing (no per-row dict materialization);
-            # compact=True: the batch holds only the decodable rows
-            batch, ok = arrowStructsToBatch(col, height, width,
-                                            compact=True)
-            vi_local = np.nonzero(ok)[0]
+            # closed before the yield: the span nests under whoever pulls
+            # this generator and is never left open on that thread
+            with tracer.span("transform.pack_in", rows=len(col)) as sp:
+                # zero-copy struct packing (no per-row dict
+                # materialization); compact=True: the batch holds only
+                # the decodable rows
+                batch, ok = arrowStructsToBatch(col, height, width,
+                                                compact=True)
+                vi_local = np.nonzero(ok)[0]
+                sp.annotate(valid=len(vi_local))
+                if len(vi_local):
+                    valid_idx.extend(int(offset + i) for i in vi_local)
+                    if origins is not None:
+                        ocol = col.field("origin")
+                        origins.extend(
+                            (ocol[int(i)].as_py() or "") for i in vi_local)
             if len(vi_local):
-                valid_idx.extend(int(offset + i) for i in vi_local)
-                if origins is not None:
-                    ocol = col.field("origin")
-                    origins.extend(
-                        (ocol[int(i)].as_py() or "") for i in vi_local)
                 yield batch
             offset += len(col)
 
@@ -358,6 +366,24 @@ class _NamedImageTransformer(_ImageInputStage, HasModelName):
             return np.zeros((0, dim), np.float32), valid_idx, len(dataset)
         return np.asarray(out), valid_idx, len(dataset)
 
+    def _output_column(self, out: np.ndarray, valid_idx: List[int],
+                       num_rows: int) -> pa.Array:
+        """The output column from the model's rows ``out`` at positions
+        ``valid_idx``; nulls elsewhere."""
+        return _float_list_array(out, valid_idx, num_rows)
+
+    def _transform(self, dataset):
+        tracer = get_tracer()
+        with tracer.span("transform.run", model=self.getModelName(),
+                         batch_size=self.getBatchSize()) as root:
+            out, valid_idx, n = self._run_model(dataset)
+            root.annotate(rows=n, valid_rows=len(valid_idx))
+            with tracer.span("transform.pack_out", rows=len(valid_idx),
+                             values=int(out.size)):
+                return dataset.withColumn(
+                    self.getOutputCol(),
+                    self._output_column(out, valid_idx, n))
+
 
 class DeepImageFeaturizer(_NamedImageTransformer):
     """Zoo-model featurization for transfer learning.
@@ -383,11 +409,6 @@ class DeepImageFeaturizer(_NamedImageTransformer):
                   modelName: Optional[str] = None,
                   batchSize: Optional[int] = None):
         return self._set(**self._input_kwargs)
-
-    def _transform(self, dataset):
-        feats, valid_idx, n = self._run_model(dataset)
-        return dataset.withColumn(
-            self.getOutputCol(), _float_list_array(feats, valid_idx, n))
 
 
 class DeepImagePredictor(_NamedImageTransformer):
@@ -433,12 +454,9 @@ class DeepImagePredictor(_NamedImageTransformer):
     def getTopK(self):
         return self.getOrDefault(self.topK)
 
-    def _transform(self, dataset):
-        probs, valid_idx, n = self._run_model(dataset)
-        out_col = self.getOutputCol()
+    def _output_column(self, probs, valid_idx, n):
         if not self.getDecodePredictions():
-            return dataset.withColumn(
-                out_col, _float_list_array(probs, valid_idx, n))
+            return _float_list_array(probs, valid_idx, n)
         decoded = decode_predictions(probs, top=self.getTopK())
         pred_type = pa.list_(pa.struct([
             pa.field("class", pa.string()),
@@ -450,7 +468,7 @@ class DeepImagePredictor(_NamedImageTransformer):
             values[i] = [
                 {"class": c, "description": d, "probability": p}
                 for c, d, p in row]
-        return dataset.withColumn(out_col, pa.array(values, type=pred_type))
+        return pa.array(values, type=pred_type)
 
 
 class TFImageTransformer(PersistableModelFunctionMixin, _ImageInputStage,

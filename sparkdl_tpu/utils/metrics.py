@@ -162,24 +162,6 @@ class Metrics:
                 out[f"{k}.p99"] = self._percentile(v, 99)
         return out
 
-    @contextlib.contextmanager
-    def profile(self, trace_dir: str, block_on=None):
-        """``jax.profiler.trace`` context around a pipeline section
-        (SURVEY.md §5 tracing).  Writes an XPlane trace under ``trace_dir``
-        viewable in TensorBoard/XProf; ``block_on`` forces device
-        completion inside the trace window so async dispatch doesn't hide
-        the compute."""
-        import jax
-
-        t0 = time.perf_counter()
-        with jax.profiler.trace(trace_dir):
-            try:
-                yield self
-            finally:
-                if block_on is not None:
-                    jax.block_until_ready(block_on)
-        self.record_time("profile", time.perf_counter() - t0)
-
 
 class StepTimer:
     """Wall-clock timer that forces device completion before stopping."""

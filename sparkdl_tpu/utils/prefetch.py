@@ -13,6 +13,8 @@ import queue
 import threading
 from typing import Any, Iterable, Iterator
 
+from sparkdl_tpu.obs.trace import get_tracer
+
 _SENTINEL = object()
 
 
@@ -28,6 +30,10 @@ def prefetch_iter(iterable: Iterable[Any], depth: int = 2) -> Iterator[Any]:
     q: "queue.Queue" = queue.Queue(maxsize=depth)
     stop = threading.Event()
     error: list = []
+    # spans the producer opens nest under the consumer's current span
+    # (this body runs at the consumer's first pull), not as new roots
+    tracer = get_tracer()
+    parent = tracer.current()
 
     def put(item) -> bool:
         # Bounded put that gives up when the consumer abandoned the
@@ -44,9 +50,10 @@ def prefetch_iter(iterable: Iterable[Any], depth: int = 2) -> Iterator[Any]:
 
     def produce():
         try:
-            for item in iterable:
-                if not put(item):
-                    return
+            with tracer.use(parent):
+                for item in iterable:
+                    if not put(item):
+                        return
         except BaseException as e:  # graftlint: allow=SDL003 reason=re-raised on the consumer side at next pull
             error.append(e)
         finally:

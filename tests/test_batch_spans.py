@@ -1,0 +1,329 @@
+"""Spans inside the batch path (ISSUE 27): ``readImages`` +
+``DeepImageFeaturizer.transform`` over a dozen tiny JPEGs leave one span
+tree per call — ``io.*`` under ``io.read_images``; packing, pad, H2D and
+gather under ``transform.run`` — whose attrs add up, which costs nothing
+and changes nothing when tracing is off, and which no generator leaves
+open across a ``yield``."""
+
+import importlib.util
+import os
+import threading
+
+import numpy as np
+import pytest
+
+from sparkdl_tpu import obs
+from sparkdl_tpu.image import io as image_io
+from sparkdl_tpu.models import get_model_spec
+from sparkdl_tpu.transformers import (DeepImageFeaturizer,
+                                      DeepImagePredictor)
+from sparkdl_tpu.transformers import named_image as ni
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+FILES, BATCH = 12, 8          # one full dispatch and one of 4 rows + 4 pad
+
+
+class _TinyZooModule:
+    """A zoo module's surface over a trivial function of the input."""
+
+    def apply(self, variables, x, train=False, features=False):
+        import jax.numpy as jnp
+
+        m = jnp.mean(x, axis=(1, 2, 3))
+        idx = jnp.arange(2048 if features else 1000, dtype=jnp.float32)
+        return m[:, None] * 0.01 + idx[None, :] * 1e-4
+
+
+@pytest.fixture(autouse=True)
+def _restore_tracer():
+    yield
+    obs.configure_from_env()
+
+
+@pytest.fixture(scope="module")
+def jpeg_dir(tmp_path_factory):
+    from PIL import Image
+
+    rng = np.random.default_rng(27)
+    d = tmp_path_factory.mktemp("jpegs")
+    for i in range(FILES):
+        arr = (rng.random((20, 24, 3)) * 255).astype("uint8")
+        Image.fromarray(arr).save(d / f"img_{i:02d}.jpg", quality=90)
+    return str(d)
+
+
+@pytest.fixture()
+def tiny_resnet(monkeypatch):
+    spec = get_model_spec("ResNet50")
+    monkeypatch.setitem(ni._MODEL_CACHE, ("ResNet50", ""),
+                        (_TinyZooModule(), {}))
+    ni._ENGINE_CACHE.clear()
+    yield spec
+    ni._ENGINE_CACHE.clear()
+
+
+def _featurizer():
+    return DeepImageFeaturizer(inputCol="image", outputCol="features",
+                               modelName="ResNet50", batchSize=BATCH)
+
+
+def _features(df):
+    return [r["features"] for r in df.collect()]
+
+
+def _chain(spans, span):
+    """Names from ``span`` up to its root."""
+    by_id = {s["span_id"]: s for s in spans}
+    path = []
+    while span is not None:
+        path.append(span["name"])
+        span = by_id.get(span["parent_id"])
+    return tuple(path)
+
+
+def _chains(spans, name):
+    return [_chain(spans, s) for s in spans if s["name"] == name]
+
+
+def _named(spans, name):
+    return [s for s in spans if s["name"] == name]
+
+
+def _one_job(jpeg_dir):
+    """``(read spans, transform spans, features, pad counter delta)`` of
+    one ``readImages`` and one ``transform`` with tracing on."""
+    tracer = obs.configure(enabled=True)
+    df = image_io.readImages(jpeg_dir, numPartitions=1)
+    read = tracer.snapshot()
+    tracer.clear()
+    stage = _featurizer()
+    pad0 = stage.engine().metrics.counters.get("engine.pad_rows", 0.0)
+    out = stage.transform(df)
+    pad = stage.engine().metrics.counters["engine.pad_rows"] - pad0
+    return read, tracer.snapshot(), _features(out), pad
+
+
+@pytest.fixture()
+def job(jpeg_dir, tiny_resnet):
+    return _one_job(jpeg_dir)
+
+
+UNDER_READ = ("io.read_images",)
+UNDER_RUN = ("pipeline.run", "transform.run")
+UNDER_PREPARE = ("pipeline.prepare",) + UNDER_RUN
+
+#: span, how many a job of 12 files at batch 8 leaves, the chain above
+#: it, the attrs it carries
+TABLE = [
+    ("io.read_images", 1, (), {"files", "rows", "null_rows", "partitions"}),
+    ("io.read", 1, UNDER_READ, {"files", "bytes"}),
+    ("io.decode", 1, UNDER_READ, {"rows", "failed"}),
+    ("io.to_arrow", 1, UNDER_READ, {"rows", "bytes"}),
+    ("io.repartition", 1, UNDER_READ, {"rows", "partitions"}),
+    ("transform.run", 1, (),
+     {"rows", "valid_rows", "model", "batch_size"}),
+    ("engine.pad", 1, UNDER_PREPARE, {"rows", "pad_rows"}),
+    ("engine.h2d", 2,
+     ("engine.dispatch", "pipeline.dispatch") + UNDER_RUN, {"bytes"}),
+    ("pipeline.gather", 2, UNDER_RUN, {"kind", "rows", "bytes"}),
+    ("transform.pack_out", 1, ("transform.run",), {"rows", "values"}),
+]
+
+
+@pytest.mark.parametrize("name,count,above,attrs", TABLE,
+                         ids=[row[0] for row in TABLE])
+def test_every_span_of_the_table_in_its_place(job, name, count, above,
+                                              attrs):
+    read, run, _, _ = job
+    spans = read if name.startswith("io.") else run
+    found = _named(spans, name)
+    assert len(found) == count
+    assert _chains(spans, name) == [(name,) + above] * count
+    assert all(set(s["attrs"]) == attrs for s in found)
+    assert all(s["status"] == "ok" for s in found)
+
+
+def test_first_chunk_packs_on_the_callers_thread_the_rest_in_prepare(job):
+    """The first chunk is pulled before the engine is built, so its
+    ``transform.pack_in`` hangs under ``transform.run`` itself; every
+    later one under ``pipeline.prepare``, on the prepare thread."""
+    _, run, _, _ = job
+    packs = sorted(_named(run, "transform.pack_in"),
+                   key=lambda s: s["ts_us"])
+    assert [_chain(run, s) for s in packs] == [
+        ("transform.pack_in", "transform.run"),
+        ("transform.pack_in",) + UNDER_PREPARE]
+    assert packs[0]["thread"] == threading.current_thread().name
+    assert packs[1]["thread"] == "sparkdl-pipeline-prepare"
+    assert [s["attrs"] for s in packs] == [
+        {"rows": BATCH, "valid": BATCH},
+        {"rows": FILES - BATCH, "valid": FILES - BATCH}]
+
+
+def test_one_trace_id_a_call_and_children_inside_parents(job):
+    read, run, _, _ = job
+    for spans, root in ((read, "io.read_images"), (run, "transform.run")):
+        assert len({s["trace_id"] for s in spans}) == 1
+        assert [s["name"] for s in spans if s["parent_id"] is None] == [root]
+        by_id = {s["span_id"]: s for s in spans}
+        for s in spans:
+            p = by_id.get(s["parent_id"])
+            if p is not None:
+                assert p["ts_us"] - 1 <= s["ts_us"]
+                assert (s["ts_us"] + s["dur_us"]
+                        <= p["ts_us"] + p["dur_us"] + 1)
+    assert read[0]["trace_id"] != run[0]["trace_id"]
+
+
+def test_attrs_add_up(job, jpeg_dir, tiny_resnet):
+    read, run, features, pad_delta = job
+    attrs = lambda spans, name: [s["attrs"] for s in _named(spans, name)]  # noqa: E731
+    on_disk = sum(os.path.getsize(os.path.join(jpeg_dir, f))
+                  for f in os.listdir(jpeg_dir))
+    assert attrs(read, "io.read") == [{"files": FILES, "bytes": on_disk}]
+    assert attrs(read, "io.decode") == [{"rows": FILES, "failed": 0}]
+    assert attrs(read, "io.read_images") == [
+        {"files": FILES, "rows": FILES, "null_rows": 0, "partitions": 1}]
+    (to_arrow,) = attrs(read, "io.to_arrow")
+    assert to_arrow["rows"] == FILES
+    assert to_arrow["bytes"] >= FILES * 20 * 24 * 3
+    assert attrs(run, "engine.pad") == [
+        {"rows": FILES - BATCH, "pad_rows": 2 * BATCH - FILES}]
+    assert pad_delta == 2 * BATCH - FILES
+    h, w = tiny_resnet.input_size
+    assert attrs(run, "engine.h2d") == [{"bytes": BATCH * h * w * 3}] * 2
+    gathers = attrs(run, "pipeline.gather")
+    assert [g["rows"] for g in gathers] == [BATCH, FILES - BATCH]
+    assert sum(g["bytes"] for g in gathers) == FILES * 2048 * 4
+    assert attrs(run, "transform.pack_out") == [
+        {"rows": FILES, "values": FILES * tiny_resnet.feature_size}]
+    assert attrs(run, "transform.run") == [
+        {"rows": FILES, "valid_rows": FILES, "model": "ResNet50",
+         "batch_size": BATCH}]
+    assert len(features) == FILES
+
+
+def test_a_file_that_does_not_decode_is_counted_not_dropped(
+        jpeg_dir, tiny_resnet, tmp_path):
+    for f in os.listdir(jpeg_dir)[:3]:
+        os.link(os.path.join(jpeg_dir, f), tmp_path / f)
+    (tmp_path / "broken.jpg").write_bytes(b"no jpeg")
+    tracer = obs.configure(enabled=True)
+    out = _featurizer().transform(image_io.readImages(str(tmp_path)))
+    spans = tracer.snapshot()
+    (decode,), (root,) = _named(spans, "io.decode"), \
+        _named(spans, "io.read_images")
+    assert decode["attrs"] == {"rows": 4, "failed": 1}
+    assert root["attrs"]["null_rows"] == 1 and root["attrs"]["rows"] == 4
+    (pack,) = _named(spans, "transform.pack_in")
+    assert pack["attrs"] == {"rows": 4, "valid": 3}
+    (run,) = _named(spans, "transform.run")
+    assert (run["attrs"]["rows"], run["attrs"]["valid_rows"]) == (4, 3)
+    assert sum(f is None for f in _features(out)) == 1
+
+
+def test_no_span_stays_open_across_a_yield(jpeg_dir, tiny_resnet):
+    """An abandoned generator leaves the thread's span stack clean."""
+    tracer = obs.configure(enabled=True)
+    batches = image_io.iterImageBatches(jpeg_dir, batch_size=5)
+    first = next(batches)
+    assert tracer.current() is None
+    assert {s["name"] for s in tracer.snapshot()} == {
+        "io.read", "io.decode", "io.to_arrow"}
+    batches.close()
+    from sparkdl_tpu.frame import DataFrame
+
+    chunks = _featurizer()._decoded_chunks(DataFrame(first), 8, 8, 2, [])
+    next(chunks)
+    assert tracer.current() is None
+    assert len(_named(tracer.snapshot(), "transform.pack_in")) == 1
+
+
+def test_tracing_off_records_nothing_and_never_blocks(jpeg_dir,
+                                                      tiny_resnet,
+                                                      monkeypatch):
+    """Off: an empty ring, no id issued, no ``block_until_ready``
+    anywhere.  On: only the gather thread blocks — never the dispatch
+    path, which stays asynchronous."""
+    import jax
+
+    blocked = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(
+        jax, "block_until_ready",
+        lambda x: blocked.append(threading.current_thread().name)
+        or real(x))
+    tracer = obs.configure(enabled=False)
+    _featurizer().transform(image_io.readImages(jpeg_dir, numPartitions=1))
+    assert len(tracer) == 0 and tracer.dropped == 0
+    assert next(tracer._ids) == 1
+    assert blocked == []
+    obs.configure(enabled=True)
+    _featurizer().transform(image_io.readImages(jpeg_dir, numPartitions=1))
+    assert set(blocked) == {"sparkdl-pipeline-gather"}
+
+
+def test_results_identical_with_tracing_on_and_off(jpeg_dir, tiny_resnet):
+    obs.configure(enabled=False)
+    df = image_io.readImages(jpeg_dir, numPartitions=1)
+    plain = _features(_featurizer().transform(df))
+    _, _, traced, _ = _one_job(jpeg_dir)
+    assert plain == traced
+    assert df.table.equals(
+        image_io.readImages(jpeg_dir, numPartitions=1).table)
+
+
+def test_dropped_counts_evictions_and_clear_resets_it():
+    tracer = obs.configure(enabled=True, capacity=4)
+    for i in range(4):
+        tracer.span("x.y", i=i).finish()
+    assert (len(tracer), tracer.dropped) == (4, 0)
+    for i in range(3):
+        tracer.span("x.y", i=i).finish()
+    assert (len(tracer), tracer.dropped) == (4, 3)
+    tracer.clear()
+    assert (len(tracer), tracer.dropped) == (0, 0)
+
+
+def test_serial_path_yields_the_same_names_without_pipeline(
+        jpeg_dir, tiny_resnet, monkeypatch):
+    _, piped, features, _ = _one_job(jpeg_dir)
+    monkeypatch.setenv("SPARKDL_PIPELINE", "0")
+    _, serial, serial_features, _ = _one_job(jpeg_dir)
+    names = lambda spans: {s["name"] for s in spans}  # noqa: E731
+    assert names(serial) == {n for n in names(piped)
+                             if not n.startswith("pipeline.")}
+    # the prefetch thread's spans hang under the caller's: one trace still
+    assert len({s["trace_id"] for s in serial}) == 1
+    assert set(_chains(serial, "transform.pack_in")) == {
+        ("transform.pack_in", "transform.run")}
+    assert serial_features == features
+
+
+def test_predictor_tail_is_under_pack_out(jpeg_dir, tiny_resnet):
+    tracer = obs.configure(enabled=True)
+    df = image_io.readImages(jpeg_dir, numPartitions=1)
+    tracer.clear()
+    rows = DeepImagePredictor(
+        inputCol="image", outputCol="preds", modelName="ResNet50",
+        decodePredictions=True, topK=3, batchSize=BATCH
+    ).transform(df).collect()
+    assert all(len(r["preds"]) == 3 for r in rows)
+    spans = tracer.snapshot()
+    (pack,) = _named(spans, "transform.pack_out")
+    assert pack["attrs"] == {"rows": FILES, "values": FILES * 1000}
+    assert _chain(spans, pack) == ("transform.pack_out", "transform.run")
+
+
+def test_trace_summary_folds_the_new_names(job):
+    spec = importlib.util.spec_from_file_location(
+        "trace_summary", os.path.join(REPO, "tools", "trace_summary.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    read, run, _, _ = job
+    summary = tool.summarize(read + run)
+    assert {row[0] for row in TABLE} | {"transform.pack_in"} \
+        <= set(summary["stages"])
+    table = tool.render(summary)
+    assert "| engine.h2d | 2 |" in table
+    assert "| io.decode | 1 |" in table

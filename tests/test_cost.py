@@ -146,7 +146,7 @@ def test_conservation_seeded_replay_bit_stable():
 
 def test_server_e2e_conservation_vs_engine_counter(setup):
     """End to end through the real batcher + engine: the ledger's
-    metered total equals the ``engine.device_time_s`` counter, and the
+    metered total equals the ``engine.call_wall_s`` counter, and the
     attributed split (tenants + pad) conserves it within 1e-6."""
     faults.clear()  # conservation needs every batch attributed — see
     # test_conservation_seeded_replay_bit_stable
@@ -158,7 +158,7 @@ def test_server_e2e_conservation_vs_engine_counter(setup):
         futs = [srv.submit(x[i], tenant=f"t{i % 5}") for i in range(43)]
         for f in futs:
             np.asarray(f.result(timeout=60))
-        metered = srv.metrics.counters["engine.device_time_s"]
+        metered = srv.metrics.counters["engine.call_wall_s"]
         snap = ledger.snapshot()
     tot = snap["totals"]
     assert metered > 0.0

@@ -1,30 +1,7 @@
-"""jax.profiler observability (VERDICT round 1, Missing #7 / SURVEY.md §5
-tracing bullet)."""
-
-import glob
-import os
-
-import numpy as np
+"""Throughput logging and the ``Metrics`` registry's timers, percentiles
+and histograms."""
 
 from sparkdl_tpu.utils.metrics import Metrics, StepTimer, throughput_counter
-
-
-def test_metrics_profile_writes_trace(tmp_path):
-    import jax
-    import jax.numpy as jnp
-
-    m = Metrics()
-    d = str(tmp_path / "trace")
-    x = np.ones((8, 8), np.float32)
-    with m.profile(d, block_on=None):
-        out = jax.jit(lambda a: jnp.tanh(a @ a))(x)
-        jax.block_until_ready(out)
-    # a non-empty trace dir with at least one xplane file
-    files = [p for p in glob.glob(os.path.join(d, "**", "*"), recursive=True)
-             if os.path.isfile(p)]
-    assert files, "profiler trace dir is empty"
-    assert any("xplane" in os.path.basename(p) for p in files), files
-    assert m.timings_s["profile"]
 
 
 def test_transformer_logs_throughput(fixture_images):
