@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import glob as _glob
 import os
+import threading
 from typing import Callable, Iterable, List, Optional, Sequence
 
 import numpy as np
@@ -426,12 +427,37 @@ def iterFileBatches(path: str, batch_size: int = 64,
         yield rb
 
 
+def _pil_decode_pooled(blobs: Sequence[bytes]
+                       ) -> "tuple[List[Optional[np.ndarray]], int]":
+    """:func:`PIL_decode` over ``blobs`` on the shared io pool, results in
+    the order of ``blobs`` -> (arrays, how many threads decoded a row)."""
+    from PIL import Image
+
+    # the lazy plugin registry, filled here once: a cold process must not
+    # race Image.preinit from every thread of the pool
+    Image.init()
+
+    def one(blob):
+        return PIL_decode(blob), threading.get_ident()
+
+    pairs = list(_io_executor().map(one, blobs))
+    return [arr for arr, _ in pairs], len({ident for _, ident in pairs})
+
+
 def iterImageBatches(path: str, batch_size: int = 64, recursive: bool = False,
                      decode_f: Callable[[bytes], Optional[np.ndarray]] = None
                      ) -> Iterable[pa.RecordBatch]:
     """LAZILY decode images under ``path`` into image-struct record batches
     (null structs for undecodable files).  Peak host memory is one batch of
-    decoded images, not the dataset."""
+    decoded images, not the dataset: a record batch is decoded whole before
+    the next is read, never ahead.
+
+    The package's own decoder (``decode_f`` left out, or :func:`PIL_decode`
+    itself) runs on the shared io pool for a batch of 4 files or more — PIL
+    releases the GIL inside the JPEG decoder — with the rows kept in file
+    order.  A caller's ``decode_f`` is called on the caller's thread, one
+    file after the other: the package cannot know that it is safe on
+    threads.  ``io.decode``'s ``workers`` says how many threads decoded."""
     decode = decode_f if decode_f is not None else PIL_decode
     tracer = get_tracer()
     for rb in iterFileBatches(path, batch_size=batch_size,
@@ -439,8 +465,12 @@ def iterImageBatches(path: str, batch_size: int = 64, recursive: bool = False,
         files = rb.column(0).to_pylist()
         blobs = rb.column(1).to_pylist()
         with tracer.span("io.decode", rows=len(blobs)) as sp:
-            decoded = [decode(blob) for blob in blobs]
-            sp.annotate(failed=sum(arr is None for arr in decoded))
+            if decode is PIL_decode and len(blobs) >= 4:
+                decoded, workers = _pil_decode_pooled(blobs)
+            else:
+                decoded, workers = [decode(blob) for blob in blobs], 1
+            sp.annotate(failed=sum(arr is None for arr in decoded),
+                        workers=workers)
         with tracer.span("io.to_arrow", rows=len(decoded)) as sp:
             structs = []
             for i, f in enumerate(files):
@@ -485,7 +515,14 @@ def readImagesWithCustomFn(path: str, decode_f: Callable[[bytes], Optional[np.nd
     DataFrame.  Counterpart of ``imageIO.readImagesWithCustomFn``; rows whose
     decode fails become null image structs (kept, so origins stay auditable).
     For datasets that don't fit in host RAM, use :func:`iterImageBatches` +
-    ``transformStream`` instead of materializing a frame."""
+    ``transformStream`` instead of materializing a frame.
+
+    ``decode_f`` is called on the caller's thread, once a file, in file
+    order — the reference ran it in separate worker processes, and a
+    function with a shared buffer, a session, or work of its own on this
+    package's io pool is not safe on threads.  Only the package's own
+    :func:`PIL_decode` (what :func:`readImages` passes) is fanned out over
+    the io pool, a record batch of 256 files at a time."""
     from sparkdl_tpu.frame import DataFrame
 
     tracer = get_tracer()

@@ -117,7 +117,7 @@ UNDER_PREPARE = ("pipeline.prepare",) + UNDER_RUN
 TABLE = [
     ("io.read_images", 1, (), {"files", "rows", "null_rows", "partitions"}),
     ("io.read", 1, UNDER_READ, {"files", "bytes"}),
-    ("io.decode", 1, UNDER_READ, {"rows", "failed"}),
+    ("io.decode", 1, UNDER_READ, {"rows", "failed", "workers"}),
     ("io.to_arrow", 1, UNDER_READ, {"rows", "bytes"}),
     ("io.repartition", 1, UNDER_READ, {"rows", "partitions"}),
     ("transform.run", 1, (),
@@ -181,7 +181,12 @@ def test_attrs_add_up(job, jpeg_dir, tiny_resnet):
     on_disk = sum(os.path.getsize(os.path.join(jpeg_dir, f))
                   for f in os.listdir(jpeg_dir))
     assert attrs(read, "io.read") == [{"files": FILES, "bytes": on_disk}]
-    assert attrs(read, "io.decode") == [{"rows": FILES, "failed": 0}]
+    (decode,) = _named(read, "io.decode")
+    # the package's decoder, 12 files: on the io pool, the caller waiting
+    assert 1 <= decode["attrs"].pop("workers") \
+        <= image_io._io_executor()._max_workers
+    assert decode["attrs"] == {"rows": FILES, "failed": 0}
+    assert decode["thread"] == threading.current_thread().name
     assert attrs(read, "io.read_images") == [
         {"files": FILES, "rows": FILES, "null_rows": 0, "partitions": 1}]
     (to_arrow,) = attrs(read, "io.to_arrow")
@@ -213,6 +218,7 @@ def test_a_file_that_does_not_decode_is_counted_not_dropped(
     spans = tracer.snapshot()
     (decode,), (root,) = _named(spans, "io.decode"), \
         _named(spans, "io.read_images")
+    assert 1 <= decode["attrs"].pop("workers") <= 4
     assert decode["attrs"] == {"rows": 4, "failed": 1}
     assert root["attrs"]["null_rows"] == 1 and root["attrs"]["rows"] == 4
     (pack,) = _named(spans, "transform.pack_in")

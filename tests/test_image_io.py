@@ -1,19 +1,26 @@
 """Image schema & I/O tests — round-trip array<->struct, decode of real
 fixture images, malformed input handling (reference C2 test strategy)."""
 
+import threading
+
 import numpy as np
+import pyarrow as pa
 import pytest
 
+from sparkdl_tpu import obs
+from sparkdl_tpu.image import io as image_io
 from sparkdl_tpu.image import (
     PIL_decode,
     createResizeImageUDF,
     filesToDF,
     imageArrayToStruct,
+    imageSchema,
     imageStructToArray,
     imageTypeByMode,
     imageTypeByName,
     ocvTypes,
     readImages,
+    readImagesWithCustomFn,
     resizeImage,
 )
 
@@ -72,6 +79,133 @@ def test_read_images_dataframe(fixture_images):
     for r in good:
         arr = imageStructToArray(r["image"])
         assert arr.dtype == np.uint8 and arr.shape[2] == 3
+
+
+def _jpeg_dir(path, rng, files, corrupt=None):
+    """``files`` tiny JPEGs of mixed sizes under ``path``, the one at index
+    ``corrupt`` (in file-name order) not an image -> the sorted paths."""
+    from PIL import Image
+
+    paths = []
+    for i in range(files):
+        p = path / f"img_{i:04d}.jpg"
+        if i == corrupt:
+            p.write_bytes(b"not a jpeg, row %d" % i)
+        else:
+            arr = (rng.random((9 + i % 5, 11 + i % 3, 3)) * 255
+                   ).astype("uint8")
+            Image.fromarray(arr).save(p, quality=90)
+        paths.append(str(p))
+    return paths
+
+
+def _read(path):
+    with open(path, "rb") as fh:
+        return fh.read()
+
+
+def _table_of(paths, arrays):
+    """What ``readImages`` builds from decoded ``arrays`` (None: a file
+    that did not decode), in record batches of 256 rows as it does."""
+    batches = []
+    for off in range(0, len(paths), 256):
+        structs = [
+            None if arr is None else imageArrayToStruct(arr, origin=f)
+            for f, arr in zip(paths[off:off + 256], arrays[off:off + 256])]
+        batches.append(pa.record_batch(
+            {"image": pa.array(structs, type=imageSchema)}))
+    return pa.Table.from_batches(
+        batches, schema=pa.schema([pa.field("image", imageSchema)]))
+
+
+@pytest.fixture()
+def tracer():
+    yield obs.configure(enabled=True)
+    obs.configure_from_env()
+
+
+def _decode_span(tracer):
+    (span,) = [s for s in tracer.snapshot() if s["name"] == "io.decode"]
+    return span
+
+
+def _ipc_bytes(table):
+    sink = pa.BufferOutputStream()
+    with pa.ipc.new_stream(sink, table.schema) as writer:
+        writer.write_table(table)
+    return sink.getvalue().to_pybytes()
+
+
+@pytest.mark.parametrize("files,corrupt", [
+    (1, None), (3, None), (12, None), (12, 6), (260, 257)],
+    ids=["1_serial", "3_serial", "12_pooled", "12_one_corrupt",
+         "260_two_record_batches"])
+def test_read_images_table_is_the_serial_decodes_table(tmp_path, rng,
+                                                       files, corrupt):
+    """Pooled or not, ``readImages`` gives byte for byte the table of
+    ``[PIL_decode(b) for b in blobs]`` in file-name order, the null
+    struct at the corrupt file's row."""
+    paths = _jpeg_dir(tmp_path, rng, files, corrupt)
+    serial = [PIL_decode(_read(p)) for p in paths]
+    assert [i for i, arr in enumerate(serial) if arr is None] == (
+        [] if corrupt is None else [corrupt])
+    want = _table_of(paths, serial)
+    got = readImages(str(tmp_path)).table
+    assert got.equals(want)
+    assert got.column("image").null_count == (corrupt is not None)
+    assert _ipc_bytes(got) == _ipc_bytes(want)
+    assert _ipc_bytes(readImages(str(tmp_path), numPartitions=1).table) \
+        == _ipc_bytes(want.combine_chunks())
+
+
+def test_read_images_has_two_rows_in_flight_at_once(tmp_path, rng,
+                                                    monkeypatch, tracer):
+    """On the pooled path two rows decode side by side: every call of
+    the decoder waits for a second one to arrive.  A serial loop would
+    break the barrier at its time limit and fail the read."""
+    if image_io._io_executor()._max_workers < 2:
+        pytest.skip("the io pool of a one-core host has one thread")
+    paths = _jpeg_dir(tmp_path, rng, 8)
+    barrier = threading.Barrier(2)
+    idents = []
+
+    def met_by_another(blob):
+        barrier.wait(timeout=30)
+        idents.append(threading.get_ident())
+        return PIL_decode(blob)
+
+    monkeypatch.setattr(image_io, "PIL_decode", met_by_another)
+    got = readImages(str(tmp_path)).table
+    decode = _decode_span(tracer)
+    assert not barrier.broken
+    assert len(idents) == 8
+    assert threading.get_ident() not in idents
+    assert decode["attrs"] == {"rows": 8, "failed": 0,
+                               "workers": len(set(idents))}
+    assert decode["attrs"]["workers"] >= 2
+    assert decode["thread"] == threading.current_thread().name
+    assert got.equals(_table_of(paths, [PIL_decode(_read(p))
+                                        for p in paths]))
+
+
+def test_custom_decode_f_runs_on_the_callers_thread_in_file_order(
+        tmp_path, rng, tracer):
+    """A caller's ``decode_f`` never goes to the pool: once a file, in
+    file order, on the thread that called ``readImagesWithCustomFn``."""
+    paths = _jpeg_dir(tmp_path, rng, 12, corrupt=5)
+    calls = []
+
+    def decode_f(blob):
+        calls.append((threading.get_ident(), blob))
+        return PIL_decode(blob)
+
+    got = readImagesWithCustomFn(str(tmp_path), decode_f).table
+    decode = _decode_span(tracer)
+    assert [ident for ident, _ in calls] == [threading.get_ident()] * 12
+    assert [blob for _, blob in calls] == [_read(p) for p in paths]
+    assert decode["attrs"] == {"rows": 12, "failed": 1, "workers": 1}
+    assert got.column("image").to_pylist()[5] is None
+    assert got.equals(readImages(str(tmp_path)).table)
 
 
 def test_files_to_df_and_partitions(fixture_images):
