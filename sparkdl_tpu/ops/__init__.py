@@ -4,11 +4,16 @@ The zoo's compute path is plain jax/flax wherever XLA already emits
 optimal code (dense convs ride the MXU untouched); this package holds the
 exceptions — ops whose default lowering materializes avoidable HBM
 traffic, rewritten as fused pallas kernels with reference-parity jax
-fallbacks for CPU/debug.
+fallbacks for CPU/debug: the separable convolutions of the CNN zoo
+(``sepconv``), and the two parts of a hybrid sequence block that have no
+lowering worth having — the chunked state-space scan (``ssd``) and
+causal grouped-query attention without the score matrix (``attention``).
 """
 
+from sparkdl_tpu.ops.attention import causal_attention
 from sparkdl_tpu.ops.sepconv import (fused_sepconv_flat, pad_to_flat,
                                      sepconv_reference, unflatten)
+from sparkdl_tpu.ops.ssd import ssd_scan
 
-__all__ = ["fused_sepconv_flat", "pad_to_flat", "sepconv_reference",
-           "unflatten"]
+__all__ = ["causal_attention", "fused_sepconv_flat", "pad_to_flat",
+           "sepconv_reference", "ssd_scan", "unflatten"]

@@ -1,0 +1,160 @@
+"""Causal grouped-query attention that never holds a row's score matrix.
+
+``q`` ``[R, T, H*hd]``, ``k`` and ``v`` ``[R, T, KV*hd]``, heads side by
+side on the last axis as the projections leave them, each key/value
+head serving ``H // KV`` query heads; ``q`` comes already scaled (and
+turned by the rotary position, as ``k``).  Returns ``[R, T, H*hd]`` in
+``q``'s dtype.
+
+On the TPU a Pallas kernel (``name="causal_attention"``): grid rows x
+query heads x query blocks x key blocks, the key axis sequential, the
+running maximum, the running sum and the float32 accumulator of the
+online softmax in VMEM scratch.  A key/value head is read in place for
+each of its query heads (no repeated copy in memory, no transpose to a
+heads-first layout); key blocks above the diagonal are neither computed
+nor fetched.  Elsewhere the same sum in ``jax.numpy``, one block of
+queries after the other (``lax.map``), so that the largest score array
+is ``[R, H, block, T]``.  Scores, softmax and the accumulator are
+float32; the two matrix products take their operands in ``q``'s dtype.
+The platform picks, as in ``ops/sepconv``, and nothing else does.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+NAME = "causal_attention"
+BLOCK = 512
+_NEG = -1e30          # a score no softmax notices; finite, so no NaN
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def attention_blocked(q, k, v, *, heads: int, kv_heads: int,
+                      block: int = BLOCK, causal: bool = True,
+                      precision=None):
+    """``jax.numpy``: queries in blocks of ``block`` against all keys.
+    ``causal=False`` lets every position see every other."""
+    f32 = jnp.float32
+    r, t, _ = q.shape
+    hd = q.shape[-1] // heads
+    rep = heads // kv_heads
+    block = min(block, t)
+    if t % block:
+        raise ValueError(f"{t} positions are no multiple of the block {block}")
+    kh = k.reshape(r, t, kv_heads, hd)
+    vh = v.reshape(r, t, kv_heads, hd)
+    qb = jnp.moveaxis(q.reshape(r, t // block, block, kv_heads, rep, hd), 1, 0)
+    key_at = jnp.arange(t)
+
+    def one(at):
+        q_i, i = at
+        s = jnp.einsum("rqgjd,rkgd->rgjqk", q_i, kh, precision=precision,
+                       preferred_element_type=f32)
+        if causal:
+            query_at = i * block + jnp.arange(block)
+            s = jnp.where(key_at[None, :] <= query_at[:, None], s, _NEG)
+        p = jax.nn.softmax(s, axis=-1)
+        return jnp.einsum("rgjqk,rkgd->rqgjd", p.astype(q.dtype), vh,
+                          precision=precision, preferred_element_type=f32)
+
+    out = lax.map(one, (qb, jnp.arange(t // block)))
+    return jnp.moveaxis(out, 0, 1).reshape(r, t, heads * hd).astype(q.dtype)
+
+
+def _attention_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
+                      causal: bool, precision):
+    """One query block of one head against one key block; blocks are
+    ``[1, block, hd]``."""
+    f32 = jnp.float32
+    qi, ki, nk = pl.program_id(2), pl.program_id(3), pl.num_programs(3)
+    block = q_ref.shape[1]
+
+    @pl.when(ki == 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(jnp.logical_or(ki <= qi, not causal))
+    def _():
+        s = lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+                            precision=precision, preferred_element_type=f32)
+        if causal:
+            rows = qi * block + lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            cols = ki * block + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(cols <= rows, s, _NEG)
+        m_old = m_ref[...]                                   # [block, 1]
+        m_new = jnp.maximum(m_old, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        scale = jnp.exp(m_old - m_new)
+        l_ref[...] = scale * l_ref[...] + p.sum(axis=1, keepdims=True)
+        acc_ref[...] = scale * acc_ref[...] + jnp.dot(
+            p.astype(v_ref.dtype), v_ref[0], precision=precision,
+            preferred_element_type=f32)
+        m_ref[...] = m_new
+
+    @pl.when(ki == nk - 1)
+    def _():
+        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, donate_argnums=(), static_argnames=(
+    "heads", "kv_heads", "block", "causal", "interpret", "precision"))
+def attention_kernel(q, k, v, *, heads: int, kv_heads: int,
+                     block: int = BLOCK, causal: bool = True,
+                     interpret: bool = False, precision=None):
+    """The Pallas kernel; on the chip ``hd`` is a multiple of 128 and
+    ``block`` of 8."""
+    r, t, _ = q.shape
+    hd = q.shape[-1] // heads
+    rep = heads // kv_heads
+    block = min(block, t)
+    if t % block:
+        raise ValueError(f"{t} positions are no multiple of the block {block}")
+    nb = t // block
+
+    def key_block(i, h, qi, ki):
+        # above the diagonal nothing is computed: name the block that is
+        # already there, so that nothing is fetched either
+        return (i, jnp.minimum(ki, qi) if causal else ki, h // rep)
+
+    query = pl.BlockSpec((1, block, hd), lambda i, h, qi, ki: (i, qi, h))
+    keys = pl.BlockSpec((1, block, hd), key_block)
+    return pl.pallas_call(
+        functools.partial(_attention_kernel, causal=causal,
+                          precision=precision),
+        grid=(r, heads, nb, nb),
+        in_specs=[query, keys, keys],
+        out_specs=query,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        scratch_shapes=[pltpu.VMEM((block, 1), jnp.float32),
+                        pltpu.VMEM((block, 1), jnp.float32),
+                        pltpu.VMEM((block, hd), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name=NAME,
+    )(q, k, v)
+
+
+def causal_attention(q, k, v, *, heads: int, kv_heads: int, precision=None,
+                     force: Optional[object] = None):
+    """The kernel on the TPU, the blocked ``jax.numpy`` form on any
+    other platform; ``force`` is the tests' (``True``, ``"interpret"``,
+    ``False``)."""
+    if _on_tpu() if force is None else force:
+        return attention_kernel(q, k, v, heads=heads, kv_heads=kv_heads,
+                                interpret=(force == "interpret"),
+                                precision=precision)
+    return attention_blocked(q, k, v, heads=heads, kv_heads=kv_heads,
+                             precision=precision)

@@ -735,6 +735,26 @@ def _cast_floating(variables, dtype):
     return jax.tree_util.tree_map(cast, variables)
 
 
+def _place_variables(variables, shardings):
+    """``variables`` on the device under ``shardings`` (one sharding for
+    every leaf, or a tree of them).  A leaf that is already a device
+    array laid out as wanted is TAKEN, not copied: weights a caller has
+    placed stay the one copy on the chip (a second copy of a model that
+    fills half the chip does not fit)."""
+    import jax
+
+    def place(leaf, sharding):
+        if (isinstance(leaf, jax.Array) and leaf.is_fully_addressable
+                and leaf.sharding.is_equivalent_to(sharding, leaf.ndim)):
+            return leaf
+        return jax.device_put(leaf, sharding)
+
+    if isinstance(shardings, jax.sharding.Sharding):
+        return jax.tree_util.tree_map(lambda l: place(l, shardings),
+                                      variables)
+    return jax.tree_util.tree_map(place, variables, shardings)
+
+
 class InferenceEngine:
     """Runs ``fn(variables, batch) -> out`` over arbitrarily-sized inputs in
     fixed-shape device batches on a device mesh.
@@ -889,7 +909,7 @@ class InferenceEngine:
         # policy splits them (each chip holds bytes/model_axis of a
         # sharded leaf), the NamedSharding replicate otherwise (the TPU
         # analog of the reference's model-GraphDef broadcast).
-        self.variables = jax.device_put(
+        self.variables = _place_variables(
             variables, self.param_shardings if self.param_shardings
             is not None else self._replicated)
         # grid SHAPE is part of the key (as in train._mesh_key): a

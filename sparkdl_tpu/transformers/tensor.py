@@ -23,6 +23,7 @@ from typing import Dict, List, Optional
 import numpy as np
 import pyarrow as pa
 
+from sparkdl_tpu.obs.trace import get_tracer
 from sparkdl_tpu.param.converters import SparkDLTypeConverters
 from sparkdl_tpu.param.params import Param, keyword_only
 from sparkdl_tpu.param.shared import HasBatchSize, HasInputCol, HasOutputCol
@@ -66,12 +67,32 @@ class ModelTransformer(PersistableModelFunctionMixin, Transformer,
     def getModelFunction(self):
         return self.getOrDefault(self.modelFunction)
 
+    def engine(self):
+        """The engine ``transform`` runs this stage on: the one
+        ``get_cached_engine`` keeps on the stage for its function and
+        batch size, built on first use."""
+        return get_cached_engine(self, self.getModelFunction(),
+                                 device_batch_size=self.getBatchSize())
+
     def _transform(self, dataset):
-        x = dataset.column_to_numpy(self.getInputCol()).astype(np.float32)
-        mf = self.getModelFunction()
-        eng = get_cached_engine(self, mf, device_batch_size=self.getBatchSize())
-        out = eng(x)
-        return dataset.withColumn(self.getOutputCol(), _rows_to_list_array(out))
+        tracer = get_tracer()
+        with tracer.span("transform.run",
+                         batch_size=self.getBatchSize()) as root:
+            with tracer.span("transform.pack_in", rows=len(dataset)) as sp:
+                x = dataset.column_to_numpy(self.getInputCol())
+                # token ids stay integers; everything else is a float
+                # column and reaches the function as float32
+                if not np.issubdtype(x.dtype, np.integer):
+                    x = x.astype(np.float32)
+                sp.annotate(bytes=int(x.nbytes))
+            root.annotate(rows=len(x))
+            if x.ndim == 2:
+                root.annotate(tokens=int(x.size))
+            out = self.engine()(x)
+            with tracer.span("transform.pack_out", rows=len(out),
+                             values=int(np.size(out))):
+                return dataset.withColumn(self.getOutputCol(),
+                                          _rows_to_list_array(out))
 
 
 class KerasTransformer(ModelTransformer):

@@ -343,3 +343,31 @@ def test_output_host_dtype_preserves_integer_leaves():
                           output_host_dtype=np.float32)(x)
     assert out["scores"].dtype == np.float32
     assert np.issubdtype(out["ids"].dtype, np.integer)
+
+
+def test_placed_variables_are_taken_not_copied():
+    """A leaf that is already on the device as the engine wants it is the
+    engine's leaf: the same buffer, no second copy of the weights.  Host
+    leaves and leaves laid out otherwise are placed as before."""
+    import jax
+    import jax.numpy as jnp
+
+    eng0 = InferenceEngine(lambda v, x: x @ v["w"],
+                           {"w": np.eye(4, dtype=np.float32)},
+                           device_batch_size=8)
+    placed = eng0.variables["w"]            # as an engine lays it out
+    elsewhere = jax.device_put(jnp.ones((4,), jnp.float32), jax.devices()[0])
+    eng = InferenceEngine(
+        lambda v, x: x @ v["w"] + v["b"] + v["c"],
+        {"w": placed, "b": elsewhere, "c": np.zeros((4,), np.float32)},
+        device_batch_size=8)
+    assert eng.variables["w"] is placed
+    assert ([s.data.unsafe_buffer_pointer()
+             for s in eng.variables["w"].addressable_shards]
+            == [s.data.unsafe_buffer_pointer()
+                for s in placed.addressable_shards])
+    for name in ("b", "c"):
+        assert eng.variables[name].sharding.is_equivalent_to(
+            placed.sharding, 1)
+    x = np.arange(12, dtype=np.float32).reshape(3, 4)
+    np.testing.assert_allclose(eng(x), x + 1.0)

@@ -163,3 +163,84 @@ def test_xception_kernel_program_compiles_for_v5e(v5e_chip, monkeypatch):
     mem = compiled.memory_analysis()
     assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
             + mem.output_size_in_bytes) < 8 * 2**30
+
+
+# -- the hybrid trunk's two kernels at the published widths ------------------
+
+def _on_chip(shape, dtype, chip):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+
+def test_ssd_scan_compiles_for_v5e_at_published_widths(v5e_chip):
+    """``ssd_scan`` at a dispatch of ``falcon_h1_34b.rows4k``: 8 rows of
+    4096 positions, 32 heads of 128 in 2 groups, state 256, chunk 128."""
+    from sparkdl_tpu.ops import ssd
+
+    rows, t, heads, p, groups, n = 8, 4096, 32, 128, 2, 256
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    compiled = ssd.ssd_scan_kernel.lower(
+        _on_chip((rows, t, heads, p), bf16, v5e_chip),
+        _on_chip((rows, t, heads), f32, v5e_chip),
+        _on_chip((heads,), f32, v5e_chip),
+        _on_chip((rows, t, groups, n), bf16, v5e_chip),
+        _on_chip((rows, t, groups, n), bf16, v5e_chip),
+        _on_chip((heads,), f32, v5e_chip), chunk=128).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the trace knows the kernel by its instruction's name
+    assert f"%{ssd.NAME}." in text
+
+
+def test_causal_attention_compiles_for_v5e_at_published_widths(v5e_chip):
+    """The attention path at 20 query / 4 key-value heads of 128 over
+    4096 positions, heads side by side as the projections leave them."""
+    from sparkdl_tpu.ops import attention
+
+    rows, t, heads, kv, hd = 8, 4096, 20, 4, 128
+    compiled = attention.attention_kernel.lower(
+        _on_chip((rows, t, heads * hd), jnp.bfloat16, v5e_chip),
+        _on_chip((rows, t, kv * hd), jnp.bfloat16, v5e_chip),
+        _on_chip((rows, t, kv * hd), jnp.bfloat16, v5e_chip),
+        heads=heads, kv_heads=kv).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and f"%{attention.NAME}." in text
+    # no row's [20, 4096, 4096] score matrix: nothing beside q, k, v, out
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+def test_one_trunk_block_program_compiles_for_v5e(v5e_chip, monkeypatch):
+    """One block of the trunk at the published widths through the
+    engine's dispatch program (kernel paths on): it compiles, holds both
+    kernels, and a dispatch of 8 rows x 4096 ids fits the chip beside
+    its weights."""
+    import json
+    import os
+
+    from jax.sharding import Mesh
+
+    from sparkdl_tpu.models import hybrid_trunk
+    from sparkdl_tpu.ops import attention, ssd
+    from sparkdl_tpu.parallel import mesh as mesh_lib
+    from sparkdl_tpu.parallel.engine import build_dispatch_jit
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "falcon_h1_34b.json")) as fh:
+        config = dict(json.load(fh), num_hidden_layers=1)
+    monkeypatch.setattr(ssd, "_on_tpu", lambda: True)
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    device = next(iter(v5e_chip.device_set))
+    mesh = Mesh(np.asarray([device]).reshape(1, 1),
+                (mesh_lib.DATA_AXIS, mesh_lib.MODEL_AXIS))
+    variables = jax.eval_shape(lambda k: hybrid_trunk.init(config, k),
+                               jax.random.PRNGKey(0))
+    fn = hybrid_trunk.model_function(config, {}).fn
+    compiled = build_dispatch_jit(fn, mesh, donate_batch=False).lower(
+        variables, jax.ShapeDtypeStruct((8, 4096), np.int32)).compile()
+    text = compiled.as_text()
+    assert f"%{ssd.NAME}." in text and f"%{attention.NAME}." in text
+    mem = compiled.memory_analysis()
+    # one block (0.86 GB) and the embedding (2.67 GB) beside the
+    # temporaries of 32,768 tokens: five more blocks are 4.3 GB
+    assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes) < 10.5 * 2**30

@@ -365,3 +365,61 @@ def test_zoo_engine_bf16_env_knob(fake_resnet, image_df, monkeypatch):
     monkeypatch.setenv("SPARKDL_ZOO_COMPUTE_DTYPE", "float16")
     with pytest.raises(ValueError, match="not supported"):
         ni._zoo_engine("ResNet50", True, 8)
+
+
+def _echo_function(seen):
+    import jax.numpy as jnp
+
+    def fn(v, t):
+        seen.append(t.dtype)
+        return jnp.asarray(t, jnp.float32).sum(axis=1, keepdims=True) * v["w"]
+
+    return ModelFunction(fn=fn, variables={"w": np.float32(2.0)})
+
+
+@pytest.mark.parametrize("values,arrow,reaches", [
+    ([[1, 2, 3], [4, 5, 6], [7, 8, 9]], pa.int32(), np.int32),
+    ([[1, 2, 3], [4, 5, 6], [7, 8, 9]], pa.int16(), np.int16),
+    ([[1.5, 2.0], [3.0, 4.5], [0.5, 0.25]], pa.float64(), np.float32),
+    ([[1.5, 2.0], [3.0, 4.5], [0.5, 0.25]], pa.float32(), np.float32),
+])
+def test_model_transformer_input_dtype(values, arrow, reaches):
+    """An integer list column (token ids) reaches the function as the
+    integers it holds; a float column still as float32, whatever its
+    width (``KerasTransformer``'s contract)."""
+    seen = []
+    df = DataFrame(pa.table({"x": pa.array(values, pa.list_(arrow))}))
+    mt = ModelTransformer(inputCol="x", outputCol="out",
+                          modelFunction=_echo_function(seen), batchSize=2)
+    got = mt.transform(df).column_to_numpy("out")
+    assert set(seen) == {np.dtype(reaches)}
+    np.testing.assert_allclose(got[:, 0], 2 * np.asarray(values).sum(axis=1))
+
+
+def test_model_transformer_spans_and_engine():
+    """``transform`` opens the spans the image stages open, under the
+    same names, and ``engine()`` is the engine it ran on."""
+    from sparkdl_tpu import obs
+    from sparkdl_tpu.parallel.engine import get_cached_engine
+
+    mf = _echo_function([])
+    df = DataFrame(pa.table({"x": pa.array(
+        [[1, 2, 3, 4]] * 5, pa.list_(pa.int32()))}))
+    mt = ModelTransformer(inputCol="x", outputCol="out", modelFunction=mf,
+                          batchSize=2)
+    obs.trace.configure(enabled=True, capacity=4096)
+    try:
+        mt.transform(df)
+        spans = {s["name"]: s for s in obs.trace.get_tracer().snapshot()}
+    finally:
+        obs.trace.configure(enabled=False)
+    run, pack_in, pack_out = (spans[n] for n in (
+        "transform.run", "transform.pack_in", "transform.pack_out"))
+    assert run["attrs"] == {"batch_size": 2, "rows": 5, "tokens": 20}
+    assert pack_in["attrs"] == {"rows": 5, "bytes": 5 * 4 * 4}
+    assert pack_out["attrs"] == {"rows": 5, "values": 5}
+    for child in (pack_in, pack_out, spans["engine.call"]):
+        assert child["parent_id"] == run["span_id"]
+    eng = mt.engine()
+    assert eng is get_cached_engine(mt, mf, device_batch_size=2)
+    assert eng.metrics.snapshot_raw()["counters"]["engine.rows"] == 5
