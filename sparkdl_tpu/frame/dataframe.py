@@ -210,6 +210,14 @@ def _to_table(data) -> pa.Table:
     raise TypeError(f"Cannot build DataFrame from {type(data).__name__}")
 
 
+def partition_sizes(rows: int, n: int) -> List[int]:
+    """Rows in each of the ``n`` partitions that ``repartition(n)`` cuts
+    ``rows`` rows into: as equal as whole rows allow, the larger first, at
+    most one partition a row."""
+    n = max(1, min(int(n), max(1, rows)))
+    return [rows // n + (1 if i < rows % n else 0) for i in range(n)]
+
+
 class DataFrame:
     """Immutable columnar frame backed by a ``pyarrow.Table``."""
 
@@ -309,15 +317,19 @@ class DataFrame:
     def repartition(self, n: int) -> "DataFrame":
         """Re-chunk into ``n`` roughly equal record batches.  Partition-count
         variation is the reference's stand-in for multi-node behavior in tests
-        (SURVEY.md §4) — preserved here for the same purpose."""
-        n = max(1, min(int(n), max(1, len(self))))
-        rows = len(self)
-        sizes = [rows // n + (1 if i < rows % n else 0) for i in range(n)]
+        (SURVEY.md §4) — preserved here for the same purpose.
+
+        A frame whose chunks already have those sizes is returned as it
+        is, nothing copied.  Of any other, each column of more than one
+        chunk is copied once, whole (``Table.combine_chunks``), and the
+        partitions are slices of that."""
+        sizes = [s for s in partition_sizes(len(self), n) if s]
+        if all([len(c) for c in col.chunks] == sizes
+               for col in self._table.columns):
+            return self
         combined = self._table.combine_chunks()
         batches, off = [], 0
         for s in sizes:
-            if s == 0:
-                continue
             batches.append(combined.slice(off, s))
             off += s
         return DataFrame(pa.concat_tables(batches) if batches else combined)

@@ -17,13 +17,62 @@ from typing import Callable, Iterable, List, Optional, Sequence
 import numpy as np
 import pyarrow as pa
 
+from sparkdl_tpu.image import schema as _schema
 from sparkdl_tpu.image.schema import (
+    ImageRowBuffers,
     imageArrayToStruct,
     imageSchema,
+    imageStructArray,
     imageStructToArray,
     imageTypeByMode,
+    imageTypeByName,
 )
 from sparkdl_tpu.obs.trace import get_tracer
+
+
+def _open_image(raw_bytes: bytes):
+    """The PIL image of compressed bytes with its header parsed and no
+    pixel decoded yet (``size`` is known), or ``None``."""
+    import io as _io
+
+    from PIL import Image
+
+    try:
+        return Image.open(_io.BytesIO(raw_bytes))
+    # graftlint: allow=SDL003 reason=PIL raises a zoo of types for bad bytes; None rides the ok-mask drop-to-null contract
+    except Exception:
+        return None
+
+
+def _rgb_pixels(img) -> Optional[np.ndarray]:
+    """Decode an opened PIL image -> a [H,W,3] or [H,W,4] uint8 array whose
+    first three channels are RGB, or ``None``.  Where PIL hands out its
+    own memory (Arrow's C data interface, Pillow 11.2; an image in one
+    block of PIL's allocator, 16 MB) the array is a view of it — four
+    bytes a pixel — and nothing is copied, nor the GIL held for a copy;
+    else it is ``np.asarray``'s copy."""
+    try:
+        if img.mode != "RGB":
+            img = img.convert("RGB")
+        else:
+            img.load()      # convert() of an RGB image would copy it
+        if hasattr(img, "__arrow_c_array__"):
+            try:
+                flat = pa.array(img).values.to_numpy(zero_copy_only=True)
+                return flat.reshape(img.size[1], img.size[0], 4)
+            except ValueError:      # the image lies in several blocks
+                pass
+        return np.asarray(img, dtype=np.uint8)
+    # graftlint: allow=SDL003 reason=PIL raises a zoo of types for bad bytes; None rides the ok-mask drop-to-null contract
+    except Exception:
+        return None
+
+
+def _flip_into(out: np.ndarray, rgb: np.ndarray) -> None:
+    """``out[:] = rgb[:, :, 2::-1]``: RGB -> BGR, OpenCV order.  One call:
+    on the io pool a task pays for every NumPy call with a wait for the
+    GIL, so three strided assigns, 4x faster alone, are slower there."""
+    np.copyto(out, rgb[..., 2::-1])
 
 
 def PIL_decode(raw_bytes: bytes) -> Optional[np.ndarray]:
@@ -33,18 +82,13 @@ def PIL_decode(raw_bytes: bytes) -> Optional[np.ndarray]:
     yields ``None`` (the reference drops/nulls such rows rather than failing
     the job).
     """
-    import io as _io
-
-    from PIL import Image
-
-    try:
-        img = Image.open(_io.BytesIO(raw_bytes))
-        img = img.convert("RGB")
-        rgb = np.asarray(img, dtype=np.uint8)
-    # graftlint: allow=SDL003 reason=PIL raises a zoo of types for bad bytes; None rides the ok-mask drop-to-null contract
-    except Exception:
+    img = _open_image(raw_bytes)
+    rgb = None if img is None else _rgb_pixels(img)
+    if rgb is None:
         return None
-    return np.ascontiguousarray(rgb[:, :, ::-1])  # RGB -> BGR (OpenCV order)
+    out = np.empty(rgb.shape[:2] + (3,), dtype=np.uint8)
+    _flip_into(out, rgb)
+    return out
 
 
 def decodeImage(raw_bytes: bytes, origin: str = "") -> Optional[dict]:
@@ -400,6 +444,16 @@ def _list_files(path: str, recursive: bool = False) -> List[str]:
     return sorted(files)
 
 
+def _read_files(files: Sequence[str]) -> List[bytes]:
+    with get_tracer().span("io.read", files=len(files)) as sp:
+        data = []
+        for f in files:
+            with open(f, "rb") as fh:
+                data.append(fh.read())
+        sp.annotate(bytes=sum(map(len, data)))
+    return data
+
+
 def iterFileBatches(path: str, batch_size: int = 64,
                     recursive: bool = False) -> Iterable[pa.RecordBatch]:
     """LAZILY read files under ``path`` into ``{filePath, fileData}`` record
@@ -409,39 +463,132 @@ def iterFileBatches(path: str, batch_size: int = 64,
     ``transformStream``."""
     files = _list_files(path, recursive=recursive)
     batch_size = max(1, int(batch_size))
-    tracer = get_tracer()
     for off in range(0, len(files), batch_size):
         chunk = files[off:off + batch_size]
         # every span here and in iterImageBatches closes before the
         # yield: a generator must not leave one open on the puller's stack
-        with tracer.span("io.read", files=len(chunk)) as sp:
-            data = []
-            for f in chunk:
-                with open(f, "rb") as fh:
-                    data.append(fh.read())
-            sp.annotate(bytes=sum(map(len, data)))
-            rb = pa.record_batch({
-                "filePath": pa.array(chunk, type=pa.string()),
-                "fileData": pa.array(data, type=pa.binary()),
-            })
-        yield rb
+        yield pa.record_batch({
+            "filePath": pa.array(chunk, type=pa.string()),
+            "fileData": pa.array(_read_files(chunk), type=pa.binary()),
+        })
 
 
-def _pil_decode_pooled(blobs: Sequence[bytes]
-                       ) -> "tuple[List[Optional[np.ndarray]], int]":
-    """:func:`PIL_decode` over ``blobs`` on the shared io pool, results in
-    the order of ``blobs`` -> (arrays, how many threads decoded a row)."""
+def _decode_into(images: list, i: int, slot: np.ndarray
+                 ) -> "tuple[Optional[np.ndarray], int]":
+    """One task of the io pool: decode the opened image ``images[i]`` and
+    flip it to BGR straight into ``slot``, its row of the record batch's
+    values buffer -> (the pixels, the thread).  The pixels are ``slot``;
+    ``None`` where the decoder failed; an array of their own where the
+    image decoded to another size than its header gave (ICO does that).
+    The task takes the image out of the list: decoded, it holds its
+    pixels, and only as many may be alive as the pool has threads."""
+    img, images[i] = images[i], None
+    rgb = _rgb_pixels(img)
+    if rgb is None:
+        return None, threading.get_ident()
+    out = (slot if rgb.shape[:2] == slot.shape[:2]
+           else np.empty(rgb.shape[:2] + (3,), np.uint8))
+    _flip_into(out, rgb)
+    return out, threading.get_ident()
+
+
+def _cut_under(nbytes: Sequence[int], limit: int) -> "List[tuple[int, int]]":
+    """``(lo, hi)`` runs of rows in order, each run as long as ``limit``
+    bytes allow and of one row at least."""
+    cuts, lo, held = [], 0, 0
+    for i, size in enumerate(nbytes):
+        if i > lo and held + size > limit:
+            cuts.append((lo, i))
+            lo, held = i, 0
+        held += size
+    return cuts + [(lo, len(nbytes))] if nbytes else []
+
+
+def _pil_decode_pooled(blobs: Sequence[bytes], origins: Sequence[str]
+                       ) -> "tuple[List[ImageRowBuffers], int]":
+    """:func:`PIL_decode` over ``blobs`` with each row's flip to BGR — its
+    decoder's last write — landing in its record batch's own values
+    buffer -> (the buffers, how many threads decoded a row).  The
+    headers, parsed here, give the sizes the buffer is laid out from; the
+    pixels are decoded on the shared io pool, a decoded image alive only
+    until its task has written it.  One set of buffers, unless the pixels
+    pass what int32 offsets hold."""
     from PIL import Image
 
     # the lazy plugin registry, filled here once: a cold process must not
     # race Image.preinit from every thread of the pool
     Image.init()
+    bgr8 = imageTypeByName("CV_8UC3")
+    # header parsing is Python under the GIL: the pool would not speed it
+    opened = [_open_image(blob) for blob in blobs]
+    nbytes = [0 if im is None else 3 * im.size[0] * im.size[1]
+              for im in opened]
+    built, idents = [], set()
+    for lo, hi in _cut_under(nbytes, _schema.MAX_BINARY_BYTES):
+        rows = ImageRowBuffers(origins[lo:hi], [
+            None if im is None else (bgr8, im.size[1], im.size[0])
+            for im in opened[lo:hi]])
+        live = [i for i in range(hi - lo) if opened[lo + i] is not None]
+        slots = [rows.view(i) for i in live]
+        done = list(_io_executor().map(
+            lambda i, slot: _decode_into(opened, lo + i, slot), live, slots))
+        idents.update(ident for _, ident in done)
+        pixels = [out for out, _ in done]
+        if all(out is slot or out is None
+               for out, slot in zip(pixels, slots)):
+            for i, out in zip(live, pixels):
+                if out is None:
+                    rows.drop(i)
+        else:
+            # a header gave another size than its decoder: copy the rows
+            # out of the buffer laid out from the headers into one that fits
+            decoded = [None] * (hi - lo)
+            for i, out in zip(live, pixels):
+                decoded[i] = out
+            rows = ImageRowBuffers.of_arrays(decoded, origins[lo:hi])
+        built.append(rows)
+    return built, len(idents)
 
-    def one(blob):
-        return PIL_decode(blob), threading.get_ident()
 
-    pairs = list(_io_executor().map(one, blobs))
-    return [arr for arr, _ in pairs], len({ident for _, ident in pairs})
+def _struct_chunks(files: Sequence[str], sizes: Iterable[int],
+                   decode: Callable) -> Iterable[pa.StructArray]:
+    """Read and decode ``files`` in runs of ``sizes`` -> one image-struct
+    array a run (more where a run's pixels pass 2 GiB), null structs for
+    undecodable files, rows in the order of ``files``."""
+    tracer = get_tracer()
+    off = 0
+    for size in sizes:
+        run = files[off:off + size]
+        off += size
+        blobs = _read_files(run)
+        built = decoded = None
+        # every span closes before the yield, as in iterFileBatches
+        with tracer.span("io.decode", rows=len(run)) as sp:
+            if decode is PIL_decode and len(run) >= 4:
+                built, workers = _pil_decode_pooled(blobs, run)
+                failed = sum(int((~rows.valid).sum()) for rows in built)
+            else:
+                decoded, workers = [decode(blob) for blob in blobs], 1
+                failed = sum(arr is None for arr in decoded)
+            sp.annotate(failed=failed, workers=workers)
+        del blobs
+        with tracer.span("io.to_arrow", rows=len(run)) as sp:
+            direct = len(run)
+            if built is not None:
+                arrays = [rows.to_arrow() for rows in built]
+            elif any(isinstance(arr, dict) for arr in decoded):
+                direct = 0
+                arrays = [pa.array(
+                    [arr if arr is None or isinstance(arr, dict)
+                     else imageArrayToStruct(np.asarray(arr), origin=f)
+                     for f, arr in zip(run, decoded)], type=imageSchema)]
+            else:
+                arrays = [imageStructArray(decoded, run)]
+            # not kept alive while the puller holds the arrays
+            built = decoded = None
+            sp.annotate(bytes=sum(a.nbytes for a in arrays),
+                        direct_rows=direct)
+        yield from arrays
 
 
 def iterImageBatches(path: str, batch_size: int = 64, recursive: bool = False,
@@ -457,34 +604,23 @@ def iterImageBatches(path: str, batch_size: int = 64, recursive: bool = False,
     releases the GIL inside the JPEG decoder — with the rows kept in file
     order.  A caller's ``decode_f`` is called on the caller's thread, one
     file after the other: the package cannot know that it is safe on
-    threads.  ``io.decode``'s ``workers`` says how many threads decoded."""
+    threads.  ``io.decode``'s ``workers`` says how many threads decoded.
+
+    What is copied: on the pool a row's pixels are written once after the
+    decoder, by the BGR flip, into the record batch's Arrow ``data``
+    buffer (laid out from the files' headers), and never again.  Arrays
+    that a ``decode_f`` returns are copied once into that buffer
+    (:func:`~sparkdl_tpu.image.schema.imageStructArray`).  Only a record
+    batch in which a ``decode_f`` returned struct dicts goes the old road:
+    ``tobytes`` a row, then ``pa.array``.  ``io.to_arrow``'s ``direct_rows``
+    counts the rows of the first two kinds.  A batch whose pixels pass
+    2 GiB (int32 offsets) comes as several record batches."""
     decode = decode_f if decode_f is not None else PIL_decode
-    tracer = get_tracer()
-    for rb in iterFileBatches(path, batch_size=batch_size,
-                              recursive=recursive):
-        files = rb.column(0).to_pylist()
-        blobs = rb.column(1).to_pylist()
-        with tracer.span("io.decode", rows=len(blobs)) as sp:
-            if decode is PIL_decode and len(blobs) >= 4:
-                decoded, workers = _pil_decode_pooled(blobs)
-            else:
-                decoded, workers = [decode(blob) for blob in blobs], 1
-            sp.annotate(failed=sum(arr is None for arr in decoded),
-                        workers=workers)
-        with tracer.span("io.to_arrow", rows=len(decoded)) as sp:
-            structs = []
-            for i, f in enumerate(files):
-                # freed as its struct is built: the peak stays one batch
-                arr, decoded[i] = decoded[i], None
-                if arr is None or isinstance(arr, dict):
-                    structs.append(arr)
-                else:
-                    structs.append(
-                        imageArrayToStruct(np.asarray(arr), origin=f))
-            out = pa.record_batch(
-                {"image": pa.array(structs, type=imageSchema)})
-            sp.annotate(bytes=out.nbytes)
-        yield out
+    files = _list_files(path, recursive=recursive)
+    batch_size = max(1, int(batch_size))
+    sizes = [batch_size] * -(-len(files) // batch_size)
+    for array in _struct_chunks(files, sizes, decode):
+        yield pa.RecordBatch.from_arrays([array], names=["image"])
 
 
 def filesToDF(path: str, numPartitions: Optional[int] = None,
@@ -522,20 +658,37 @@ def readImagesWithCustomFn(path: str, decode_f: Callable[[bytes], Optional[np.nd
     function with a shared buffer, a session, or work of its own on this
     package's io pool is not safe on threads.  Only the package's own
     :func:`PIL_decode` (what :func:`readImages` passes) is fanned out over
-    the io pool, a record batch of 256 files at a time."""
+    the io pool.
+
+    What is copied (see :func:`iterImageBatches`): with the package's
+    decoder each of the ``numPartitions`` partitions (record batches of 256
+    files where it is left out) is built as asked, its pixels written once
+    into its one Arrow buffer, and nothing is copied after — a partition
+    whose pixels pass 2 GiB comes as several chunks under that.  With a
+    caller's ``decode_f`` the sizes are not known before the decode: the
+    frame is built in record batches of 256 files and ``repartition`` copies
+    it once more where those are not the partitions asked for
+    (``io.repartition``'s ``copied_bytes``)."""
     from sparkdl_tpu.frame import DataFrame
+    from sparkdl_tpu.frame.dataframe import partition_sizes
 
     tracer = get_tracer()
-    schema = pa.schema([pa.field("image", imageSchema)])
     with tracer.span("io.read_images") as root:
-        batches = list(iterImageBatches(path, batch_size=256,
-                                        recursive=recursive,
-                                        decode_f=decode_f))
+        files = _list_files(path, recursive=recursive)
+        as_asked = bool(numPartitions) and decode_f is PIL_decode
+        sizes = ([n for n in partition_sizes(len(files), numPartitions) if n]
+                 if as_asked else [256] * -(-len(files) // 256))
+        arrays = list(_struct_chunks(files, sizes, decode_f))
         with tracer.span("io.repartition") as sp:
-            df = DataFrame(pa.Table.from_batches(batches, schema=schema))
-            if numPartitions:
-                df = df.repartition(numPartitions)
-            sp.annotate(rows=len(df), partitions=df.num_partitions)
+            built = DataFrame(pa.table(
+                {"image": pa.chunked_array(arrays, type=imageSchema)}))
+            df = (built.repartition(numPartitions)
+                  if numPartitions and not as_asked else built)
+            # combine_chunks leaves a column of one chunk where it lies
+            sp.annotate(rows=len(df), partitions=df.num_partitions,
+                        copied_bytes=0 if df is built else sum(
+                            col.nbytes for col in built.table.columns
+                            if col.num_chunks > 1))
         root.annotate(files=len(df), rows=len(df),
                       null_rows=df.table.column("image").null_count,
                       partitions=df.num_partitions)

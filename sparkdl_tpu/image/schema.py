@@ -16,7 +16,7 @@ field names so frames interop with Spark's image source format.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict, Optional
+from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
 import pyarrow as pa
@@ -125,6 +125,98 @@ def imageArrayToStruct(array: np.ndarray, origin: str = "") -> dict:
         "mode": t.ord,
         "data": array.tobytes(),
     }
+
+
+#: the most bytes one ``data`` child holds: its offsets are int32
+MAX_BINARY_BYTES = 2 ** 31 - 1
+
+
+class ImageRowBuffers:
+    """The buffers of one ``imageSchema`` struct array, laid out from the
+    rows' shapes before their pixels exist: one uint8 values buffer that
+    row ``i``'s decoder writes through ``view(i)``, int32 offsets, and the
+    five scalar children.  ``to_arrow`` wraps them without a copy.
+
+    ``shapes[i]`` is ``(ImageType, height, width)``, or ``None`` for a row
+    that stays a null struct (children empty, as ``pa.array`` leaves
+    them).  Raises ``ValueError`` where the pixels pass
+    :data:`MAX_BINARY_BYTES`: the caller cuts such rows into several."""
+
+    def __init__(self, origins: Sequence[str],
+                 shapes: Sequence[Optional[Tuple[ImageType, int, int]]]):
+        n = len(shapes)
+        self._origins = list(origins)
+        self._dtypes = [s and s[0].dtype for s in shapes]
+        self.valid = np.array([s is not None for s in shapes], dtype=bool)
+        # columns: height, width, nChannels, mode
+        self._dims = np.zeros((n, 4), dtype=np.int32)
+        ends = np.zeros(n + 1, dtype=np.int64)
+        for i, s in enumerate(shapes):
+            if s is not None:
+                t, h, w = s
+                self._dims[i] = (h, w, t.nChannels, t.ord)
+                ends[i + 1] = int(h) * int(w) * t.nChannels * t.itemsize
+        np.cumsum(ends, out=ends)
+        if ends[-1] > MAX_BINARY_BYTES:
+            raise ValueError(
+                f"{n} image rows hold {int(ends[-1])} bytes of pixels; one "
+                f"Arrow binary array holds {MAX_BINARY_BYTES}")
+        self._offsets = ends.astype(np.int32)
+        self._values = np.empty(int(ends[-1]), dtype=np.uint8)
+
+    @classmethod
+    def of_arrays(cls, arrays: Sequence[Optional[np.ndarray]],
+                  origins: Sequence[str]) -> "ImageRowBuffers":
+        """Laid out from [H,W,C] (or [H,W]) arrays, ``None`` for a null
+        row, and each array copied into its row."""
+        arrays = [a if a is None or a.ndim != 2 else a[:, :, None]
+                  for a in (a if a is None else np.asarray(a)
+                            for a in arrays)]
+        rows = cls(origins, [
+            a if a is None else (_infer_image_type(a), a.shape[0], a.shape[1])
+            for a in arrays])
+        for i, a in enumerate(arrays):
+            if a is not None:
+                np.copyto(rows.view(i), a)
+        return rows
+
+    def view(self, i: int) -> np.ndarray:
+        """Row ``i``'s pixels as a writable [H,W,C] array of its dtype."""
+        h, w, c, _ = self._dims[i]
+        row = self._values[self._offsets[i]:self._offsets[i + 1]]
+        return row.view(self._dtypes[i]).reshape(h, w, c)
+
+    def drop(self, i: int) -> None:
+        """Make row ``i`` a null struct after all: its decoder failed past
+        the header.  Its bytes stay in the buffer, under the null."""
+        self.valid[i] = False
+        self._dims[i] = 0
+
+    def to_arrow(self) -> pa.StructArray:
+        n = len(self.valid)
+        data = pa.Array.from_buffers(
+            pa.binary(), n,
+            [None, pa.py_buffer(self._offsets), pa.py_buffer(self._values)],
+            null_count=0)
+        origins = pa.array(
+            [o if ok else "" for o, ok in zip(self._origins, self.valid)],
+            type=pa.string())
+        scalars = [pa.array(np.ascontiguousarray(self._dims[:, k]))
+                   for k in range(4)]
+        # a validity bitmap only where a row is null, as pa.array builds it
+        mask = None if self.valid.all() else pa.array(~self.valid)
+        return pa.StructArray.from_arrays(
+            [origins] + scalars + [data], fields=list(imageSchema), mask=mask)
+
+
+def imageStructArray(arrays: Sequence[Optional[np.ndarray]],
+                     origins: Sequence[str]) -> pa.StructArray:
+    """The ``imageSchema`` struct array of a record batch from [H,W,C] (or
+    [H,W]) arrays of uint8 or float32 — ``None`` for a null row — equal to
+    ``pa.array([imageArrayToStruct(a, o) ...], type=imageSchema)``.  Every
+    pixel is copied once, into the array's own values buffer: no
+    ``tobytes``, no dict a row."""
+    return ImageRowBuffers.of_arrays(arrays, origins).to_arrow()
 
 
 def imageStructToArray(struct: dict) -> np.ndarray:

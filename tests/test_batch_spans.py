@@ -118,8 +118,9 @@ TABLE = [
     ("io.read_images", 1, (), {"files", "rows", "null_rows", "partitions"}),
     ("io.read", 1, UNDER_READ, {"files", "bytes"}),
     ("io.decode", 1, UNDER_READ, {"rows", "failed", "workers"}),
-    ("io.to_arrow", 1, UNDER_READ, {"rows", "bytes"}),
-    ("io.repartition", 1, UNDER_READ, {"rows", "partitions"}),
+    ("io.to_arrow", 1, UNDER_READ, {"rows", "bytes", "direct_rows"}),
+    ("io.repartition", 1, UNDER_READ,
+     {"rows", "partitions", "copied_bytes"}),
     ("transform.run", 1, (),
      {"rows", "valid_rows", "model", "batch_size"}),
     ("engine.pad", 1, UNDER_PREPARE, {"rows", "pad_rows"}),
@@ -190,8 +191,12 @@ def test_attrs_add_up(job, jpeg_dir, tiny_resnet):
     assert attrs(read, "io.read_images") == [
         {"files": FILES, "rows": FILES, "null_rows": 0, "partitions": 1}]
     (to_arrow,) = attrs(read, "io.to_arrow")
-    assert to_arrow["rows"] == FILES
+    # every row's flip wrote into the frame's own buffer; at
+    # numPartitions=1 that buffer is the partition: nothing copied after
+    assert (to_arrow["rows"], to_arrow["direct_rows"]) == (FILES, FILES)
     assert to_arrow["bytes"] >= FILES * 20 * 24 * 3
+    assert attrs(read, "io.repartition") == [
+        {"rows": FILES, "partitions": 1, "copied_bytes": 0}]
     assert attrs(run, "engine.pad") == [
         {"rows": FILES - BATCH, "pad_rows": 2 * BATCH - FILES}]
     assert pad_delta == 2 * BATCH - FILES

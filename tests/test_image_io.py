@@ -124,8 +124,12 @@ def tracer():
     obs.configure_from_env()
 
 
+def _spans(tracer, name):
+    return [s for s in tracer.snapshot() if s["name"] == name]
+
+
 def _decode_span(tracer):
-    (span,) = [s for s in tracer.snapshot() if s["name"] == "io.decode"]
+    (span,) = _spans(tracer, "io.decode")
     return span
 
 
@@ -136,26 +140,330 @@ def _ipc_bytes(table):
     return sink.getvalue().to_pybytes()
 
 
+def _partitions_of(table, n):
+    """``table`` copied into ``n`` chunks as equal as whole rows allow,
+    the larger first (``None``: left in its record batches of 256)."""
+    if n is None:
+        return table
+    rows = table.num_rows
+    n = max(1, min(n, rows))
+    sizes = [rows // n + (i < rows % n) for i in range(n)]
+    whole = table.combine_chunks()
+    return pa.concat_tables([whole.slice(sum(sizes[:i]), size)
+                             for i, size in enumerate(sizes)])
+
+
+def _chunk_sizes(table):
+    return [len(chunk) for chunk in table.column("image").chunks]
+
+
+def _data_bytes(table):
+    return [None if row is None else row["data"]
+            for row in table.column("image").to_pylist()]
+
+
+def _assert_same_frame(got, want):
+    """``got`` is ``want`` by value, null for null and chunk for chunk."""
+    assert got.schema == want.schema
+    assert got.equals(want)
+    assert _data_bytes(got) == _data_bytes(want)
+    assert got.column("image").null_count == want.column("image").null_count
+    assert _chunk_sizes(got) == _chunk_sizes(want)
+
+
+@pytest.mark.parametrize("partitions", [None, 1, 3, 7])
 @pytest.mark.parametrize("files,corrupt", [
     (1, None), (3, None), (12, None), (12, 6), (260, 257)],
     ids=["1_serial", "3_serial", "12_pooled", "12_one_corrupt",
          "260_two_record_batches"])
 def test_read_images_table_is_the_serial_decodes_table(tmp_path, rng,
-                                                       files, corrupt):
-    """Pooled or not, ``readImages`` gives byte for byte the table of
-    ``[PIL_decode(b) for b in blobs]`` in file-name order, the null
-    struct at the corrupt file's row."""
+                                                       files, corrupt,
+                                                       partitions):
+    """Pooled or not, built partition by partition or in record batches
+    of 256, ``readImages`` gives byte for byte the table that
+    ``imageArrayToStruct`` + ``pa.array`` give from ``[PIL_decode(b) for
+    b in blobs]`` in file-name order, the null struct at the corrupt
+    file's row — down to the Arrow IPC stream, so to which rows and
+    which chunks carry a validity bitmap."""
     paths = _jpeg_dir(tmp_path, rng, files, corrupt)
     serial = [PIL_decode(_read(p)) for p in paths]
     assert [i for i, arr in enumerate(serial) if arr is None] == (
         [] if corrupt is None else [corrupt])
-    want = _table_of(paths, serial)
-    got = readImages(str(tmp_path)).table
-    assert got.equals(want)
+    want = _partitions_of(_table_of(paths, serial), partitions)
+    got = readImages(str(tmp_path), numPartitions=partitions).table
+    _assert_same_frame(got, want)
     assert got.column("image").null_count == (corrupt is not None)
-    assert _ipc_bytes(got) == _ipc_bytes(want)
-    assert _ipc_bytes(readImages(str(tmp_path), numPartitions=1).table) \
-        == _ipc_bytes(want.combine_chunks())
+    if partitions in (None, 1):     # a slice's IPC stream is its own
+        assert _ipc_bytes(got) == _ipc_bytes(want)
+
+
+def _png(path, arr, mode):
+    from PIL import Image
+
+    Image.fromarray(arr, mode).save(path)
+
+
+GREY = np.arange(6 * 7, dtype=np.uint8).reshape(6, 7)
+
+
+@pytest.mark.parametrize("decoded", [
+    lambda i: GREY + i,
+    lambda i: (GREY + i)[:, :, None],
+    lambda i: np.stack([GREY + i] * 4, axis=2),
+    lambda i: np.stack([GREY + i] * 3, axis=2).astype(np.float32) / 7,
+    lambda i: np.stack([GREY + i] * 3, axis=2)[:, ::-1],
+    lambda i: (np.full((3 + i, 2, 1), i, np.uint8) if i % 2
+               else np.full((2, 3 + i, 3), i / 3, np.float32)),
+], ids=["grey_2d", "grey_1_channel", "bgra", "float32", "not_contiguous",
+        "sizes_and_dtypes_mixed_in_one_batch"])
+@pytest.mark.parametrize("partitions", [None, 2])
+def test_a_callers_arrays_are_copied_once_into_the_buffer(
+        tmp_path, rng, tracer, decoded, partitions):
+    """Whatever supported dtype and channel count a caller's ``decode_f``
+    returns, the frame is the table of ``imageArrayToStruct`` +
+    ``pa.array`` — built from buffers (``direct_rows``), a ``None`` a
+    null struct in its place."""
+    paths = _jpeg_dir(tmp_path, rng, 6)
+    arrays = [None if i == 4 else decoded(i) for i in range(6)]
+    by_blob = {_read(p): arr for p, arr in zip(paths, arrays)}
+    got = readImagesWithCustomFn(str(tmp_path), by_blob.__getitem__,
+                                 numPartitions=partitions).table
+    _assert_same_frame(
+        got, _partitions_of(_table_of(paths, arrays), partitions))
+    (to_arrow,) = _spans(tracer, "io.to_arrow")
+    assert to_arrow["attrs"]["direct_rows"] == to_arrow["attrs"]["rows"] == 6
+    # one record batch of 6 rows is sliced in two where it lies
+    (repartition,) = _spans(tracer, "io.repartition")
+    assert repartition["attrs"]["copied_bytes"] == 0
+
+
+@pytest.mark.parametrize("partitions,copied", [(None, False), (1, True),
+                                                (2, True)])
+def test_a_callers_decoder_leaves_the_copy_to_repartition(
+        tmp_path, rng, tracer, partitions, copied):
+    """The sizes a caller's ``decode_f`` will give are not known before
+    it ran: its frame is built in record batches of 256 files, and where
+    those are not the partitions asked for, ``repartition`` joins them —
+    every byte of the frame copied once more, and counted."""
+    paths = _jpeg_dir(tmp_path, rng, 260, corrupt=3)
+
+    def decode_f(blob):
+        return PIL_decode(blob)
+
+    df = readImagesWithCustomFn(str(tmp_path), decode_f,
+                                numPartitions=partitions)
+    want = _table_of(paths, [PIL_decode(_read(p)) for p in paths])
+    _assert_same_frame(df.table, _partitions_of(want, partitions))
+    (repartition,) = _spans(tracer, "io.repartition")
+    assert repartition["attrs"]["copied_bytes"] == (
+        want.nbytes if copied else 0)
+    assert [s["attrs"]["direct_rows"]
+            for s in _spans(tracer, "io.to_arrow")] == [256, 4]
+
+
+def test_the_packages_decoder_turns_grey_and_rgba_files_to_bgr(tmp_path,
+                                                               rng):
+    """Files that are not RGB (a grey JPEG, an RGBA and a palette PNG) go
+    through ``convert('RGB')`` on the pool as in ``PIL_decode``."""
+    from PIL import Image
+
+    paths = _jpeg_dir(tmp_path, rng, 4)
+    Image.fromarray(GREY * 5).save(tmp_path / "img_0004.jpg")
+    _png(tmp_path / "img_0005.png", np.stack([GREY] * 4, axis=2), "RGBA")
+    Image.fromarray(GREY % 4).convert("P").save(tmp_path / "img_0006.png")
+    paths += [str(tmp_path / n) for n in
+              ("img_0004.jpg", "img_0005.png", "img_0006.png")]
+    serial = [PIL_decode(_read(p)) for p in paths]
+    assert all(arr.shape[2] == 3 for arr in serial)
+    got = readImages(str(tmp_path), numPartitions=1).table
+    _assert_same_frame(got, _table_of(paths, serial))
+    assert _ipc_bytes(got) == _ipc_bytes(_table_of(paths, serial))
+
+
+@pytest.mark.parametrize("some_arrays", [False, True],
+                         ids=["dicts", "dicts_and_arrays"])
+def test_a_record_batch_of_struct_dicts_goes_the_old_road(
+        tmp_path, rng, tracer, some_arrays):
+    paths = _jpeg_dir(tmp_path, rng, 6, corrupt=2)
+    serial = [PIL_decode(_read(p)) for p in paths]
+
+    def decode_f(blob):
+        i = [_read(p) for p in paths].index(blob)
+        if serial[i] is None or (some_arrays and i % 2):
+            return serial[i]
+        return imageArrayToStruct(serial[i], origin=paths[i])
+
+    got = readImagesWithCustomFn(str(tmp_path), decode_f).table
+    _assert_same_frame(got, _table_of(paths, serial))
+    (to_arrow,) = _spans(tracer, "io.to_arrow")
+    assert (to_arrow["attrs"]["rows"],
+            to_arrow["attrs"]["direct_rows"]) == (6, 0)
+
+
+def test_a_row_that_fails_after_its_header_is_a_null_in_its_place(
+        tmp_path, rng):
+    """A truncated JPEG: the header gives its size, the decoder fails.
+    Its slot stays in the buffer under a null struct; every other row
+    reads as before, through ``arrowStructsToBatch`` too."""
+    from sparkdl_tpu.image import arrowStructsToBatch
+
+    from PIL import Image
+
+    paths = _jpeg_dir(tmp_path, rng, 8)
+    Image.fromarray((rng.random((64, 64, 3)) * 255).astype("uint8")).save(
+        paths[3], quality=90)
+    whole = _read(paths[3])
+    with open(paths[3], "wb") as fh:
+        fh.write(whole[:len(whole) * 2 // 3])
+    assert image_io._open_image(_read(paths[3])) is not None
+    serial = [PIL_decode(_read(p)) for p in paths]
+    assert [i for i, arr in enumerate(serial) if arr is None] == [3]
+    for partitions in (None, 1, 2):
+        got = readImages(str(tmp_path), numPartitions=partitions).table
+        _assert_same_frame(
+            got, _partitions_of(_table_of(paths, serial), partitions))
+    batch, ok = arrowStructsToBatch(got.column("image"), 9, 11)
+    want, _ = arrowStructsToBatch(
+        _table_of(paths, serial).column("image"), 9, 11)
+    assert list(ok) == [i != 3 for i in range(8)]
+    np.testing.assert_array_equal(batch, want)
+
+
+def test_a_header_that_gives_another_size_than_the_decoder(tmp_path, rng,
+                                                          monkeypatch):
+    """Where an image decodes to another size than its header said (ICO
+    can), the record batch is laid out again from the decoded sizes."""
+    paths = _jpeg_dir(tmp_path, rng, 8, corrupt=1)
+    serial = [PIL_decode(_read(p)) for p in paths]
+    rgb_pixels = image_io._rgb_pixels
+
+    def cropped(img):
+        rgb = rgb_pixels(img)
+        return rgb[1:] if rgb.shape[0] == serial[5].shape[0] else rgb
+
+    monkeypatch.setattr(image_io, "_rgb_pixels", cropped)
+    want = [arr if arr is None or arr.shape[0] != serial[5].shape[0]
+            else arr[1:] for arr in serial]
+    assert sum(a is not b for a, b in zip(want, serial)) >= 1
+    got = readImages(str(tmp_path), numPartitions=1).table
+    _assert_same_frame(got, _table_of(paths, want))
+
+
+@pytest.mark.parametrize("partitions", [None, 1, 3])
+def test_pixels_past_the_int32_limit_are_cut_into_chunks_under_it(
+        tmp_path, rng, monkeypatch, tracer, partitions):
+    """One Arrow binary array holds 2 GiB: a partition (or a lazy record
+    batch) whose pixels pass that comes as several chunks, each under the
+    limit and as long as the limit allows, the rows in order."""
+    from sparkdl_tpu.image import schema
+
+    paths = _jpeg_dir(tmp_path, rng, 12, corrupt=4)
+    serial = [PIL_decode(_read(p)) for p in paths]
+    monkeypatch.setattr(schema, "MAX_BINARY_BYTES", 1500)
+    got = readImages(str(tmp_path), numPartitions=partitions).table
+    want = _table_of(paths, serial)
+    assert got.equals(want) and _data_bytes(got) == _data_bytes(want)
+    sizes = _chunk_sizes(got)
+    assert len(sizes) > (partitions or 1) and sum(sizes) == 12
+    nbytes = [0 if arr is None else arr.nbytes for arr in serial]
+    bounds = np.cumsum([0] + sizes)
+    held = [sum(nbytes[lo:hi]) for lo, hi in zip(bounds, bounds[1:])]
+    assert max(held) <= 1500
+    # no chunk could have taken the next one's first row as well, unless
+    # an asked partition ends there
+    asked = set(np.cumsum(_chunk_sizes(
+        _partitions_of(want, partitions or 1))))
+    assert all(h + nbytes[hi] > 1500 or hi in asked
+               for h, hi in zip(held[:-1], bounds[1:-1]))
+    assert all(s["attrs"]["direct_rows"] == s["attrs"]["rows"]
+               for s in _spans(tracer, "io.to_arrow"))
+    (repartition,) = _spans(tracer, "io.repartition")
+    assert repartition["attrs"]["copied_bytes"] == 0
+    lazy = list(image_io.iterImageBatches(str(tmp_path), batch_size=12))
+    assert [rb.num_rows for rb in lazy] == _chunk_sizes(
+        readImages(str(tmp_path), numPartitions=1).table)
+    assert pa.Table.from_batches(lazy).equals(want)
+    # one image over the limit fits no array at all
+    monkeypatch.setattr(schema, "MAX_BINARY_BYTES", 100)
+    with pytest.raises(ValueError, match="bytes of pixels"):
+        readImages(str(tmp_path))
+    with pytest.raises(ValueError, match="bytes of pixels"):
+        schema.imageStructArray([serial[0]], ["o"])
+
+
+def _buffer_addresses(table):
+    return [buf.address for chunk in table.column("image").chunks
+            for buf in chunk.buffers() if buf is not None]
+
+
+@pytest.mark.parametrize("partitions", [1, 3, 12, 40])
+def test_repartition_of_chunks_that_fit_copies_nothing(tmp_path, rng,
+                                                       tracer, partitions):
+    """``readImages`` builds the partitions asked for, so its
+    ``repartition`` has nothing to do (``copied_bytes``), and a second
+    ``repartition`` to the same count hands back the same frame: the same
+    buffers.  To another count it gives an equal frame, copied."""
+    paths = _jpeg_dir(tmp_path, rng, 12, corrupt=7)
+    df = readImages(str(tmp_path), numPartitions=partitions)
+    (span,) = _spans(tracer, "io.repartition")
+    assert span["attrs"] == {"rows": 12, "partitions": min(partitions, 12),
+                             "copied_bytes": 0}
+    again = df.repartition(partitions)
+    assert again is df
+    other = df.repartition(5)
+    assert _chunk_sizes(other.table) == [3, 3, 2, 2, 2]
+    assert other.table.equals(df.table)
+    # one chunk is sliced where it lies; several are joined first
+    shared = set(_buffer_addresses(other.table)) \
+        & set(_buffer_addresses(df.table))
+    assert bool(shared) == (partitions == 1)
+    back = other.repartition(partitions)
+    _assert_same_frame(back.table, df.table)
+    serial = [PIL_decode(_read(p)) for p in paths]
+    assert df.table.equals(_table_of(paths, serial))
+
+
+def test_repartition_looks_at_every_column(rng):
+    """A frame is left as it is only if EVERY column's chunks fit."""
+    from sparkdl_tpu.frame import DataFrame
+
+    a = pa.chunked_array([[1, 2], [3, 4]])
+    b = pa.chunked_array([[1, 2, 3], [4]])
+    even = DataFrame(pa.table({"a": a, "b": a}))
+    assert even.repartition(2) is even
+    uneven = DataFrame(pa.Table.from_arrays([a, b], names=["a", "b"]))
+    out = uneven.repartition(2)
+    assert out is not uneven and out.table.equals(uneven.table)
+    assert [len(c) for c in out.table.column("b").chunks] == [2, 2]
+    empty = DataFrame(pa.table({"a": pa.array([], type=pa.int64())}))
+    assert len(empty.repartition(3)) == 0
+
+
+def test_image_struct_array_is_pa_array_of_the_structs(rng):
+    """``imageStructArray`` against the row-level helper it stands in
+    for: equal by value and in the IPC stream, nulls and all, and no
+    validity bitmap where no row is null."""
+    from sparkdl_tpu.image.schema import imageStructArray
+
+    arrays = [(rng.random((5, 4, 3)) * 255).astype(np.uint8), None,
+              rng.random((3, 3, 1)).astype(np.float32),
+              (rng.random((2, 6)) * 255).astype(np.uint8)]
+    origins = [f"mem://{i}" for i in range(4)]
+    for rows in (slice(0, 4), slice(2, 4), slice(0, 0), slice(1, 2)):
+        got = imageStructArray(arrays[rows], origins[rows])
+        want = pa.array(
+            [None if a is None else imageArrayToStruct(a, origin=o)
+             for a, o in zip(arrays[rows], origins[rows])], type=imageSchema)
+        assert got.type == imageSchema and got.equals(want)
+        assert got.to_pylist() == want.to_pylist()
+        assert _ipc_bytes(pa.table({"image": got})) \
+            == _ipc_bytes(pa.table({"image": want}))
+        got.validate(full=True)
+    assert imageStructArray(arrays[2:], origins[2:]).buffers()[0] is None
+    with pytest.raises(ValueError, match="dtype"):
+        imageStructArray([np.zeros((2, 2, 3), np.int16)], ["o"])
+    with pytest.raises(ValueError, match="channel count"):
+        imageStructArray([np.zeros((2, 2, 2), np.uint8)], ["o"])
 
 
 def test_read_images_has_two_rows_in_flight_at_once(tmp_path, rng,
@@ -169,12 +477,14 @@ def test_read_images_has_two_rows_in_flight_at_once(tmp_path, rng,
     barrier = threading.Barrier(2)
     idents = []
 
-    def met_by_another(blob):
+    decode_into = image_io._decode_into
+
+    def met_by_another(*args):
         barrier.wait(timeout=30)
         idents.append(threading.get_ident())
-        return PIL_decode(blob)
+        return decode_into(*args)
 
-    monkeypatch.setattr(image_io, "PIL_decode", met_by_another)
+    monkeypatch.setattr(image_io, "_decode_into", met_by_another)
     got = readImages(str(tmp_path)).table
     decode = _decode_span(tracer)
     assert not barrier.broken
