@@ -702,9 +702,7 @@ def bench_config1_e2e():
     """The user path: JPEG bytes -> decode+resize -> streaming featurize."""
     from sparkdl_tpu.image.io import decodeResizeBatch
     from sparkdl_tpu.parallel.engine import InferenceEngine
-    from sparkdl_tpu.parallel.pipeline import (pipeline_enabled_from_env,
-                                               pipeline_stage_summary)
-    from sparkdl_tpu.utils.prefetch import prefetch_iter
+    from sparkdl_tpu.parallel.pipeline import pipeline_stage_summary
 
     fn, variables, (h, w) = _zoo_fn("InceptionV3", featurize=True)
     eng = InferenceEngine(fn, variables, device_batch_size=BATCH,
@@ -724,11 +722,8 @@ def bench_config1_e2e():
     w0, _ = decodeResizeBatch(blobs[:eng.device_batch_size], h, w)
     list(eng.map_batches([w0]))
     # the pipelined engine's prepare thread pulls the decode iterator
-    # itself; prefetch_iter is only needed on the serial escape hatch
-    feed = (chunks() if pipeline_enabled_from_env()
-            else prefetch_iter(chunks(), depth=2))
     t0 = time.perf_counter()
-    outs = list(eng.map_batches(feed))
+    outs = list(eng.map_batches(chunks()))
     elapsed = time.perf_counter() - t0
     rows = sum(o.shape[0] for o in outs)
     assert rows == n
@@ -1022,7 +1017,7 @@ out["metrics_snapshot"] = metrics_snapshot(m)
 
 def bench_pipeline():
     """Pipelined host/device overlap on the synthetic slow device:
-    speedup vs the serial path (SPARKDL_PIPELINE=0 equivalent) plus the
+    speedup vs the calling-thread path (``pipeline=False``) plus the
     per-stage stall/occupancy ledger.  The tier-1 contract
     (tests/test_pipeline.py) asserts >= 1.5x on this same benchmark."""
     prof = _run_chipless(_PIPELINE_BENCH)

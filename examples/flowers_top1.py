@@ -68,7 +68,6 @@ def main(argv=None):
     from sparkdl_tpu.image.io import filesToModelBatch
     from sparkdl_tpu.models import get_model_spec
     from sparkdl_tpu.parallel.engine import InferenceEngine
-    from sparkdl_tpu.utils.prefetch import prefetch_iter
 
     # perf_counter, not time.time(): "seconds" is an elapsed-time
     # measurement and wall clock can step under NTP slew (SDL006)
@@ -107,8 +106,7 @@ def main(argv=None):
                       f"{bad[0]})", file=sys.stderr)
             yield batch
 
-    feats = np.concatenate(
-        list(eng.map_batches(prefetch_iter(chunks(), depth=2))), axis=0)
+    feats = np.concatenate(list(eng.map_batches(chunks())), axis=0)
 
     # Split and fit the head (the reference used Spark ML LogisticRegression
     # on the driver; ours trains data-parallel on the mesh).

@@ -11,12 +11,12 @@
 # below keeps that wiring from silently regressing if the file moves.
 # Likewise tests/test_pipeline.py carries the pipelined-execution overlap
 # contract (synthetic 100 ms slow device on the CPU backend, >= 1.5x vs
-# SPARKDL_PIPELINE=0, bit-identical outputs): fast, chip-free, tier-1.
+# pipeline=False, bit-identical outputs): fast, chip-free, tier-1.
 #
 # Everything that needs the real chip lives OUTSIDE this gate:
 # `python chip_smoke.py` on the chip machine is the bring-up proof (it
-# fails here by design — no accelerator), bench.py and
-# tools/perf_experiments.py are the measurements.
+# fails here by design — no accelerator), benchmark/run.py is the
+# measurement.
 #
 # Usage: ./run-tests.sh [extra pytest args]
 set -euo pipefail

@@ -67,10 +67,8 @@ def zoo_dispatch_specs(max_batch_size: int = 32,
     program exactly as ``_zoo_engine`` + ``InferenceEngine`` build it
     (fused preprocess, compute-dtype cast, replicated params, data-axis
     batch sharding) — the featurizer cut at every compiled shape in the
-    serving bucket plan, the predictor cut (``Server(featurize=False)``,
-    the serving default) at the largest bucket, and the grouped
-    ``batches_per_dispatch`` ``lax.map`` program for one representative
-    model."""
+    serving bucket plan, and the predictor cut (``Server(featurize=
+    False)``, the serving default) at the largest bucket."""
     import jax.numpy as jnp
 
     from sparkdl_tpu.models import SUPPORTED_MODELS, get_model_spec
@@ -97,27 +95,19 @@ def zoo_dispatch_specs(max_batch_size: int = 32,
             memo[name] = av
         return memo[name]
 
-    def build(name: str, bucket: int, featurize: bool, group_k: int = 0):
+    def build(name: str, bucket: int, featurize: bool):
         def _build():
             import jax
             import numpy as np
 
-            from sparkdl_tpu.parallel.engine import (
-                build_dispatch_jit, build_grouped_dispatch_jit)
+            from sparkdl_tpu.parallel.engine import build_dispatch_jit
             from sparkdl_tpu.transformers.named_image import zoo_model_fn
 
             mspec = get_model_spec(name)
             fn = zoo_model_fn(name, featurize=featurize, compute_dtype=cdt)
             h, w = mspec.input_size
-            if group_k:
-                jitted = build_grouped_dispatch_jit(
-                    fn, mesh, donate_batch=False,
-                    batches_per_dispatch=group_k)
-                batch = jax.ShapeDtypeStruct((group_k, bucket, h, w, 3),
-                                             np.uint8)
-            else:
-                jitted = build_dispatch_jit(fn, mesh, donate_batch=False)
-                batch = jax.ShapeDtypeStruct((bucket, h, w, 3), np.uint8)
+            jitted = build_dispatch_jit(fn, mesh, donate_batch=False)
+            batch = jax.ShapeDtypeStruct((bucket, h, w, 3), np.uint8)
             return jitted, (avals(name), batch)
 
         return _build
@@ -145,19 +135,6 @@ def zoo_dispatch_specs(max_batch_size: int = 32,
             batch_rows=pb,
             shardings=("replicated", "batch"),
             group=f"zoo/{canonical}/predict/{compute_dtype}", **base))
-    # the grouped lax.map dispatch program (SPARKDL_BATCHES_PER_DISPATCH
-    # > 1): the wrapper is model-independent, so ONE representative
-    # (MobileNetV2, the cheapest trace) at a FIXED canonical shape
-    # (b32 x k4, like the train specs) — stable across subset audits,
-    # so narrowed runs still line up with the committed baseline
-    rep = "MobileNetV2"
-    if any(get_model_spec(n).name == rep for n in names):
-        specs.append(ProgramSpec(
-            name=f"zoo/{rep}/featurize/{compute_dtype}/b32xk4",
-            build=build(rep, 32, featurize=True, group_k=4),
-            batch_rows=32 * 4,
-            shardings=("replicated", "stacked_batch"),
-            group=f"zoo/{rep}/featurize/{compute_dtype}/grouped", **base))
     return specs
 
 

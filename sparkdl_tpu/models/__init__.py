@@ -36,9 +36,8 @@ class ModelSpec:
     feature_size: int                          # featurizer-cut dimensionality
     preprocess_mode: str                       # see models.preprocess
     keras_app: str                             # keras.applications attr name
-    # () -> str tag when module_builder reads process env (e.g. the
-    # InceptionV3 s2d-stem knob); caches keyed on the model name must fold
-    # this tag in (model_variant_key) or they serve stale variants.
+    # () -> str tag when module_builder reads process env; caches keyed
+    # on the model name fold it in (model_variant_key).
     variant_key_fn: Optional[Callable[[], str]] = None
 
     @property
@@ -218,28 +217,10 @@ def _populate():
         feature_size=2048, preprocess_mode="tf", keras_app="Xception",
         variant_key_fn=lambda: "tiled" if _xc_tiled_enabled() else ""),
         xception_auto_order)
-    def _inception_builder():
-        # SPARKDL_S2D_STEM=1 computes stem_conv1 via space-to-depth
-        # (identical variables/math, better MXU occupancy — inception.py);
-        # SPARKDL_FUSED_HEADS=0 disables the branch-head conv fusion
-        # (default: on at inference — inception.py fused_heads)
-        return InceptionV3(s2d_stem=_s2d_stem_enabled(),
-                           fused_heads=None if _fused_heads_enabled()
-                           else False)
-
-    def _inception_variant():
-        tags = []
-        if _s2d_stem_enabled():
-            tags.append("s2d")
-        if not _fused_heads_enabled():
-            tags.append("nofh")
-        return "+".join(tags)
-
     _registry.register(ModelSpec(
-        name="InceptionV3", module_builder=_inception_builder,
+        name="InceptionV3", module_builder=InceptionV3,
         input_size=(299, 299),
-        feature_size=2048, preprocess_mode="tf", keras_app="InceptionV3",
-        variant_key_fn=_inception_variant),
+        feature_size=2048, preprocess_mode="tf", keras_app="InceptionV3"),
         inception_import_order)
     # Beyond the reference's five: edge/efficiency-class backbones (see
     # mobilenet.py / efficientnet.py).
@@ -287,14 +268,6 @@ def _env_flag(name: str, default: bool) -> bool:
     return raw not in ("0", "false")
 
 
-def _s2d_stem_enabled() -> bool:
-    return _env_flag("SPARKDL_S2D_STEM", False)
-
-
-def _fused_heads_enabled() -> bool:
-    return _env_flag("SPARKDL_FUSED_HEADS", True)
-
-
 def _xc_tiled_enabled() -> bool:
     return _env_flag("SPARKDL_XC_TILED", False)
 
@@ -308,14 +281,9 @@ def _mnv2_fused_enabled() -> bool:
 
 
 def model_variant_key(name: str) -> str:
-    """Environment-dependent build-variant tag for ``name``.
-
-    When a spec's ``module_builder`` reads process env (today:
-    ``SPARKDL_S2D_STEM`` for InceptionV3, via its ``variant_key_fn``), a
-    cache keyed on the model name alone would keep serving the
-    previously-built variant after the env var is toggled.  Cache owners
-    must include this tag in their keys.
-    """
+    """Environment-dependent build-variant tag for ``name`` (its spec's
+    ``variant_key_fn``).  Cache owners fold it into their keys, so that a
+    flag flipped mid-process rebuilds instead of serving the old build."""
     spec = _registry.get(name)
     return spec.variant_key_fn() if spec.variant_key_fn is not None else ""
 

@@ -156,24 +156,16 @@ def inception_import_order():
 
 
 class InceptionV3(nn.Module):
-    """``s2d_stem``: compute ``stem_conv1`` (3x3/s2/VALID on the 3-channel
-    input — 3/128 MXU lane occupancy) via the space-to-depth transform
-    (``layers.SpaceToDepthConv``): same variables, same math (allclose
-    parity pinned in tests/test_models.py), different XLA program.  Off by
-    default; the registry builder enables it when ``SPARKDL_S2D_STEM=1``.
-    Measured delta on the bench is recorded in PERF.md.
-
-    ``fused_heads``: at inference, the 2-3 LEADING 1x1 convs of each mixed
+    """``fused_heads``: at inference, the 2-3 LEADING 1x1 convs of each mixed
     block's branches (which all read the same block input) run as ONE
     wider conv — kernels concatenated along output channels, BN folded
     into the kernel/shift, one ReLU, then split.  Identical math and
     variables (``ConvBN(fold=True)`` declares the same tree); attacks the
     "many small matmuls" MFU story the round-4 profile documented (no
-    single fusion >4% of device time).  None = on at inference; disable
-    with ``SPARKDL_FUSED_HEADS=0`` (registry builder) for A/B runs."""
+    single fusion >4% of device time).  None = on at inference; False
+    keeps the per-branch (training) program: the parity test's reference."""
 
     num_classes: int = 1000
-    s2d_stem: bool = False
     fused_heads: Optional[bool] = None
 
     def _use_fused_heads(self, train: bool) -> bool:
@@ -206,8 +198,6 @@ class InceptionV3(nn.Module):
                     x = ConvBN(op.filters, (op.kh, op.kw), strides=op.strides,
                                padding=op.padding, bn_eps=1e-3,
                                bn_scale=False,
-                               s2d=(self.s2d_stem
-                                    and op.name == "stem_conv1"),
                                name=op.name)(x, train=train)
                 elif isinstance(op, P):
                     x = pool(x, op)

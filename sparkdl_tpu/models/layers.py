@@ -214,70 +214,9 @@ class DepthwiseConv2D(nn.Module):
         return y
 
 
-class SpaceToDepthConv(nn.Module):
-    """Stride-``s`` VALID conv computed as space-to-depth + stride-1 conv.
-
-    A stem conv reads a 3-channel input, occupying 3/128 MXU lanes; folding
-    each s x s spatial block into channels multiplies lane occupancy by s^2
-    while computing the *same* function: the kernel is zero-padded to a
-    multiple of the stride and re-blocked so every original tap lands on
-    the matching input pixel (the MLPerf-era TPU stem transform).  Declares
-    the IDENTICAL ``kernel`` param as ``nn.Conv(use_bias=False)`` — same
-    name, shape, and init — so a model can route the same variables through
-    either path and weight import is unaffected.
-
-    Only ``padding="VALID"`` with block == stride is supported (what
-    InceptionV3's ``stem_conv1`` needs); odd input extents are zero-padded,
-    which is exact because the padded taps multiply zero kernel rows.
-    """
-
-    features: int
-    kernel_size: Tuple[int, int]
-    strides: Tuple[int, int]
-
-    @nn.compact
-    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
-        import jax.lax as lax
-
-        kh, kw = self.kernel_size
-        bh, bw = self.strides
-        n, h, w, cin = x.shape
-        kernel = self.param("kernel", nn.initializers.lecun_normal(),
-                            (kh, kw, cin, self.features))
-        dtype = x.dtype
-        hp = -(-h // bh) * bh
-        wp = -(-w // bw) * bw
-        khp = -(-kh // bh) * bh
-        kwp = -(-kw // bw) * bw
-        xpad = jnp.pad(x, ((0, 0), (0, hp - h), (0, wp - w), (0, 0)))
-        # [n, hp/bh, wp/bw, bh*bw*cin]: channel index (dy*bw + dx)*cin + c
-        xs = xpad.reshape(n, hp // bh, bh, wp // bw, bw, cin).transpose(
-            0, 1, 3, 2, 4, 5).reshape(n, hp // bh, wp // bw, bh * bw * cin)
-        k4 = jnp.pad(jnp.asarray(kernel, dtype),
-                     ((0, khp - kh), (0, kwp - kw), (0, 0), (0, 0)))
-        # k2[by,bx,(dy*bw+dx)*cin+c,o] = k4[by*bh+dy, bx*bw+dx, c, o]
-        k2 = k4.reshape(khp // bh, bh, kwp // bw, bw, cin, self.features
-                        ).transpose(0, 2, 1, 3, 4, 5).reshape(
-            khp // bh, kwp // bw, bh * bw * cin, self.features)
-        out = lax.conv_general_dilated(
-            xs, k2, window_strides=(1, 1), padding="VALID",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"))
-        # blocked VALID yields ceil(h/bh)-ceil(kh/bh)+1 rows; the reference
-        # conv yields (h-kh)//bh + 1.  They differ (by one trailing row of
-        # padded-tap output) when kh % bh == 0 and h % bh != 0 — slice to
-        # the reference extent so parity holds for every supported config.
-        oh = (h - kh) // bh + 1
-        ow = (w - kw) // bw + 1
-        return out[:, :oh, :ow, :]
-
-
 class ConvBN(nn.Module):
     """``conv2d_bn`` from keras.applications.inception_v3: Conv(no bias) +
-    BatchNorm(scale=False) + ReLU.
-
-    ``s2d=True`` routes the conv through :class:`SpaceToDepthConv`
-    (identical variables, identical math, better MXU occupancy for
-    few-channel stems); requires VALID padding."""
+    BatchNorm(scale=False) + ReLU."""
 
     features: int
     kernel_size: Tuple[int, int]
@@ -285,7 +224,6 @@ class ConvBN(nn.Module):
     padding: str = "SAME"
     bn_eps: float = BN_EPS_DEFAULT
     bn_scale: bool = False
-    s2d: bool = False
 
     @nn.compact
     def __call__(self, x: jnp.ndarray, train: bool = False,
@@ -300,14 +238,9 @@ class ConvBN(nn.Module):
             s, t = BNAffine(epsilon=self.bn_eps, use_scale=self.bn_scale,
                             name="bn")(self.features)
             return kernel, s, t
-        if self.s2d:
-            assert self.padding == "VALID", "s2d requires VALID padding"
-            x = SpaceToDepthConv(self.features, self.kernel_size,
-                                 self.strides, name="conv")(x)
-        else:
-            x = nn.Conv(self.features, self.kernel_size,
-                        strides=self.strides, padding=self.padding,
-                        use_bias=False, name="conv")(x)
+        x = nn.Conv(self.features, self.kernel_size,
+                    strides=self.strides, padding=self.padding,
+                    use_bias=False, name="conv")(x)
         x = nn.BatchNorm(use_running_average=not train,
                          momentum=BN_MOMENTUM_DEFAULT, epsilon=self.bn_eps,
                          use_scale=self.bn_scale, name="bn")(x)

@@ -14,8 +14,7 @@ from sparkdl_tpu.parallel.engine import (CircuitOpenError,
                                          InferenceEngine)
 from sparkdl_tpu.parallel.pipeline import (PipelinedRunner,
                                            PipelineStageError,
-                                           PipelineStageFatalError,
-                                           pipeline_enabled_from_env)
+                                           PipelineStageFatalError)
 from sparkdl_tpu.parallel import distributed
 
 __all__ = [
@@ -28,6 +27,5 @@ __all__ = [
     "batch_sharding",
     "distributed",
     "get_mesh",
-    "pipeline_enabled_from_env",
     "replicated_sharding",
 ]

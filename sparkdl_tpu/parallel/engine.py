@@ -20,9 +20,8 @@ Throughput design:
     input size (both ``map_batches`` and ``__call__``);
   * host stages overlap the device by default: prepare (decode/pack/pad),
     H2D+dispatch, and D2H gather run on worker threads with backpressure
-    queues (``parallel.pipeline.PipelinedRunner``; ``SPARKDL_PIPELINE=0``
-    restores the serial path) — batch k+1 decodes while batch k computes
-    and batch k-1 gathers, bit-identically to the serial path.
+    queues (``parallel.pipeline.PipelinedRunner``), bit-identically to
+    the calling-thread path (``pipeline=False``).
 """
 
 from __future__ import annotations
@@ -37,8 +36,7 @@ from sparkdl_tpu.faults import inject
 from sparkdl_tpu.obs.flight import emit as flight_emit
 from sparkdl_tpu.obs.trace import get_tracer
 from sparkdl_tpu.parallel import mesh as mesh_lib
-from sparkdl_tpu.parallel.pipeline import (PipelinedRunner,
-                                           pipeline_enabled_from_env)
+from sparkdl_tpu.parallel.pipeline import PipelinedRunner
 from sparkdl_tpu.utils.logging import get_logger
 from sparkdl_tpu.utils.metrics import Metrics
 from sparkdl_tpu.utils.retry import NON_RETRYABLE, with_retries
@@ -277,30 +275,6 @@ def build_dispatch_jit(fn: Callable, mesh, donate_batch: bool,
         fn,
         in_shardings=(params_sh, mesh_lib.batch_sharding(mesh)),
         out_shardings=mesh_lib.batch_sharding(mesh),
-        donate_argnums=(1,) if donate_batch else ())
-
-
-def build_grouped_dispatch_jit(fn: Callable, mesh, donate_batch: bool,
-                               batches_per_dispatch: int,
-                               param_shardings=None):
-    """The grouped (``batches_per_dispatch`` > 1) dispatch program: one
-    ``lax.map`` launch over a stacked leading group axis.  Shared with
-    ``analysis.program`` exactly like :func:`build_dispatch_jit`;
-    ``param_shardings`` has the same semantics."""
-    import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    group_sh = NamedSharding(mesh, P(None, mesh_lib.DATA_AXIS))
-
-    def fn_group(v, xs):
-        return jax.lax.map(lambda x: fn(v, x), xs)
-
-    params_sh = (param_shardings if param_shardings is not None
-                 else mesh_lib.replicated_sharding(mesh))
-    return jax.jit(
-        fn_group,
-        in_shardings=(params_sh, group_sh),
-        out_shardings=group_sh,
         donate_argnums=(1,) if donate_batch else ())
 
 
@@ -695,16 +669,6 @@ class HeadBank:
         return out
 
 
-def batches_per_dispatch_from_env() -> int:
-    """``SPARKDL_BATCHES_PER_DISPATCH`` (clamped to >= 1) — the one
-    parser every engine-constructing site shares, so cache keys and
-    defaults cannot drift."""
-    import os
-
-    raw = os.environ.get("SPARKDL_BATCHES_PER_DISPATCH", "") or "1"
-    return max(1, int(raw))
-
-
 def _is_narrow_float(dtype) -> bool:
     """True iff ``dtype`` is an ml_dtypes narrow float (bf16/f8 families).
 
@@ -785,7 +749,6 @@ class InferenceEngine:
                  donate_batch: bool = False,
                  partition_rules: Any = None,
                  param_shardings: Any = None,
-                 batches_per_dispatch: int = 1,
                  dispatch_retries: int = 0,
                  dispatch_backoff_s: float = 0.05,
                  dispatch_max_backoff_s: float = 2.0,
@@ -836,17 +799,6 @@ class InferenceEngine:
         self.breaker = DispatchCircuitBreaker(
             threshold=breaker_threshold, cooldown_s=breaker_cooldown_s)
         self._on_dispatch_error = on_dispatch_error
-
-        # k host batches per compiled dispatch (lax.map over a stacked
-        # leading group axis): one launch + one result fetch per k batches
-        # — the inference analog of the train loop's steps_per_execution.
-        # Identical per-batch math (lax.map is a scan, not a vmap, so
-        # nothing about the batch dimension the model sees changes); wins
-        # whenever dispatch/fetch latency rivals compute (small models,
-        # multi-host pods).  k=1 is the plain program.  map_batches scales
-        # its in-flight window to max(1, window // k) GROUPS so grouping
-        # does not silently multiply peak device residency by ~k.
-        self.batches_per_dispatch = max(1, int(batches_per_dispatch))
 
         if compute_dtype is not None:
             variables = _cast_floating(variables, compute_dtype)
@@ -919,25 +871,13 @@ class InferenceEngine:
                     tuple(self.mesh.axis_names),
                     tuple(self.mesh.devices.shape), bool(donate_batch),
                     self.sharding_digest)
-        key = (id(fn),) + mesh_key + (1,)
+        key = (id(fn),) + mesh_key
         compiled = _JIT_CACHE.get(key)
         if compiled is None:
             compiled = build_dispatch_jit(fn, self.mesh, donate_batch,
                                           param_shardings=self.param_shardings)
             _JIT_CACHE.put(key, compiled)
-        # the plain per-batch program always exists: it runs run_padded
-        # and the ragged tail group (cheaper than padding a group with
-        # full zero batches that would execute the whole model)
         self._compiled = compiled
-        if self.batches_per_dispatch > 1:
-            gkey = (id(fn),) + mesh_key + (self.batches_per_dispatch,)
-            grouped = _JIT_CACHE.get(gkey)
-            if grouped is None:
-                grouped = build_grouped_dispatch_jit(
-                    fn, self.mesh, donate_batch, self.batches_per_dispatch,
-                    param_shardings=self.param_shardings)
-                _JIT_CACHE.put(gkey, grouped)
-            self._compiled_group = grouped
 
     # -- low level ---------------------------------------------------------
     @staticmethod
@@ -975,7 +915,7 @@ class InferenceEngine:
         # NOTE: success is NOT recorded here.  Dispatch is an async
         # ENQUEUE — a dying device usually raises when the result is
         # forced (D2H), so the attempt is only known good at force time
-        # (_force_parts), which records the breaker success.
+        # (_force_part), which records the breaker success.
         return out
 
     def _charge_breaker(self, e: BaseException, counter: str) -> None:
@@ -993,37 +933,24 @@ class InferenceEngine:
         if self._on_dispatch_error is not None:
             self._on_dispatch_error(e)
 
-    def _force_parts(self, ns, out, block=None):
-        """Force one in-flight dispatch to host row batch(es) — the D2H
-        fetch + trim shared verbatim by the serial drain and the
-        pipelined gather stage (``ns`` int = plain piece; tuple = a
-        grouped dispatch, fetched once and sliced host-side).
+    def _force_part(self, n, out, block=None):
+        """Force one in-flight dispatch to its ``n`` real host rows: the
+        D2H fetch + trim of both the calling-thread drain and the
+        pipelined gather stage.
 
-        This is the OTHER failure surface of an async dispatch: jax's
-        enqueue returns before the device runs, so a dying device
-        typically raises here, not in ``_attempt_dispatch`` — errors are
-        charged to the same breaker/health accounting (no retry: a
-        failed force cannot be re-run without re-dispatching), and a
-        successful force is what records breaker success.  ``block``
-        (the gather span's ``block_until_ready``) forces device
-        completion inside the caller's span so device wait stays
-        attributed."""
-        import jax
-
+        The OTHER failure surface of an async dispatch: jax's enqueue
+        returns before the device runs, so a dying device typically
+        raises here, not in ``_attempt_dispatch``.  Errors are charged to
+        the same breaker/health accounting (no retry: a failed force
+        cannot be re-run without re-dispatching); a successful force
+        records breaker success.  ``block`` (the gather span's
+        ``block_until_ready``) waits for the device inside the caller's
+        span so device wait stays attributed."""
         try:
             inject("engine.gather")
             if block is not None:
                 block(out)
-            if isinstance(ns, int):
-                parts = [self._trim(out, ns)]
-            else:
-                # one D2H fetch for the whole group, sliced on the host
-                # (per-batch device slicing would pay k fetch round
-                # trips — the latency the grouping exists to amortize)
-                host = jax.tree_util.tree_map(np.asarray, out)
-                parts = [self._trim(jax.tree_util.tree_map(
-                    lambda a, i=i: a[i], host), n)
-                    for i, n in enumerate(ns)]
+            part = self._trim(out, n)
         except NON_RETRYABLE:
             self.breaker.release_trial()
             raise
@@ -1031,7 +958,7 @@ class InferenceEngine:
             self._charge_breaker(e, "engine.gather_errors")
             raise
         self.breaker.record_success()
-        return parts
+        return part
 
     def _run_dispatch(self, thunk):
         """Dispatch with the engine's transient-fault retry budget:
@@ -1167,8 +1094,7 @@ class InferenceEngine:
         return jax.tree_util.tree_map(lambda a: a[off:off + size], batch)
 
     # -- whole-array API ---------------------------------------------------
-    def __call__(self, batch, window: int = 2,
-                 pipeline: Optional[bool] = None,
+    def __call__(self, batch, window: int = 2, pipeline: bool = True,
                  on_metered=None):
         """Process a full batch (array or pytree); returns host output with
         matching row count.
@@ -1179,24 +1105,20 @@ class InferenceEngine:
         per-engine so concurrent batches on one shared bucket engine
         each observe their own span.
 
-        Host-memory contract: the pipelined path (``pipeline=True``, the
-        ``SPARKDL_PIPELINE`` default) PREALLOCATES the output — the leaf
-        output shape is fixed by the single compiled program, so after the
-        first gathered chunk the full ``[n, ...]`` result buffer is
-        allocated once and every later chunk is copied into it and
-        released.  Peak host residency is therefore the output itself plus
-        O(window + depth) chunks, never a second whole-output's worth of
-        accumulated parts (the serial path concatenates a per-chunk list,
-        which transiently doubles the output footprint).  Either way the
-        OUTPUT still materializes in host RAM — route multi-million-row
-        frames through ``map_batches`` streaming instead.
+        Host-memory contract: the pipelined path (the default)
+        PREALLOCATES the output — the one compiled program fixes the leaf
+        shapes, so after the first gathered chunk the ``[n, ...]`` result
+        is allocated once and every later chunk is copied into it.  Peak
+        host residency is the output plus O(window + depth) chunks
+        (``pipeline=False`` concatenates a per-chunk list, which
+        transiently doubles the output).  Either way the OUTPUT
+        materializes in host RAM — route multi-million-row frames through
+        ``map_batches`` instead.
 
-        Chunks run through the same bounded in-flight window as
-        ``map_batches`` (chunk k+1 transfers/computes while chunk k is
-        gathered), so device residency is O(window x device_batch) even
-        for huge inputs.  Pipelined outputs are bit-identical to serial
-        ones (same programs, same pad/trim, same order); inputs that fit
-        one device batch skip the worker threads entirely — nothing to
+        Chunks run through ``map_batches``' bounded in-flight window, so
+        device residency is O(window x device_batch) for any input, and
+        pipelined outputs are bit-identical to serial ones.  An input
+        that fits one device batch skips the worker threads — nothing to
         overlap — so serving-sized calls pay no thread latency.
         """
         import time
@@ -1207,11 +1129,9 @@ class InferenceEngine:
         n = self._leaves(batch)
         if n == 0:
             raise ValueError("Empty input batch")
-        use_pipe = (pipeline_enabled_from_env() if pipeline is None
-                    else bool(pipeline))
         t0 = time.perf_counter()
         with get_tracer().span("engine.call", rows=n):
-            if not use_pipe or n <= self.device_batch_size:
+            if not pipeline or n <= self.device_batch_size:
                 outs = list(self.map_batches([batch], window=window,
                                              pipeline=False))
                 result = jax.tree_util.tree_map(
@@ -1247,107 +1167,49 @@ class InferenceEngine:
             on_metered(elapsed)
         return result
 
-    def _stack_group(self, pieces):
-        """Host half of a grouped dispatch: pad each of the
-        ``batches_per_dispatch`` ``pieces`` and stack them on a leading
-        group axis; returns (true_row_counts, stacked_host_batch)."""
-        import jax
-
-        ns = tuple(self._leaves(p) for p in pieces)
-        stacked = jax.tree_util.tree_map(
-            lambda *parts: np.stack(parts, axis=0),
-            *[self._pad(p) for p in pieces])
-        return ns, stacked
-
-    def _dispatch_group(self, stacked):
-        """Device half of a grouped dispatch: H2D transfer + ONE stacked
-        lax.map launch; returns the device output."""
-        import jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        sh = NamedSharding(self.mesh, P(None, mesh_lib.DATA_AXIS))
-
-        def attempt():
-            with get_tracer().span("engine.dispatch",
-                                   group=self.batches_per_dispatch):
-                return self._compiled_group(self.variables,
-                                            self._h2d(stacked, sh))
-
-        return self._run_dispatch(attempt)
-
     # -- streaming API -----------------------------------------------------
     def map_batches(self, batches: Iterable[Any], window: int = 2,
-                    pipeline: Optional[bool] = None) -> Iterator[Any]:
+                    pipeline: bool = True) -> Iterator[Any]:
         """Map over an iterator of host batches with a bounded in-flight
         window (double buffering by default): batch k+1 transfers/computes
-        while batch k is gathered.  With ``batches_per_dispatch`` = k > 1
-        the in-flight unit is a GROUP of k stacked batches (one launch,
-        ONE host fetch per group), so the effective window is scaled to
-        ``max(1, window // k)`` groups — peak device residency stays
-        O(window x device_batch) in HOST-BATCH terms instead of growing
-        ~k-fold with the dispatch grouping.  A ragged tail group runs its
-        pieces through the plain per-batch program instead of padding
-        with whole zero batches.
+        while batch k is gathered.
 
-        ``pipeline`` (default: the ``SPARKDL_PIPELINE`` env knob, ON)
-        runs host prepare, H2D+dispatch, and D2H gather on overlapping
-        worker threads (:class:`~sparkdl_tpu.parallel.pipeline.
+        ``pipeline`` runs host prepare, H2D+dispatch and D2H gather on
+        three threads (:class:`~sparkdl_tpu.parallel.pipeline.
         PipelinedRunner`): the input iterator — typically the decode
-        stage — is pulled on its own thread while the device computes and
-        a third thread gathers, with the same bounded window and
-        BIT-IDENTICAL outputs.  ``pipeline=False`` (or
-        ``SPARKDL_PIPELINE=0``) keeps everything on the calling thread."""
-        use_pipe = (pipeline_enabled_from_env() if pipeline is None
-                    else bool(pipeline))
-        if use_pipe:
+        stage — is pulled on its own; ``pipeline=False`` keeps everything
+        on the calling thread, with BIT-IDENTICAL outputs."""
+        if pipeline:
             return PipelinedRunner(self, window=window).run(batches)
         return self._map_batches_serial(batches, window)
 
     def _iter_pieces(self, batches: Iterable[Any]) -> Iterator[tuple]:
-        """THE host-prepare sequence, shared verbatim by the serial path
-        and the pipelined runner's prepare stage (so their dispatch order
-        is identical by construction): slice chunks into device-batch
-        pieces and pad them, stacking full ``batches_per_dispatch``
-        groups; yields ``("plain", n_rows, padded_piece)`` /
-        ``("group", n_rows_tuple, stacked_group)`` in dispatch order.
-        The ragged tail group runs its pieces through the plain per-batch
-        program instead of padding with whole zero batches."""
+        """THE host-prepare sequence of both the calling-thread path and
+        the runner's prepare stage: slice chunks into device-batch pieces
+        and pad them; yields ``(n_rows, padded_piece)`` in dispatch order."""
         import jax
 
-        group: list = []
         for chunk in batches:
             chunk = jax.tree_util.tree_map(np.asarray, chunk)
             n = self._leaves(chunk)
             for off in range(0, n, self.device_batch_size):
                 piece = self._slice(chunk, off, self.device_batch_size)
-                if self.batches_per_dispatch == 1:
-                    yield ("plain", self._leaves(piece), self._pad(piece))
-                else:
-                    group.append(piece)
-                    if len(group) == self.batches_per_dispatch:
-                        yield ("group",) + self._stack_group(group)
-                        group = []
-        for piece in group:  # ragged tail: plain program, no zero batches
-            yield ("plain", self._leaves(piece), self._pad(piece))
+                yield self._leaves(piece), self._pad(piece)
 
     def _map_batches_serial(self, batches: Iterable[Any],
                             window: int = 2) -> Iterator[Any]:
-        """The single-threaded path (``SPARKDL_PIPELINE=0``): identical
-        piece order and programs, no worker threads."""
+        """The calling-thread path: same pieces, same program, no threads."""
         from collections import deque
 
-        if self.batches_per_dispatch > 1:
-            window = max(1, int(window) // self.batches_per_dispatch)
         inflight: deque = deque()
 
         def drain(limit):
             while len(inflight) > limit:
-                ns, out = inflight.popleft()
-                yield from self._force_parts(ns, out)
+                n, out = inflight.popleft()
+                yield self._force_part(n, out)
 
-        for kind, ns, host in self._iter_pieces(batches):
-            inflight.append((ns, self.run_padded(host) if kind == "plain"
-                             else self._dispatch_group(host)))
+        for n, host in self._iter_pieces(batches):
+            inflight.append((n, self.run_padded(host)))
             yield from drain(window)
         yield from drain(0)
 
@@ -1366,11 +1228,8 @@ def get_cached_engine(holder, model_function, *, device_batch_size: int,
     The cache entry pins the ModelFunction alive so id-keying cannot alias
     a recycled object.
     """
-    engine_kwargs.setdefault("batches_per_dispatch",
-                             batches_per_dispatch_from_env())
     cache = holder.__dict__.setdefault("_engine_cache", {})
-    key = (id(model_function), device_batch_size,
-           engine_kwargs["batches_per_dispatch"])
+    key = (id(model_function), device_batch_size)
     entry = cache.get(key)
     if entry is None:
         eng = InferenceEngine(model_function.fn, model_function.variables,

@@ -14,21 +14,16 @@ dispatch provides the device-side overlap; this layer provides the
 host-side one.
 
 Contracts:
-  * BIT-IDENTICAL outputs to the serial path — the stages call the exact
-    same engine methods (``_pad``/``run_padded``/``_stack_group``/
-    ``_dispatch_group``/``_trim``) in the exact same per-piece order; the
-    FIFO queues only move them onto threads.
-  * bounded residency — every inter-stage queue is bounded, so host prep
-    runs at most ``depth`` items ahead and at most ``window`` dispatched
-    batches (groups under ``batches_per_dispatch``) are device-resident,
-    exactly the serial path's in-flight window.
+  * BIT-IDENTICAL outputs to the serial path (``pipeline=False``) — the
+    stages call the same engine methods (``_pad``/``run_padded``/
+    ``_trim``) in the same per-piece order; the FIFO queues only move
+    them onto threads.
+  * bounded residency — host prep runs at most ``depth`` items ahead and
+    at most ``window`` dispatched batches are device-resident, the
+    serial path's in-flight window.
   * per-stage queue-depth / stall metrics land in the engine's
     ``utils.metrics.Metrics`` registry under ``pipeline.*`` (surfaced by
     ``bench.py`` per-config JSON lines and ``Server.stats``).
-
-``SPARKDL_PIPELINE=0`` is the escape hatch: every scoring surface
-(``InferenceEngine.map_batches``/``__call__``, the zoo/image/tensor
-transformers, image UDFs, and serving) then runs the serial path.
 
 Failure domain (ISSUE 4): each stage loop carries a fault-injection
 site (``pipeline.prepare`` / ``pipeline.dispatch`` / ``pipeline.gather``
@@ -41,7 +36,6 @@ with a blocked producer/consumer and no thread outlives the run.
 
 from __future__ import annotations
 
-import os
 import queue
 import threading
 import time
@@ -108,21 +102,12 @@ def wrap_stage_error(stage: str, piece: int,
     return cls(stage, piece, cause)
 
 
-def pipeline_enabled_from_env() -> bool:
-    """``SPARKDL_PIPELINE`` (default ON) — the one parser every
-    pipeline-aware call site shares.  ``0``/``false``/``off``/``no``
-    disable the threaded stages and restore the serial path everywhere."""
-    raw = os.environ.get("SPARKDL_PIPELINE", "").strip().lower()
-    return raw not in ("0", "false", "off", "no")
-
-
 class PipelinedRunner:
     """Runs an :class:`~sparkdl_tpu.parallel.engine.InferenceEngine` over
     an iterator of host batches with host prepare, H2D+dispatch, and
     D2H gather on three overlapping threads.
 
-    ``window`` bounds dispatched-but-ungathered device batches (scaled to
-    groups under ``batches_per_dispatch``, mirroring the serial path);
+    ``window`` bounds dispatched-but-ungathered device batches;
     ``depth`` bounds how far host prepare runs ahead of dispatch and how
     many gathered host outputs wait for the consumer.  Peak residency is
     therefore O(depth) prepared + O(window) device + O(depth) gathered
@@ -132,11 +117,7 @@ class PipelinedRunner:
     def __init__(self, engine, window: int = 2, depth: int = 2,
                  metrics: Optional[Metrics] = None):
         self.engine = engine
-        bpd = engine.batches_per_dispatch
-        w = max(1, int(window))
-        # same scaling as the serial path: with grouped dispatch the
-        # in-flight unit is a k-batch GROUP, so the window counts groups
-        self.window = max(1, w // bpd) if bpd > 1 else w
+        self.window = max(1, int(window))
         self.depth = max(1, int(depth))
         self.metrics = metrics if metrics is not None else engine.metrics
 
@@ -240,16 +221,14 @@ class PipelinedRunner:
                     if item is _DONE:
                         break
                     idx += 1
-                    kind, ns, host = item
+                    n, host = item
                     inject("pipeline.dispatch", piece=idx)
                     # H2D + async launch: returns as soon as the transfer
                     # is enqueued; the device computes while we loop
-                    with tracer.span("pipeline.dispatch",
-                                     parent=run_span, kind=kind):
-                        dev = (eng.run_padded(host) if kind == "plain"
-                               else eng._dispatch_group(host))
+                    with tracer.span("pipeline.dispatch", parent=run_span):
+                        dev = eng.run_padded(host)
                     m.incr("pipeline.dispatches")
-                    if not self._put(disp_q, (kind, ns, dev), stop,
+                    if not self._put(disp_q, (n, dev), stop,
                                      "dispatch", "inflight_q"):
                         return
                 self._put(disp_q, _DONE, stop, "dispatch", "inflight_q")
@@ -267,31 +246,26 @@ class PipelinedRunner:
                     if item is _DONE:
                         break
                     idx += 1
-                    kind, ns, dev = item
+                    n, dev = item
                     inject("pipeline.gather", piece=idx)
                     # span covers device wait + D2H + trim, NOT the
-                    # downstream puts (backpressure is a separate story
-                    # told by pipeline.gather_out_stall_s); when tracing
-                    # is on, block_until_ready splits device wait
-                    # (device_us) from the host-side copy/cast.  The
-                    # force itself is the engine's OWN shared
-                    # _force_parts (identical to the serial drain, and
-                    # the point where force-time device errors charge
-                    # the breaker/health accounting).
-                    with tracer.span("pipeline.gather", parent=run_span,
-                                     kind=kind) as sp:
-                        parts = eng._force_parts(
-                            ns, dev, block=sp.block_until_ready)
+                    # downstream put (pipeline.gather_out_stall_s tells
+                    # backpressure); when tracing is on,
+                    # block_until_ready splits device wait (device_us)
+                    # from the host-side copy/cast.  The force is the
+                    # serial drain's own _force_part, where device
+                    # errors charge the breaker/health accounting.
+                    with tracer.span("pipeline.gather", parent=run_span) as sp:
+                        part = eng._force_part(
+                            n, dev, block=sp.block_until_ready)
                         if tracer.enabled:
                             sp.annotate(
-                                rows=ns if kind == "plain" else sum(ns),
+                                rows=n,
                                 bytes=sum(
                                     a.nbytes for a in
-                                    jax.tree_util.tree_leaves(parts)))
-                    for part in parts:
-                        if not self._put(out_q, part, stop, "gather",
-                                         "out_q"):
-                            return
+                                    jax.tree_util.tree_leaves(part)))
+                    if not self._put(out_q, part, stop, "gather", "out_q"):
+                        return
                     m.incr("pipeline.gathers")
                 self._put(out_q, _DONE, stop, "gather", "out_q")
             # graftlint: allow=SDL003 reason=recorded via fail() and re-raised consumer-side as PipelineStageError

@@ -45,7 +45,7 @@ logger = get_logger(__name__)
 
 def ragged_enabled_from_env() -> bool:
     """``SPARKDL_RAGGED`` (default ON) — the one parser every
-    ragged-aware call site shares (the ``SPARKDL_PIPELINE`` pattern).
+    ragged-aware call site shares.
     ``0``/``false``/``off``/``no`` restore the flush-on-full baseline:
     an age-triggered flush takes everything waiting and pads it into
     the nearest bucket."""

@@ -7,7 +7,7 @@ into a bounded queue, assembled into dynamic micro-batches
 sizes so the engine's jit executable cache stays warm (a handful of
 compiled shapes, never one per request count), dispatched through the
 existing :class:`~sparkdl_tpu.parallel.engine.InferenceEngine` (same
-grouped-dispatch substrate and per-controller mesh policy), and
+dispatch program and per-controller mesh policy), and
 demultiplexed back to per-request futures.
 
 Production envelope:
@@ -219,8 +219,7 @@ class Server:
     bit-identical to batching the same inputs through
     ``InferenceEngine.map_batches`` at the same padded shape, regardless
     of arrival order or which micro-batch a request lands in (across
-    DIFFERENT bucket shapes results agree to XLA-refusion tolerance, the
-    same caveat as the engine's own grouped dispatch).
+    DIFFERENT bucket shapes results agree to XLA-refusion tolerance).
 
     Parameters beyond the batcher knobs:
       * ``bucket_sizes`` — padded dispatch sizes (default quarter/half/

@@ -97,7 +97,7 @@ class StreamScorer:
                  max_poll_backoff_s: float = 0.25,
                  seed: int = 0,
                  window: int = 2,
-                 pipeline: Optional[bool] = None,
+                 pipeline: bool = True,
                  slos: Optional[Any] = None,
                  cache: Any = None,
                  cache_namespace: Optional[Any] = None,

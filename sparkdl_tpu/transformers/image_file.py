@@ -97,16 +97,10 @@ class ImageFileTransformer(PersistableModelFunctionMixin, Transformer,
     def _transform(self, dataset):
         from itertools import chain
 
-        from sparkdl_tpu.parallel.pipeline import pipeline_enabled_from_env
-        from sparkdl_tpu.utils.prefetch import prefetch_iter
-
         valid_idx: List[int] = []
-        chunks = self._loaded_chunks(dataset, max(1, self.getBatchSize()),
-                                     valid_idx)
-        # under the pipelined engine its prepare thread pulls the loader
-        # iterator; the explicit prefetch hop is the serial fallback's
-        it = (iter(chunks) if pipeline_enabled_from_env()
-              else prefetch_iter(chunks, depth=2))
+        # the engine's runner pulls this on its prepare thread
+        it = iter(self._loaded_chunks(dataset, max(1, self.getBatchSize()),
+                                      valid_idx))
         first = next(it, None)
         outs = []
         if first is not None:

@@ -28,9 +28,7 @@ from sparkdl_tpu.param.converters import SparkDLTypeConverters
 from sparkdl_tpu.param.params import Param, TypeConverters, keyword_only
 from sparkdl_tpu.param.shared import (HasBatchSize, HasInputCol, HasModelName,
                                       HasOutputCol, HasOutputMode, HasTopK)
-from sparkdl_tpu.parallel.engine import (InferenceEngine,
-                                         batches_per_dispatch_from_env,
-                                         get_cached_engine)
+from sparkdl_tpu.parallel.engine import InferenceEngine, get_cached_engine
 from sparkdl_tpu.persistence import PersistableModelFunctionMixin
 from sparkdl_tpu.transformers.base import Transformer
 from sparkdl_tpu.utils.logging import get_logger
@@ -50,8 +48,7 @@ def clear_model_caches():
 
 
 def _cached_model(name: str):
-    # key includes the env-dependent build variant (e.g. SPARKDL_S2D_STEM)
-    # so toggling the knob mid-process rebuilds instead of serving stale
+    # key includes the env-dependent build variant (model_variant_key)
     key = (name, model_variant_key(name))
     if key not in _MODEL_CACHE:
         _MODEL_CACHE[key] = load_model(name)
@@ -182,9 +179,7 @@ def _zoo_engine(name: str, featurize: bool, batch_size: int) -> InferenceEngine:
     f32 end-to-end and the parity oracles are f32.
     """
     cdt_name = zoo_compute_dtype_name()
-    bpd = batches_per_dispatch_from_env()
-    key = (name, model_variant_key(name), featurize, batch_size, cdt_name,
-           bpd)
+    key = (name, model_variant_key(name), featurize, batch_size, cdt_name)
     eng = _ENGINE_CACHE.get(key)
     if eng is None:
         import jax.numpy as jnp
@@ -195,7 +190,6 @@ def _zoo_engine(name: str, featurize: bool, batch_size: int) -> InferenceEngine:
         eng = InferenceEngine(
             fn, variables, device_batch_size=batch_size,
             compute_dtype=cdt,
-            batches_per_dispatch=bpd,
             output_host_dtype=np.float32 if cdt is not None else None)
         _ENGINE_CACHE[key] = eng
     return eng
@@ -218,8 +212,8 @@ class _ImageInputStage(Transformer, HasInputCol, HasOutputCol, HasBatchSize):
     The decode is STREAMING: the column is consumed one record batch at a
     time (the analog of the reference's per-partition hot loop, SURVEY.md
     §3.1) — at no point does a whole-dataset ``[N,H,W,3]`` array exist.
-    Host decode of chunk k+1 runs on a prefetch thread while the device
-    computes chunk k, and the engine bounds in-flight device buffers."""
+    Host decode of chunk k+1 runs on the runner's prepare thread while the
+    device computes chunk k, and the engine bounds in-flight device buffers."""
 
     def _first_valid_struct(self, dataset) -> Optional[dict]:
         """First non-null image struct, without materializing the column."""
@@ -284,25 +278,13 @@ class _ImageInputStage(Transformer, HasInputCol, HasOutputCol, HasBatchSize):
         nothing when no row decodes.  The engine (weights + compile) is
         only built once the first decoded chunk proves there is work to
         do.  Consumers that pack outputs incrementally (image mode) keep
-        peak host residency at O(chunk), not O(dataset).
-
-        Decode/compute overlap: under the default pipelined engine
-        (``SPARKDL_PIPELINE``) the runner's own prepare thread pulls the
-        decode iterator while the device computes and a gather thread
-        fetches — wrapping the decode in ``prefetch_iter`` too would only
-        add a queue hop, so the explicit prefetch is reserved for the
-        serial escape hatch."""
+        peak host residency at O(chunk), not O(dataset)."""
         from itertools import chain
 
         import time
 
-        from sparkdl_tpu.parallel.pipeline import pipeline_enabled_from_env
-        from sparkdl_tpu.utils.prefetch import prefetch_iter
-
-        chunks = self._decoded_chunks(
-            dataset, height, width, self._chunk_rows(), valid_idx, origins)
-        it = (iter(chunks) if pipeline_enabled_from_env()
-              else prefetch_iter(chunks, depth=2))
+        it = iter(self._decoded_chunks(
+            dataset, height, width, self._chunk_rows(), valid_idx, origins))
         first = next(it, None)
         if first is None:
             return

@@ -147,10 +147,9 @@ def register_image_udf(name: str, model_function, *,
 
     Pipeline per call: decode/resize image structs on the host (null rows
     stay null) -> [optional jax ``preprocessor``] ∘ model in one jit program
-    on the mesh.  Scoring rides the engine's pipelined execution path
-    (``SPARKDL_PIPELINE``): a multi-batch column overlaps H2D, compute,
-    and gather across chunks, and the output matrix is preallocated and
-    streamed into rather than accumulated per chunk.
+    on the mesh.  Scoring rides the engine's pipelined path: a multi-batch
+    column overlaps H2D, compute, and gather across chunks, and the output
+    matrix is preallocated and streamed into, not accumulated per chunk.
     """
     from sparkdl_tpu.graph.function import ModelFunction
     from sparkdl_tpu.image.io import arrowStructsToBatch, structsToBatch

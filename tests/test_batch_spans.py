@@ -126,7 +126,7 @@ TABLE = [
     ("engine.pad", 1, UNDER_PREPARE, {"rows", "pad_rows"}),
     ("engine.h2d", 2,
      ("engine.dispatch", "pipeline.dispatch") + UNDER_RUN, {"bytes"}),
-    ("pipeline.gather", 2, UNDER_RUN, {"kind", "rows", "bytes"}),
+    ("pipeline.gather", 2, UNDER_RUN, {"rows", "bytes"}),
     ("transform.pack_out", 1, ("transform.run",), {"rows", "values"}),
 ]
 
@@ -298,13 +298,17 @@ def test_dropped_counts_evictions_and_clear_resets_it():
 
 def test_serial_path_yields_the_same_names_without_pipeline(
         jpeg_dir, tiny_resnet, monkeypatch):
+    from sparkdl_tpu.parallel.engine import InferenceEngine
+
     _, piped, features, _ = _one_job(jpeg_dir)
-    monkeypatch.setenv("SPARKDL_PIPELINE", "0")
+    map_batches = InferenceEngine.map_batches
+    monkeypatch.setattr(
+        InferenceEngine, "map_batches",
+        lambda self, batches: map_batches(self, batches, pipeline=False))
     _, serial, serial_features, _ = _one_job(jpeg_dir)
     names = lambda spans: {s["name"] for s in spans}  # noqa: E731
     assert names(serial) == {n for n in names(piped)
                              if not n.startswith("pipeline.")}
-    # the prefetch thread's spans hang under the caller's: one trace still
     assert len({s["trace_id"] for s in serial}) == 1
     assert set(_chains(serial, "transform.pack_in")) == {
         ("transform.pack_in", "transform.run")}

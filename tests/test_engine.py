@@ -84,108 +84,44 @@ def test_engine_streaming_window(setup):
     np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-6)
 
 
-def test_engine_batches_per_dispatch_matches_plain(setup):
-    """Grouped dispatch (k host batches per compiled program via lax.map
-    — the inference analog of steps_per_execution) returns EXACTLY the
-    plain engine's outputs: same rows, same order, ragged tail groups
-    and ragged final batches included."""
-    variables, x, ref = setup
-    plain = InferenceEngine(_fn, variables, device_batch_size=16)
-    grouped = InferenceEngine(_fn, variables, device_batch_size=16,
-                              batches_per_dispatch=3)
-    # 45 rows / 16 = 3 pieces -> one full group of 3 (third piece ragged)
-    # (allclose, not equal: the grouped program's op order differs at the
-    # last ulp, same as any XLA re-fusion)
-    np.testing.assert_allclose(grouped(x), plain(x), rtol=1e-5, atol=1e-6)
-    # streaming, multiple chunks, tail group of 2 of 3: 5 pieces total
-    chunks = [x[:20], x[20:41], x[41:]]
-    got = list(grouped.map_batches(iter(chunks)))
-    want = list(plain.map_batches(iter(chunks)))
-    assert len(got) == len(want)
-    for g, w in zip(got, want):
-        assert g.shape == w.shape
-        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(np.concatenate(got), ref, rtol=1e-5,
-                               atol=1e-6)
+def _pieces_case(name):
+    """chunks, and the real rows of the pieces they must be cut into."""
+    rng = np.random.default_rng(4)
+    x = rng.normal(size=(23, 12)).astype(np.float32)
+    if name == "array":         # 2.5 device batches
+        return [x[:20]], [8, 8, 4]
+    if name == "pytree":
+        ids = np.arange(20, dtype=np.int32)
+        return [{"x": x[:20], "ids": ids}], [8, 8, 4]
+    # a piece never spans two chunks: each chunk's tail is padded alone
+    return [x[:20], x[20:]], [8, 8, 4, 3]
 
 
-def test_engine_batches_per_dispatch_tail_uses_plain_program(setup,
-                                                             monkeypatch):
-    """A ragged tail group must run its pieces through the plain
-    per-batch program — not pad the group with whole zero batches that
-    would execute the full model for nothing."""
-    variables, x, _ = setup
-    eng = InferenceEngine(_fn, variables, device_batch_size=16,
-                          batches_per_dispatch=3)
-    calls = {"group": 0, "plain": 0}
-    orig_group, orig_plain = eng._dispatch_group, eng.run_padded
-    monkeypatch.setattr(eng, "_dispatch_group", lambda s: (
-        calls.__setitem__("group", calls["group"] + 1), orig_group(s))[1])
-    monkeypatch.setattr(eng, "run_padded", lambda b: (
-        calls.__setitem__("plain", calls["plain"] + 1), orig_plain(b))[1])
-    # serial path pinned: the call-count choreography under test is the
-    # single-threaded one (test_pipeline covers the threaded analog)
-    out = eng(np.concatenate([x, x[:19]]), pipeline=False)  # 4 pieces: 3+1
-    assert out.shape[0] == 64
-    assert calls == {"group": 1, "plain": 1}
+@pytest.mark.parametrize("name", ["array", "pytree", "two chunks"])
+def test_iter_pieces_yields_rows_and_padded_pieces(setup, name):
+    """``_iter_pieces``, the one host-prepare sequence both paths consume:
+    ``(n_rows, padded_piece)`` in dispatch order, every piece of the
+    compiled leading size, the real rows first and zeros behind them."""
+    import jax
 
-
-def test_engine_grouped_dispatch_scales_inflight_window(setup, monkeypatch):
-    """With batches_per_dispatch=k the in-flight unit is a k-batch GROUP,
-    so the effective window must scale to max(1, window // k) groups —
-    otherwise grouping silently multiplies peak device residency ~k-fold
-    (advisor round-5).  window=2, k=3 -> at most 1+1 groups outstanding."""
     variables, _, _ = setup
-    rng = np.random.default_rng(9)
-    x = rng.normal(size=(144, 12)).astype(np.float32)  # 9 pieces, 3 groups
-    ref = np.tanh(x @ variables["w"] + variables["b"])
-    eng = InferenceEngine(_fn, variables, device_batch_size=16,
-                          batches_per_dispatch=3)
-    events = []
-    orig_group, orig_trim = eng._dispatch_group, eng._trim
-    monkeypatch.setattr(eng, "_dispatch_group", lambda s: (
-        events.append("dispatch"), orig_group(s))[1])
-    monkeypatch.setattr(eng, "_trim", lambda o, n: (
-        events.append("trim"), orig_trim(o, n))[1])
-    # serial path pinned: dispatch/trim interleaving on ONE thread is the
-    # invariant under test (the pipelined runner bounds residency with
-    # queue capacities instead — test_pipeline)
-    outs = list(eng.map_batches([x], window=2, pipeline=False))
-    np.testing.assert_allclose(np.concatenate(outs), ref, rtol=1e-5,
-                               atol=1e-6)
-    # every 3rd trim completes one group's gather
-    outstanding = peak = trims = 0
-    for e in events:
-        if e == "dispatch":
-            outstanding += 1
-            peak = max(peak, outstanding)
-        else:
-            trims += 1
-            if trims % 3 == 0:
-                outstanding -= 1
-    assert peak <= 2, events  # max(1, 2 // 3) + the batch being dispatched
+    eng = InferenceEngine(lambda v, b: b, variables, device_batch_size=8)
+    chunks, want_rows = _pieces_case(name)
+    pieces = list(eng._iter_pieces(iter(chunks)))
+    assert [n for n, _ in pieces] == want_rows      # pairs, nothing else
+    for n, padded in pieces:
+        for leaf in jax.tree_util.tree_leaves(padded):
+            assert leaf.shape[0] == 8
+            assert not leaf[n:].any()
 
+    def rows(trees):
+        return jax.tree_util.tree_leaves(jax.tree_util.tree_map(
+            lambda *parts: np.concatenate(parts), *trees))
 
-def test_engine_batches_per_dispatch_pytree(setup):
-    """Grouped dispatch with pytree outputs and integer leaves (argmax
-    ids) — per-leaf group indexing and host-dtype rules must hold."""
-    import jax.numpy as jnp
-
-    variables, x, ref = setup
-
-    def fn(v, xb):
-        y = jnp.tanh(xb @ v["w"] + v["b"])
-        return {"y": y, "ids": jnp.argmax(y, axis=-1)}
-
-    plain = InferenceEngine(fn, variables, device_batch_size=8,
-                            output_host_dtype=np.float32)
-    grouped = InferenceEngine(fn, variables, device_batch_size=8,
-                              batches_per_dispatch=2,
-                              output_host_dtype=np.float32)
-    a, b = plain(x), grouped(x)
-    np.testing.assert_allclose(a["y"], b["y"], rtol=1e-5, atol=1e-6)
-    np.testing.assert_array_equal(a["ids"], b["ids"])
-    assert b["ids"].dtype.kind in "iu"  # never floated
+    real = [jax.tree_util.tree_map(lambda a, n=n: a[:n], padded)
+            for n, padded in pieces]
+    for got, want in zip(rows(real), rows(chunks)):
+        np.testing.assert_array_equal(got, want)
 
 
 def test_engine_multicontroller_mesh_policy(setup, monkeypatch):

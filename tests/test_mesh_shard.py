@@ -151,7 +151,7 @@ def test_engine_sharded_vs_replicated_bit_identical_tp8():
     json.dumps(info)  # varz-embeddable
 
 
-def test_engine_explicit_param_shardings_and_grouped_dispatch():
+def test_engine_explicit_param_shardings_and_default_rules():
     rng = np.random.default_rng(2)
     v = _variables(rng)
     x = rng.normal(size=(64, 16)).astype(np.float32)
@@ -163,13 +163,12 @@ def test_engine_explicit_param_shardings_and_grouped_dispatch():
         param_shardings={"dense": {"kernel": P(None, "model"),
                                    "bias": P()}})
     assert np.array_equal(np.asarray(e_exp(x)), ref)
-    # the grouped (lax.map) program shards the same way
-    e_grp = InferenceEngine(_wide_fn, v, mesh=mesh, device_batch_size=16,
-                            partition_rules=mesh_lib.
-                            default_partition_rules,
-                            batches_per_dispatch=2)
+    # the default rules split the same kernel the same way
+    e_rules = InferenceEngine(_wide_fn, v, mesh=mesh, device_batch_size=16,
+                              partition_rules=mesh_lib.
+                              default_partition_rules)
     got = np.concatenate(
-        list(e_grp.map_batches([x], pipeline=False)), axis=0)
+        list(e_rules.map_batches([x], pipeline=False)), axis=0)
     assert np.array_equal(got, ref)
 
 

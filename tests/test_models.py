@@ -231,76 +231,6 @@ def test_efficientnet_drop_connect():
                mutable=["batch_stats"])
 
 
-def test_space_to_depth_conv_parity():
-    """SpaceToDepthConv == nn.Conv (VALID, stride==block) bit-for-bit at
-    f32 tolerance, across even/odd extents and kernel/stride combos —
-    including InceptionV3's stem shape class (odd 2k+1 extent, 3x3/s2)."""
-    import jax
-    import jax.numpy as jnp
-    from flax import linen as nn
-
-    from sparkdl_tpu.models.layers import SpaceToDepthConv
-
-    rng = np.random.default_rng(3)
-    cases = [
-        ((1, 19, 19, 3), (3, 3), (2, 2), 8),   # odd extent (299-like)
-        ((2, 20, 24, 3), (3, 3), (2, 2), 8),   # even extent
-        ((1, 21, 21, 3), (7, 7), (2, 2), 4),   # kernel > stride*2
-        ((1, 16, 16, 4), (4, 4), (4, 4), 8),   # stride 4, kernel == stride
-        ((1, 13, 17, 2), (3, 5), (2, 2), 3),   # anisotropic kernel
-        # kernel % stride == 0 AND extent % stride != 0: the blocked conv
-        # emits one extra padded-tap row/col that must be sliced off
-        ((1, 9, 9, 3), (2, 2), (2, 2), 4),
-        ((1, 18, 18, 3), (4, 4), (4, 4), 4),
-    ]
-    for shape, ks, st, feats in cases:
-        x = jnp.asarray(rng.normal(size=shape).astype(np.float32))
-        ref_mod = nn.Conv(feats, ks, strides=st, padding="VALID",
-                          use_bias=False)
-        v = ref_mod.init(jax.random.PRNGKey(0), x)
-        ref = ref_mod.apply(v, x)
-        s2d_mod = SpaceToDepthConv(feats, ks, st)
-        got = s2d_mod.apply(v, x)  # SAME variables, by construction
-        assert got.shape == ref.shape, (shape, ks, st)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                   rtol=2e-5, atol=2e-5)
-
-
-def test_inception_s2d_stem_model_parity():
-    """InceptionV3(s2d_stem=True) is the same function as the default
-    model on the same variables (VERDICT r3 #3: the lever must be real,
-    gated, and parity-tested), and the registry env knob builds it."""
-    import jax
-
-    from sparkdl_tpu.models.inception import InceptionV3
-
-    base = InceptionV3()
-    s2d = InceptionV3(s2d_stem=True)
-    rng = np.random.default_rng(11)
-    x = rng.uniform(0, 255, size=(1, 299, 299, 3)).astype(np.float32)
-    x = (x / 127.5) - 1.0
-    variables = jax.jit(
-        lambda r, xx: base.init(r, xx, train=False))(
-        jax.random.PRNGKey(0), x)
-    f_base = jax.jit(lambda v, xx: base.apply(v, xx, train=False,
-                                              features=True))
-    f_s2d = jax.jit(lambda v, xx: s2d.apply(v, xx, train=False,
-                                            features=True))
-    a = np.asarray(f_base(variables, x))
-    b = np.asarray(f_s2d(variables, x))
-    np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
-
-
-def test_inception_s2d_env_gate(monkeypatch):
-    from sparkdl_tpu.models import get_model_spec
-
-    spec = get_model_spec("InceptionV3")
-    monkeypatch.delenv("SPARKDL_S2D_STEM", raising=False)
-    assert spec.build().s2d_stem is False
-    monkeypatch.setenv("SPARKDL_S2D_STEM", "1")
-    assert spec.build().s2d_stem is True
-
-
 def test_inception_fused_heads_parity():
     """InceptionV3 fused branch heads (one wide 1x1 conv per mixed block
     instead of 2-3 narrow ones, BN folded into the kernel) is the same
@@ -364,17 +294,3 @@ def test_resnet_fused_shortcut_parity(monkeypatch):
     monkeypatch.setenv("SPARKDL_RN_FUSED_SHORTCUT", "1")
     assert spec.build().fused_shortcut is True
     assert model_variant_key("ResNet50") == "fsc"
-
-
-def test_inception_fused_heads_env_gate(monkeypatch):
-    from sparkdl_tpu.models import get_model_spec, model_variant_key
-
-    spec = get_model_spec("InceptionV3")
-    monkeypatch.delenv("SPARKDL_FUSED_HEADS", raising=False)
-    assert spec.build().fused_heads is None       # auto: on at inference
-    assert model_variant_key("InceptionV3") == ""
-    monkeypatch.setenv("SPARKDL_FUSED_HEADS", "0")
-    assert spec.build().fused_heads is False
-    assert model_variant_key("InceptionV3") == "nofh"
-    monkeypatch.setenv("SPARKDL_S2D_STEM", "1")
-    assert model_variant_key("InceptionV3") == "s2d+nofh"

@@ -1,15 +1,16 @@
 """Pipelined host/device execution tests (parallel.pipeline).
 
 Contracts pinned here:
-  * bit-identical outputs to the serial path on every scoring surface —
-    engine (``map_batches``/``__call__``), transformer, UDF, and serving;
+  * bit-identical outputs to the calling-thread path (``pipeline=False``)
+    on every scoring surface — engine (``map_batches``/``__call__``),
+    transformer, UDF, and serving;
   * the synthetic slow-device benchmark proves the overlap: >= 1.5x
-    throughput vs ``SPARKDL_PIPELINE=0`` with a simulated 100 ms dispatch
+    throughput vs ``pipeline=False`` with a simulated 100 ms dispatch
     latency on the CPU backend (the tier-1 contract run-tests.sh guards);
   * pipelined ``__call__`` streams into ONE preallocated output — a frame
     much larger than the in-flight window keeps peak host chunk residency
     bounded (no per-chunk accumulation list);
-  * the ``SPARKDL_PIPELINE=0`` escape hatch, error propagation, and
+  * the ``pipeline=False`` argument, error propagation, and
     worker-thread cleanup on early consumer abandonment.
 """
 
@@ -22,9 +23,7 @@ import pytest
 
 from sparkdl_tpu.parallel import engine as engine_mod
 from sparkdl_tpu.parallel.engine import InferenceEngine
-from sparkdl_tpu.parallel.pipeline import (PipelinedRunner,
-                                           pipeline_enabled_from_env,
-                                           pipeline_stage_summary,
+from sparkdl_tpu.parallel.pipeline import (pipeline_stage_summary,
                                            synthetic_overlap_benchmark)
 from sparkdl_tpu.utils.metrics import Metrics
 
@@ -60,49 +59,40 @@ def _wait_threads_gone(timeout=5.0):
     return False
 
 
-# -- env knob --------------------------------------------------------------
-
-def test_pipeline_env_knob(monkeypatch):
-    monkeypatch.delenv("SPARKDL_PIPELINE", raising=False)
-    assert pipeline_enabled_from_env()
-    for off in ("0", "false", "OFF", "no"):
-        monkeypatch.setenv("SPARKDL_PIPELINE", off)
-        assert not pipeline_enabled_from_env()
-    monkeypatch.setenv("SPARKDL_PIPELINE", "1")
-    assert pipeline_enabled_from_env()
-
+# -- the calling-thread argument -------------------------------------------
 
 def test_escape_hatch_never_builds_a_runner(setup, monkeypatch):
-    """SPARKDL_PIPELINE=0 must route through the serial path without even
-    constructing a PipelinedRunner."""
+    """``pipeline=False`` must route through the calling-thread path
+    without even constructing a PipelinedRunner."""
     variables, x = setup
-    monkeypatch.setenv("SPARKDL_PIPELINE", "0")
 
     def boom(*a, **k):
-        raise AssertionError("PipelinedRunner built despite the escape "
-                             "hatch")
+        raise AssertionError("PipelinedRunner built despite "
+                             "pipeline=False")
 
     monkeypatch.setattr(engine_mod, "PipelinedRunner", boom)
     eng = InferenceEngine(_fn, variables, device_batch_size=16)
     ref = np.tanh(x @ variables["w"] + variables["b"])
-    np.testing.assert_allclose(eng(x), ref, rtol=1e-5, atol=1e-6)
-    got = np.concatenate(list(eng.map_batches([x])), axis=0)
+    np.testing.assert_allclose(eng(x, pipeline=False), ref, rtol=1e-5,
+                               atol=1e-6)
+    got = np.concatenate(list(eng.map_batches([x], pipeline=False)), axis=0)
     np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-6)
 
 
 # -- engine parity ---------------------------------------------------------
 
-@pytest.mark.parametrize("bpd", [1, 3])
-def test_map_batches_bit_identical_to_serial(setup, bpd):
-    """Same programs, same pad/trim, same order — the pipelined stream is
-    byte-for-byte the serial stream, ragged chunks and ragged tail groups
-    included."""
+@pytest.mark.parametrize("window", [1, 3])
+def test_map_batches_bit_identical_to_serial(setup, window):
+    """Same program, same pad/trim, same order — the pipelined stream is
+    byte-for-byte the calling-thread stream, ragged chunks included,
+    whatever the in-flight window."""
     variables, x = setup
-    eng = InferenceEngine(_fn, variables, device_batch_size=16,
-                          batches_per_dispatch=bpd)
+    eng = InferenceEngine(_fn, variables, device_batch_size=16)
     chunks = [x[:60], x[60:63], x[63:]]
-    serial = list(eng.map_batches(iter(chunks), pipeline=False))
-    piped = list(eng.map_batches(iter(chunks), pipeline=True))
+    serial = list(eng.map_batches(iter(chunks), window=window,
+                                  pipeline=False))
+    piped = list(eng.map_batches(iter(chunks), window=window,
+                                 pipeline=True))
     assert len(serial) == len(piped)
     for a, b in zip(serial, piped):
         assert a.shape == b.shape and a.dtype == b.dtype
@@ -145,34 +135,6 @@ def test_single_piece_call_skips_worker_threads(setup, monkeypatch):
     out = eng(x[:10], pipeline=True)
     ref = np.tanh(x[:10] @ variables["w"] + variables["b"])
     np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6)
-
-
-def test_pipelined_grouped_tail_uses_plain_program(setup, monkeypatch):
-    """The grouped-dispatch ragged tail must run through the plain
-    per-batch program in the pipelined stages too — never padded with
-    whole zero batches."""
-    variables, x = setup
-    eng = InferenceEngine(_fn, variables, device_batch_size=16,
-                          batches_per_dispatch=3)
-    calls = {"group": 0, "plain": 0}
-    lock = threading.Lock()
-    orig_group, orig_plain = eng._dispatch_group, eng.run_padded
-
-    def spy_group(stacked):
-        with lock:
-            calls["group"] += 1
-        return orig_group(stacked)
-
-    def spy_plain(batch):
-        with lock:
-            calls["plain"] += 1
-        return orig_plain(batch)
-
-    monkeypatch.setattr(eng, "_dispatch_group", spy_group)
-    monkeypatch.setattr(eng, "run_padded", spy_plain)
-    out = eng(np.concatenate([x[:45], x[:19]]), pipeline=True)  # 4 pieces
-    assert out.shape[0] == 64
-    assert calls == {"group": 1, "plain": 1}  # one full group, 1-piece tail
 
 
 # -- host-memory contract --------------------------------------------------
@@ -238,6 +200,23 @@ def test_consumer_abandonment_stops_worker_threads(setup):
     assert _wait_threads_gone()
 
 
+def test_consumer_garbage_collection_stops_worker_threads(setup):
+    """A consumer that simply DROPS the iterator (a function return, an
+    exception unwound past it) never calls ``close()``: CPython finalizes
+    the generator when it is collected, its ``finally`` sets the stop
+    flag, and all three stages leave their bounded queue loops."""
+    import gc
+
+    variables, x = setup
+    eng = InferenceEngine(_fn, variables, device_batch_size=8)
+    it = eng.map_batches([x], pipeline=True)
+    assert next(it).shape[0] == 8
+    assert len(_pipeline_threads()) == 3
+    del it
+    gc.collect()
+    assert _wait_threads_gone()
+
+
 # -- metrics + the overlap contract ----------------------------------------
 
 def test_stage_metrics_recorded(setup):
@@ -287,10 +266,29 @@ def _image_frame(n=7, h=16, w=12, null_at=2):
         {"image": pa.array(structs, type=imageSchema)}))
 
 
-def test_transformer_surface_parity(monkeypatch):
-    """TFImageTransformer.transform and transformStream emit bit-identical
-    columns with the pipeline on and off."""
+@pytest.fixture()
+def engine_calls(monkeypatch):
+    """Every ``InferenceEngine.__call__`` a surface makes, as ``(engine,
+    batch)``, so a test can put the SAME batch through the SAME engine on
+    the calling thread."""
+    calls = []
+    real = InferenceEngine.__call__
+
+    def spy(self, batch, *args, **kwargs):
+        calls.append((self, batch))
+        return real(self, batch, *args, **kwargs)
+
+    monkeypatch.setattr(InferenceEngine, "__call__", spy)
+    return calls
+
+
+def test_transformer_surface_parity():
+    """TFImageTransformer.transform and transformStream (the engine's
+    pipelined ``map_batches``) emit the rows the same engine gives for the
+    same batch on the calling thread, bit for bit."""
     from sparkdl_tpu.graph.function import ModelFunction
+    from sparkdl_tpu.image.io import arrowStructsToBatch
+    from sparkdl_tpu.parallel.engine import get_cached_engine
     from sparkdl_tpu.transformers.named_image import TFImageTransformer
 
     df = _image_frame()
@@ -300,57 +298,58 @@ def test_transformer_surface_parity(monkeypatch):
         variables={"w": np.linspace(-1, 1, 16 * 12 * 3 * 4).reshape(
             16 * 12 * 3, 4).astype(np.float32)})
 
-    def run():
-        t = TFImageTransformer(inputCol="image", outputCol="out",
-                               modelFunction=mf, inputSize=[16, 12],
-                               batchSize=2)
-        full = t.transform(df).table.column("out").to_pylist()
-        streamed = []
-        for rb in t.transformStream(df.table.to_batches(max_chunksize=3)):
-            streamed.extend(rb.column(rb.schema.names.index("out"))
-                            .to_pylist())
-        return full, streamed
+    t = TFImageTransformer(inputCol="image", outputCol="out",
+                           modelFunction=mf, inputSize=[16, 12],
+                           batchSize=2)
+    full = t.transform(df).table.column("out").to_pylist()
+    streamed = []
+    for rb in t.transformStream(df.table.to_batches(max_chunksize=3)):
+        streamed.extend(rb.column(rb.schema.names.index("out"))
+                        .to_pylist())
 
-    monkeypatch.setenv("SPARKDL_PIPELINE", "0")
-    full_serial, stream_serial = run()
-    monkeypatch.setenv("SPARKDL_PIPELINE", "1")
-    full_piped, stream_piped = run()
-    assert full_piped == full_serial          # bit-exact floats
-    assert stream_piped == stream_serial
-    assert full_serial[2] is None             # null row contract intact
+    eng = get_cached_engine(t, mf, device_batch_size=2)  # the stage's own
+    batch, ok = arrowStructsToBatch(df.table.column("image"), 16, 12,
+                                    compact=True)
+    want = iter(np.asarray(eng(batch, pipeline=False), np.float32).tolist())
+    serial = [next(want) if good else None for good in ok]
+    assert full == serial                     # bit-exact floats
+    assert streamed == serial
+    assert serial[2] is None                  # null row contract intact
 
 
-def test_udf_surface_parity(monkeypatch):
-    """register_image_udf scoring emits bit-identical columns with the
-    pipeline on and off."""
+def test_udf_surface_parity(engine_calls):
+    """register_image_udf scoring (the engine's pipelined ``__call__`` over
+    three device batches) emits the rows the same engine gives for the
+    same batch on the calling thread, bit for bit."""
     from sparkdl_tpu.graph.function import ModelFunction
     from sparkdl_tpu.udf import UDFRegistry, register_image_udf
 
-    df = _image_frame()
+    df = _image_frame(n=20)
     mf = ModelFunction(
         fn=lambda v, x: x.reshape(x.shape[0], -1) @ v["w"],
         variables={"w": np.linspace(0, 1, 16 * 12 * 3 * 2).reshape(
             16 * 12 * 3, 2).astype(np.float32)})
 
-    def run():
-        reg = UDFRegistry()
-        register_image_udf("p", mf, input_size=(16, 12), batch_size=2,
-                           registry=reg)
-        out = reg.apply("p", df, "image", "scores")
-        return out.table.column("scores").to_pylist()
+    reg = UDFRegistry()
+    register_image_udf("p", mf, input_size=(16, 12), batch_size=2,
+                       registry=reg)
+    piped = reg.apply("p", df, "image", "scores").table.column(
+        "scores").to_pylist()
 
-    monkeypatch.setenv("SPARKDL_PIPELINE", "0")
-    serial = run()
-    monkeypatch.setenv("SPARKDL_PIPELINE", "1")
-    piped = run()
+    (eng, batch), = engine_calls
+    assert batch.shape[0] == 19 > 2 * eng.device_batch_size
+    want = iter(np.asarray(eng(batch, pipeline=False),
+                           np.float32).tolist())
+    serial = [None if i == 2 else next(want) for i in range(20)]
     assert piped == serial
     assert serial[2] is None
 
 
-def test_serving_surface_parity(monkeypatch):
-    """Served rows are bit-identical with the pipeline on and off (the
-    serving micro-batch is a single device batch, so it rides the
-    single-piece fast path either way — this pins that equivalence)."""
+def test_serving_surface_parity(engine_calls):
+    """Every served row is the row the same bucket engine gives for the
+    same micro-batch with ``pipeline=False`` (the serving micro-batch is a
+    single device batch, so it rides the single-piece path either way —
+    this pins that equivalence)."""
     from sparkdl_tpu.serving import Server
 
     rng = np.random.default_rng(3)
@@ -363,14 +362,13 @@ def test_serving_surface_parity(monkeypatch):
 
     xs = rng.normal(size=(20, 12)).astype(np.float32)
 
-    def run():
-        with Server(fn, {"w": w}, max_batch_size=8, max_wait_ms=2.0) as srv:
-            futs = [srv.submit(row) for row in xs]
-            return [np.asarray(f.result()) for f in futs]
+    with Server(fn, {"w": w}, max_batch_size=8, max_wait_ms=2.0) as srv:
+        futs = [srv.submit(row) for row in xs]
+        served = [np.asarray(f.result()) for f in futs]
 
-    monkeypatch.setenv("SPARKDL_PIPELINE", "0")
-    serial = run()
-    monkeypatch.setenv("SPARKDL_PIPELINE", "1")
-    piped = run()
-    for a, b in zip(serial, piped):
-        np.testing.assert_array_equal(a, b)
+    serial = {}
+    for eng, batch in list(engine_calls):
+        out = eng(batch, pipeline=False)
+        serial.update((row.tobytes(), o) for row, o in zip(batch, out))
+    for row, got in zip(xs, served):
+        np.testing.assert_array_equal(got, serial[row.tobytes()])

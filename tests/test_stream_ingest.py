@@ -279,6 +279,31 @@ def test_exactly_once_basic_pipelined(engine, payloads, oracle, tmp_path):
     assert sc.health()["state"] == "closed" and not sc.health()["live"]
 
 
+def test_default_scorer_builds_the_pipelined_runner(engine, payloads, oracle,
+                                                    tmp_path, monkeypatch):
+    """A ``StreamScorer`` left at its defaults over an engine sink rides
+    the runner's three threads (poll/journal/prepare overlap dispatch
+    and gather), not the calling thread."""
+    from sparkdl_tpu.parallel import engine as engine_mod
+
+    built = []
+    real = engine_mod.PipelinedRunner
+
+    def spy(*a, **k):
+        built.append(k.get("window"))
+        return real(*a, **k)
+
+    monkeypatch.setattr(engine_mod, "PipelinedRunner", spy)
+    base = str(tmp_path)
+    sc = StreamScorer(engine, MemorySource(payloads, finished=True),
+                      journal_path=os.path.join(base, "journal.jsonl"),
+                      out_dir=os.path.join(base, "out"))
+    assert sc.run()["chunks_scored"] == len(payloads)
+    sc.close()
+    assert built == [2]  # one runner, the scorer's default window
+    assert np.array_equal(_assemble(base), oracle)
+
+
 def test_duplicate_delivery_suppressed_by_id(engine, payloads, oracle,
                                              tmp_path):
     """A chunk the journal already committed (here: offset 1, committed

@@ -21,7 +21,6 @@ from sparkdl_tpu.models import get_model_spec
 from sparkdl_tpu.transformers import (DeepImageFeaturizer, PipelineModel,
                                       TFImageTransformer)
 from sparkdl_tpu.transformers import named_image as ni
-from sparkdl_tpu.utils.prefetch import prefetch_iter
 
 
 @pytest.fixture()
@@ -161,20 +160,6 @@ def test_pipeline_transform_stream_chains_lazily(fake_resnet, many_images):
     assert set(table.column_names) >= {"image", "mean_bgr", "features"}
 
 
-def test_prefetch_iter_propagates_errors_and_order():
-    def gen():
-        yield 1
-        yield 2
-        raise RuntimeError("boom")
-
-    it = prefetch_iter(gen(), depth=2)
-    assert next(it) == 1
-    assert next(it) == 2
-    with pytest.raises(RuntimeError, match="boom"):
-        next(it)
-    assert list(prefetch_iter(iter(range(5)), depth=1)) == list(range(5))
-
-
 def test_image_file_transformer_streams(many_images, monkeypatch):
     """URI-column path: files are loaded per chunk, not all at once."""
     from sparkdl_tpu.transformers.image_file import ImageFileTransformer
@@ -207,54 +192,77 @@ def test_image_file_transformer_streams(many_images, monkeypatch):
     assert max(chunk_sizes) <= 8
 
 
-def test_prefetch_iter_producer_stops_when_consumer_abandons():
-    """Abandoning the consumer mid-stream must release the producer thread
-    (it was previously stuck forever in q.put on the full queue)."""
+def _featurizer_job(many_images):
+    df = readImages(many_images)
+    ft = DeepImageFeaturizer(inputCol="image", outputCol="features",
+                             modelName="ResNet50", batchSize=8)
+    return ft, "_decoded_chunks", df, "features"
+
+
+def _file_transformer_job(many_images):
+    from sparkdl_tpu.transformers.image_file import ImageFileTransformer
+
+    def loader(uri):
+        img = Image.open(uri).convert("RGB").resize((16, 16))
+        return np.asarray(img, dtype=np.float32)
+
+    paths = sorted(
+        os.path.join(many_images, f) for f in os.listdir(many_images))
+    t = ImageFileTransformer(
+        inputCol="uri", outputCol="out", imageLoader=loader, batchSize=8,
+        modelFunction=ModelFunction(fn=lambda v, x: x.mean(axis=(1, 2)),
+                                    variables={}))
+    return t, "_loaded_chunks", DataFrame({"uri": paths}), "out"
+
+
+@pytest.mark.parametrize("make_job", [_featurizer_job,
+                                      _file_transformer_job],
+                         ids=["DeepImageFeaturizer", "ImageFileTransformer"])
+def test_decode_runs_ahead_on_the_runners_prepare_thread(
+        fake_resnet, many_images, monkeypatch, make_job):
+    """The decode generator is handed to ``map_batches`` as it is: after
+    the chunk that proves there is work (pulled by the caller, before an
+    engine exists) the runner's prepare thread pulls it, and chunk k+1 is
+    decoded while chunk k is still on its way out — the gather of chunk k
+    WAITS here for that, so a decode that ran only when the consumer
+    asked for more would time out."""
     import threading
-    import time
 
-    produced = []
+    from sparkdl_tpu.parallel.engine import InferenceEngine
 
-    def gen():
-        for i in range(100):
-            produced.append(i)
-            yield i
+    stage, chunks_attr, df, out_col = make_job(many_images)
+    pulled_on, done = [], []
+    ahead = threading.Condition()
+    decode = getattr(stage, chunks_attr)
 
-    before = threading.active_count()
-    it = prefetch_iter(gen(), depth=1)
-    assert next(it) == 0
-    it.close()  # consumer walks away
-    deadline = time.monotonic() + 5.0
-    while threading.active_count() > before and time.monotonic() < deadline:
-        time.sleep(0.05)
-    assert threading.active_count() <= before, "producer thread leaked"
-    assert len(produced) < 100
+    def spy(*args, **kwargs):
+        for chunk in decode(*args, **kwargs):
+            with ahead:
+                pulled_on.append(threading.current_thread().name)
+                ahead.notify_all()
+            yield chunk
+        with ahead:
+            done.append(True)
+            ahead.notify_all()
 
+    monkeypatch.setattr(stage, chunks_attr, spy)
+    gathered, late = [], []
+    force = InferenceEngine._force_part
 
-def test_prefetch_iter_producer_stops_when_consumer_garbage_collected():
-    """The close() above is the polite path; a consumer that simply
-    DROPS the iterator (function return, exception unwound past it) must
-    release the producer too — CPython finalizes the generator on GC,
-    its ``finally`` sets the stop flag, and the producer's bounded-put
-    loop observes it instead of spinning on the full queue forever."""
-    import gc
-    import threading
-    import time
+    def gather_after_the_next_decode(self, n, out, block=None):
+        k = len(gathered)
+        with ahead:
+            if not ahead.wait_for(
+                    lambda: len(pulled_on) > k + 1 or done, timeout=10.0):
+                late.append(k)
+        gathered.append(k)
+        return force(self, n, out, block)
 
-    from sparkdl_tpu.utils.prefetch import prefetch_iter
-
-    def gen():
-        for i in range(100):
-            yield i
-
-    it = prefetch_iter(gen(), depth=1)
-    assert next(it) == 0
-    del it          # consumer walks away without close()
-    gc.collect()    # finalize the generator deterministically
-    deadline = time.monotonic() + 5.0
-    while (any(t.name == "sparkdl-prefetch" for t in threading.enumerate())
-           and time.monotonic() < deadline):
-        time.sleep(0.05)
-    leaked = [t.name for t in threading.enumerate()
-              if t.name == "sparkdl-prefetch"]
-    assert not leaked, f"producer thread leaked after consumer GC: {leaked}"
+    monkeypatch.setattr(InferenceEngine, "_force_part",
+                        gather_after_the_next_decode)
+    rows = stage.transform(df).collect()
+    assert sum(1 for r in rows if r[out_col] is None) == 2  # bad files
+    assert len(gathered) == len(pulled_on) == 6     # 42 rows in chunks of 8
+    assert late == [], f"decode never ran ahead of the gather of {late}"
+    assert pulled_on[0] == threading.current_thread().name
+    assert set(pulled_on[1:]) == {"sparkdl-pipeline-prepare"}
