@@ -14,6 +14,7 @@ from typing import Optional
 import numpy as np
 import pyarrow as pa
 
+from sparkdl_tpu.frame import list_column
 from sparkdl_tpu.param.params import Param, TypeConverters, keyword_only
 from sparkdl_tpu.param.shared import HasLabelCol
 from sparkdl_tpu.parallel import mesh as mesh_lib
@@ -191,7 +192,4 @@ class LogisticRegressionModel(Model, _HasClassifierCols):
         pred = p.argmax(axis=1)
         out = dataset.withColumn(
             self.getPredictionCol(), pa.array(pred.astype(np.int64)))
-        return out.withColumn(
-            self.getProbabilityCol(),
-            pa.array([[float(v) for v in row] for row in p],
-                     type=pa.list_(pa.float32())))
+        return out.withColumn(self.getProbabilityCol(), list_column(p))

@@ -8,6 +8,7 @@ runs standalone; when pyspark is present the same stages can be bridged via
 pandas-UDFs (see ``sparkdl_tpu.udf``).
 """
 
-from sparkdl_tpu.frame.dataframe import DataFrame, Row
+from sparkdl_tpu.frame.dataframe import (DataFrame, Row, list_column,
+                                         list_values_nbytes)
 
-__all__ = ["DataFrame", "Row"]
+__all__ = ["DataFrame", "Row", "list_column", "list_values_nbytes"]

@@ -12,6 +12,7 @@ Design notes (TPU-first):
 
 from __future__ import annotations
 
+import math
 from typing import Any, Callable, Dict, Iterable, Iterator, List, Optional, Sequence
 
 import numpy as np
@@ -218,6 +219,96 @@ def partition_sizes(rows: int, n: int) -> List[int]:
     return [rows // n + (1 if i < rows % n else 0) for i in range(n)]
 
 
+_FLOAT_LIST = pa.list_(pa.float32())
+# values one list array's int32 offsets can address
+_LIST_VALUES_LIMIT = 2 ** 31 - 1
+
+
+def list_column(mat, valid_idx: Optional[Sequence[int]] = None,
+                num_rows: Optional[int] = None):
+    """A ``list<item: float>`` column of ``num_rows`` rows from the rows
+    of ``mat``: row ``k`` of ``mat`` at position ``valid_idx[k]``, nulls
+    elsewhere; with no ``valid_idx``, row ``i`` at position ``i``.  The
+    inverse of :meth:`DataFrame.column_to_numpy`.
+
+    ``mat`` is any real array of rows; each row is flattened (a vector
+    is rows of one value) and cast to float32 by NumPy.  ``valid_idx`` is
+    strictly increasing (the order in which a stage met its valid rows),
+    ``num_rows`` defaults to ``len(mat)``.  The column is built as Arrow
+    buffers — values, int32 offsets (a null row has length 0), a validity
+    bitmap — and no value or row becomes a Python object.
+
+    The column owns its bytes: the values are copied exactly ONCE, cast
+    and made contiguous in that copy, so no later write to ``mat`` shows
+    in the column.
+
+    One list array's int32 offsets address ``2**31 - 1`` values.  A
+    larger column comes back as a ``pa.ChunkedArray`` of list arrays,
+    each under that limit, slices of the one copy
+    (``DataFrame.withColumn`` appends it as it is); anything smaller is
+    one ``pa.ListArray``.  One row wider than the limit raises
+    ``pa.ArrowCapacityError``."""
+    mat = np.asarray(mat)
+    if mat.ndim < 1:
+        raise ValueError("list_column needs rows of values, got a scalar")
+    if np.iscomplexobj(mat):
+        raise TypeError(f"list_column needs real values, got {mat.dtype}")
+    rows = mat.shape[0]
+    width = math.prod(mat.shape[1:])
+    n = rows if num_rows is None else int(num_rows)
+    if valid_idx is None:
+        if n != rows:
+            raise ValueError(
+                f"{rows} rows cannot fill a column of {n} without "
+                f"valid_idx")
+        valid = np.ones(n, dtype=bool)
+    else:
+        idx = np.asarray(valid_idx, dtype=np.int64).reshape(-1)
+        if len(idx) != rows:
+            raise ValueError(
+                f"{rows} rows for {len(idx)} positions in valid_idx")
+        if rows and (idx[0] < 0 or idx[-1] >= n
+                     or (np.diff(idx) <= 0).any()):
+            raise ValueError(
+                f"valid_idx must be strictly increasing within "
+                f"[0, {n})")
+        valid = np.zeros(n, dtype=bool)
+        valid[idx] = True
+    values = np.empty(mat.shape, dtype=np.float32)
+    np.copyto(values, mat, casting="unsafe")  # the one copy
+    values = values.reshape(-1)
+    ends = np.cumsum(valid, dtype=np.int64) * width  # a row's last value
+    chunks = []
+    p = s = 0  # the next chunk's first row, and its first value
+    while True:
+        q = int(np.searchsorted(ends, s + _LIST_VALUES_LIMIT, side="right"))
+        if q == p < n:
+            raise pa.ArrowCapacityError(
+                f"one row of {width} values exceeds the {_LIST_VALUES_LIMIT} "
+                f"a list array's offsets can address")
+        e = int(ends[q - 1]) if q > p else s
+        offsets = np.zeros(q - p + 1, dtype=np.int32)
+        offsets[1:] = ends[p:q] - s
+        validity = None if rows == n else pa.py_buffer(
+            np.packbits(valid[p:q], bitorder="little"))
+        chunks.append(pa.Array.from_buffers(
+            _FLOAT_LIST, q - p, [validity, pa.py_buffer(offsets)],
+            children=[pa.Array.from_buffers(
+                _FLOAT_LIST.value_type, e - s,
+                [None, pa.py_buffer(values[s:e])])]))
+        p, s = q, e
+        if p >= n:
+            break
+    return chunks[0] if len(chunks) == 1 else pa.chunked_array(chunks)
+
+
+def list_values_nbytes(col) -> int:
+    """Bytes of the values under a list column (``pa.Array`` or
+    ``pa.ChunkedArray``), its offsets and validity left out."""
+    chunks = col.chunks if isinstance(col, pa.ChunkedArray) else [col]
+    return sum(c.values.nbytes for c in chunks)
+
+
 class DataFrame:
     """Immutable columnar frame backed by a ``pyarrow.Table``."""
 
@@ -267,9 +358,11 @@ class DataFrame:
 
     def withColumn(self, name: str, values) -> "DataFrame":
         """Append/replace a column.  ``values`` may be a pyarrow Array /
-        ChunkedArray, numpy array (any rank: rank 2 becomes a
-        ``list<leaf dtype>`` column, rank>=3 nests ``fixed_size_list``
-        per trailing dim, leaf dtype preserved), or Python list."""
+        ChunkedArray (appended as it is, chunks kept: a column past the
+        int32 offsets of one list array exists only in chunks), numpy
+        array (any rank: rank 2 becomes a ``list<leaf dtype>`` column,
+        rank>=3 nests ``fixed_size_list`` per trailing dim, leaf dtype
+        preserved), or Python list."""
         if isinstance(values, (pa.Array, pa.ChunkedArray)):
             arr = values
         elif isinstance(values, np.ndarray):
@@ -288,8 +381,6 @@ class DataFrame:
                     arr = pa.FixedSizeListArray.from_arrays(arr, int(dim))
         else:
             arr = pa.array(values)
-        if isinstance(arr, pa.ChunkedArray):
-            arr = arr.combine_chunks()
         t = self._table
         if name in t.column_names:
             # Replace in place, preserving schema position (pyspark semantics).

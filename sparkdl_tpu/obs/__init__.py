@@ -43,7 +43,13 @@ record batch and ``io.repartition``; attrs ``files``/``rows``/
 ``transformers.named_image`` (``transform.run`` per zoo-stage
 ``transform`` — ``rows``/``valid_rows``/``model``/``batch_size`` — over
 ``transform.pack_in`` per chunk — ``rows``/``valid`` — and
-``transform.pack_out`` — ``rows``/``values``), ``parallel.engine.
+``transform.pack_out`` — ``rows``/``values``, and of the column it
+appended ``bytes`` (its values, offsets and validity left out),
+``null_rows`` and ``py_values``: values that were a Python object on
+the way, 0 wherever ``frame.list_column`` built the column from the
+model's matrix, ``topK`` a row under ``decodePredictions``;
+``transformers.tensor.ModelTransformer`` opens the same three spans),
+``parallel.engine.
 InferenceEngine`` (call/dispatch spans; ``engine.pad`` —
 ``rows``/``pad_rows`` — where a piece is padded and ``engine.h2d`` —
 ``bytes`` — around the dispatch's ``device_put``, host side only;

@@ -12,8 +12,8 @@ from __future__ import annotations
 from typing import List, Optional
 
 import numpy as np
-import pyarrow as pa
 
+from sparkdl_tpu.frame import list_column
 from sparkdl_tpu.image.io import _io_executor
 from sparkdl_tpu.param.converters import SparkDLTypeConverters
 from sparkdl_tpu.param.params import Param, keyword_only
@@ -120,17 +120,14 @@ class ImageFileTransformer(PersistableModelFunctionMixin, Transformer,
                         type(self).__name__, k, elapsed, ips, ips / ndev,
                         ndev)
         n = len(dataset)
-        values: List[Optional[list]] = [None] * n
         if outs:
             out = np.concatenate([np.asarray(o) for o in outs], axis=0)
-            flat = out.reshape(out.shape[0], -1).astype(np.float32)
-            for row, i in zip(flat, valid_idx):
-                values[i] = [float(v) for v in row]
         else:
             logger.warning("imageLoader produced no usable images out of %d "
                            "URIs; output column is all null", n)
-        return dataset.withColumn(
-            self.getOutputCol(), pa.array(values, type=pa.list_(pa.float32())))
+            out = np.zeros((0, 0), np.float32)
+        return dataset.withColumn(self.getOutputCol(),
+                                  list_column(out, valid_idx, n))
 
 
 class KerasImageFileTransformer(ImageFileTransformer):

@@ -19,6 +19,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 import pyarrow as pa
 
+from sparkdl_tpu.frame import list_column, list_values_nbytes
 from sparkdl_tpu.image.io import arrowStructsToBatch
 from sparkdl_tpu.image.schema import imageArrayToStruct, imageSchema
 from sparkdl_tpu.models import get_model_spec, load_model, model_variant_key
@@ -195,15 +196,6 @@ def _zoo_engine(name: str, featurize: bool, batch_size: int) -> InferenceEngine:
     return eng
 
 
-def _float_list_array(mat: np.ndarray, valid_idx: Sequence[int],
-                      num_rows: int) -> pa.Array:
-    """Rows of ``mat`` at positions ``valid_idx``; nulls elsewhere."""
-    values: List[Optional[list]] = [None] * num_rows
-    for row, i in zip(mat, valid_idx):
-        values[i] = [float(v) for v in row]
-    return pa.array(values, type=pa.list_(pa.float32()))
-
-
 class _ImageInputStage(Transformer, HasInputCol, HasOutputCol, HasBatchSize):
     """Shared plumbing: pull the image-struct column, decode/resize valid
     rows into dense batches, keep nulls aligned (undecodable rows stay null
@@ -349,10 +341,11 @@ class _NamedImageTransformer(_ImageInputStage, HasModelName):
         return np.asarray(out), valid_idx, len(dataset)
 
     def _output_column(self, out: np.ndarray, valid_idx: List[int],
-                       num_rows: int) -> pa.Array:
+                       num_rows: int) -> Tuple[pa.Array, int]:
         """The output column from the model's rows ``out`` at positions
-        ``valid_idx``; nulls elsewhere."""
-        return _float_list_array(out, valid_idx, num_rows)
+        ``valid_idx``, nulls elsewhere; and how many of its values were
+        Python objects on the way."""
+        return list_column(out, valid_idx, num_rows), 0
 
     def _transform(self, dataset):
         tracer = get_tracer()
@@ -361,10 +354,11 @@ class _NamedImageTransformer(_ImageInputStage, HasModelName):
             out, valid_idx, n = self._run_model(dataset)
             root.annotate(rows=n, valid_rows=len(valid_idx))
             with tracer.span("transform.pack_out", rows=len(valid_idx),
-                             values=int(out.size)):
-                return dataset.withColumn(
-                    self.getOutputCol(),
-                    self._output_column(out, valid_idx, n))
+                             values=int(out.size)) as sp:
+                col, py_values = self._output_column(out, valid_idx, n)
+                sp.annotate(bytes=list_values_nbytes(col),
+                            null_rows=col.null_count, py_values=py_values)
+                return dataset.withColumn(self.getOutputCol(), col)
 
 
 class DeepImageFeaturizer(_NamedImageTransformer):
@@ -438,7 +432,7 @@ class DeepImagePredictor(_NamedImageTransformer):
 
     def _output_column(self, probs, valid_idx, n):
         if not self.getDecodePredictions():
-            return _float_list_array(probs, valid_idx, n)
+            return super()._output_column(probs, valid_idx, n)
         decoded = decode_predictions(probs, top=self.getTopK())
         pred_type = pa.list_(pa.struct([
             pa.field("class", pa.string()),
@@ -450,7 +444,9 @@ class DeepImagePredictor(_NamedImageTransformer):
             values[i] = [
                 {"class": c, "description": d, "probability": p}
                 for c, d, p in row]
-        return pa.array(values, type=pred_type)
+        # each decoded probability is a Python float in a Python dict
+        return (pa.array(values, type=pred_type),
+                sum(len(row) for row in decoded))
 
 
 class TFImageTransformer(PersistableModelFunctionMixin, _ImageInputStage,
@@ -552,13 +548,9 @@ class TFImageTransformer(PersistableModelFunctionMixin, _ImageInputStage,
             # Nothing decodable but the size was known (explicit or pinned
             # by transformStream): keep the drop-to-null contract — an
             # all-null record batch mid-stream must not kill the job.
-            return dataset.withColumn(
-                self.getOutputCol(),
-                pa.array([None] * n, type=pa.list_(pa.float32())))
-        out = np.asarray(out)
-        flat = out.reshape(out.shape[0], -1).astype(np.float32)
+            out = np.zeros((0, 0), np.float32)
         return dataset.withColumn(
-            self.getOutputCol(), _float_list_array(flat, valid_idx, n))
+            self.getOutputCol(), list_column(out, valid_idx, n))
 
     def _transform_image_mode(self, dataset, engine_factory, h, w, n):
         """Image-sized outputs are packed to structs PER CHUNK as the

@@ -21,8 +21,8 @@ from __future__ import annotations
 from typing import Dict, List, Optional
 
 import numpy as np
-import pyarrow as pa
 
+from sparkdl_tpu.frame import list_column, list_values_nbytes
 from sparkdl_tpu.obs.trace import get_tracer
 from sparkdl_tpu.param.converters import SparkDLTypeConverters
 from sparkdl_tpu.param.params import Param, keyword_only
@@ -30,13 +30,6 @@ from sparkdl_tpu.param.shared import HasBatchSize, HasInputCol, HasOutputCol
 from sparkdl_tpu.parallel.engine import get_cached_engine
 from sparkdl_tpu.persistence import PersistableModelFunctionMixin
 from sparkdl_tpu.transformers.base import Transformer
-
-
-def _rows_to_list_array(mat: np.ndarray) -> pa.Array:
-    mat = np.asarray(mat)
-    flat = mat.reshape(mat.shape[0], -1).astype(np.float32)
-    return pa.array([[float(v) for v in row] for row in flat],
-                    type=pa.list_(pa.float32()))
 
 
 class ModelTransformer(PersistableModelFunctionMixin, Transformer,
@@ -90,9 +83,11 @@ class ModelTransformer(PersistableModelFunctionMixin, Transformer,
                 root.annotate(tokens=int(x.size))
             out = self.engine()(x)
             with tracer.span("transform.pack_out", rows=len(out),
-                             values=int(np.size(out))):
-                return dataset.withColumn(self.getOutputCol(),
-                                          _rows_to_list_array(out))
+                             values=int(np.size(out))) as sp:
+                col = list_column(out)
+                sp.annotate(bytes=list_values_nbytes(col),
+                            null_rows=col.null_count, py_values=0)
+                return dataset.withColumn(self.getOutputCol(), col)
 
 
 class KerasTransformer(ModelTransformer):
@@ -211,5 +206,5 @@ class TFTransformer(Transformer, HasBatchSize):
             out = {mf.output_names[0]: out}
         for output_name, col in out_map.items():
             dataset = dataset.withColumn(
-                col, _rows_to_list_array(out[output_name]))
+                col, list_column(out[output_name]))
         return dataset

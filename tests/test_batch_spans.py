@@ -127,7 +127,8 @@ TABLE = [
     ("engine.h2d", 2,
      ("engine.dispatch", "pipeline.dispatch") + UNDER_RUN, {"bytes"}),
     ("pipeline.gather", 2, UNDER_RUN, {"rows", "bytes"}),
-    ("transform.pack_out", 1, ("transform.run",), {"rows", "values"}),
+    ("transform.pack_out", 1, ("transform.run",),
+     {"rows", "values", "bytes", "null_rows", "py_values"}),
 ]
 
 
@@ -205,8 +206,12 @@ def test_attrs_add_up(job, jpeg_dir, tiny_resnet):
     gathers = attrs(run, "pipeline.gather")
     assert [g["rows"] for g in gathers] == [BATCH, FILES - BATCH]
     assert sum(g["bytes"] for g in gathers) == FILES * 2048 * 4
+    # the column's values buffer is the features as float32, and none of
+    # them was a Python object on the way
     assert attrs(run, "transform.pack_out") == [
-        {"rows": FILES, "values": FILES * tiny_resnet.feature_size}]
+        {"rows": FILES, "values": FILES * tiny_resnet.feature_size,
+         "bytes": FILES * tiny_resnet.feature_size * 4, "null_rows": 0,
+         "py_values": 0}]
     assert attrs(run, "transform.run") == [
         {"rows": FILES, "valid_rows": FILES, "model": "ResNet50",
          "batch_size": BATCH}]
@@ -326,7 +331,11 @@ def test_predictor_tail_is_under_pack_out(jpeg_dir, tiny_resnet):
     assert all(len(r["preds"]) == 3 for r in rows)
     spans = tracer.snapshot()
     (pack,) = _named(spans, "transform.pack_out")
-    assert pack["attrs"] == {"rows": FILES, "values": FILES * 1000}
+    # the decoded tail is the one output that crosses Python objects:
+    # three probabilities a row
+    assert pack["attrs"].pop("bytes") > 0
+    assert pack["attrs"] == {"rows": FILES, "values": FILES * 1000,
+                             "null_rows": 0, "py_values": FILES * 3}
     assert _chain(spans, pack) == ("transform.pack_out", "transform.run")
 
 

@@ -417,7 +417,8 @@ def test_model_transformer_spans_and_engine():
         "transform.run", "transform.pack_in", "transform.pack_out"))
     assert run["attrs"] == {"batch_size": 2, "rows": 5, "tokens": 20}
     assert pack_in["attrs"] == {"rows": 5, "bytes": 5 * 4 * 4}
-    assert pack_out["attrs"] == {"rows": 5, "values": 5}
+    assert pack_out["attrs"] == {"rows": 5, "values": 5, "bytes": 5 * 4,
+                                 "null_rows": 0, "py_values": 0}
     for child in (pack_in, pack_out, spans["engine.call"]):
         assert child["parent_id"] == run["span_id"]
     eng = mt.engine()
