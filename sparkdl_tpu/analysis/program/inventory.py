@@ -10,6 +10,10 @@ Abstract by construction: model variables come from
 ``ModelSpec.abstract_variables()`` (``jax.eval_shape`` over ``init`` —
 shape/dtype only), batches are ``ShapeDtypeStruct``s, and nothing is
 ever placed on a device.
+
+Not in the inventory, and so not in ``PROGRAMS.lock.json``: the sequence
+trunks' programs (``models/hybrid_trunk``, ``models/expert_trunk``),
+which run through ``ModelTransformer`` alone (``ROADMAP.md``, Reach R3).
 """
 
 from __future__ import annotations

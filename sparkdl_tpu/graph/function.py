@@ -33,6 +33,12 @@ class ModelFunction:
     # (pred, new_batch_stats)`` — set for models with BatchNorm whose
     # statistics can update during fine-tuning (estimator trainBatchStats).
     train_fn: Optional[Callable[[Any, Any], Any]] = None
+    # Outputs that are COUNTERS of the program, not columns: ``fn`` then
+    # returns a dict keyed by ``output_names``, every leaf with the
+    # batch axis leading (the engine pads and trims a counter's rows as
+    # it does a column's), and a stage adds what the real rows counted
+    # to its engine's metrics instead of writing it to the frame.
+    counter_names: Sequence[str] = ()
 
     def __call__(self, x):
         return self.fn(self.variables, x)
@@ -89,7 +95,8 @@ class ModelFunction:
 
         return ModelFunction(
             fn=fn, variables={"f": f.variables, "g": g.variables},
-            input_names=f.input_names, output_names=g.output_names)
+            input_names=f.input_names, output_names=g.output_names,
+            counter_names=g.counter_names)
 
     def jit(self):
         """Eagerly jit-compile (otherwise the engine jits with shardings)."""

@@ -5,9 +5,12 @@ optimal code (dense convs ride the MXU untouched); this package holds the
 exceptions — ops whose default lowering materializes avoidable HBM
 traffic, rewritten as fused pallas kernels with reference-parity jax
 fallbacks for CPU/debug: the separable convolutions of the CNN zoo
-(``sepconv``), and the two parts of a hybrid sequence block that have no
+(``sepconv``), the two parts of a hybrid sequence block that have no
 lowering worth having — the chunked state-space scan (``ssd``) and
-causal grouped-query attention without the score matrix (``attention``).
+causal grouped-query attention without the score matrix, over the whole
+row or a window of it (``attention``) — and an expert layer's products
+by group over the tokens routed to each expert (``grouped_matmul``; the
+module is imported by its own name, which its entry point shares).
 """
 
 from sparkdl_tpu.ops.attention import causal_attention

@@ -4,7 +4,8 @@
 side on the last axis as the projections leave them, each key/value
 head serving ``H // KV`` query heads; ``q`` comes already scaled (and
 turned by the rotary position, as ``k``).  Returns ``[R, T, H*hd]`` in
-``q``'s dtype.
+``q``'s dtype.  With ``window`` a query sees the ``window`` keys that
+end at its own position (``query - key < window``) and none before.
 
 On the TPU a Pallas kernel (``name="causal_attention"``): grid rows x
 query heads x query blocks x key blocks, the key axis sequential, the
@@ -12,7 +13,10 @@ running maximum, the running sum and the float32 accumulator of the
 online softmax in VMEM scratch.  A key/value head is read in place for
 each of its query heads (no repeated copy in memory, no transpose to a
 heads-first layout); key blocks above the diagonal are neither computed
-nor fetched.  Elsewhere the same sum in ``jax.numpy``, one block of
+nor fetched, and with a window the key axis of the grid is only as
+long as the band is wide (a grid dimension that is data, as the window
+is): it starts at the first block a query block can see, so blocks
+below the band are never visited at all.  Elsewhere the same sum in ``jax.numpy``, one block of
 queries after the other (``lax.map``), so that the largest score array
 is ``[R, H, block, T]``.  Scores, softmax and the accumulator are
 float32; the two matrix products take their operands in ``q``'s dtype.
@@ -39,11 +43,18 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _first_key_block(qi, block: int, window):
+    """The first key block that holds a key a query of block ``qi`` sees."""
+    return jnp.maximum(qi * block - (window - 1), 0) // block
+
+
 def attention_blocked(q, k, v, *, heads: int, kv_heads: int,
                       block: int = BLOCK, causal: bool = True,
-                      precision=None):
+                      window=None, precision=None):
     """``jax.numpy``: queries in blocks of ``block`` against all keys.
     ``causal=False`` lets every position see every other."""
+    if window is not None and not causal:
+        raise ValueError("a window needs causal attention")
     f32 = jnp.float32
     r, t, _ = q.shape
     hd = q.shape[-1] // heads
@@ -62,7 +73,10 @@ def attention_blocked(q, k, v, *, heads: int, kv_heads: int,
                        preferred_element_type=f32)
         if causal:
             query_at = i * block + jnp.arange(block)
-            s = jnp.where(key_at[None, :] <= query_at[:, None], s, _NEG)
+            seen = key_at[None, :] <= query_at[:, None]
+            if window is not None:
+                seen &= query_at[:, None] - key_at[None, :] < window
+            s = jnp.where(seen, s, _NEG)
         p = jax.nn.softmax(s, axis=-1)
         return jnp.einsum("rgjqk,rkgd->rqgjd", p.astype(q.dtype), vh,
                           precision=precision, preferred_element_type=f32)
@@ -71,15 +85,19 @@ def attention_blocked(q, k, v, *, heads: int, kv_heads: int,
     return jnp.moveaxis(out, 0, 1).reshape(r, t, heads * hd).astype(q.dtype)
 
 
-def _attention_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                      causal: bool, precision):
+def _attention_kernel(*refs, causal: bool, windowed: bool, precision):
     """One query block of one head against one key block; blocks are
-    ``[1, block, hd]``."""
+    ``[1, block, hd]``.  With a window its width comes first, in scalar
+    memory, and the grid's key axis counts ``kj`` from the first block
+    the query block sees (block 0 without a window)."""
     f32 = jnp.float32
-    qi, ki, nk = pl.program_id(2), pl.program_id(3), pl.num_programs(3)
+    window = refs[0][0] if windowed else None
+    q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs[windowed:]
+    qi, kj, nk = pl.program_id(2), pl.program_id(3), pl.num_programs(3)
     block = q_ref.shape[1]
+    ki = kj + _first_key_block(qi, block, window) if windowed else kj
 
-    @pl.when(ki == 0)
+    @pl.when(kj == 0)
     def _():
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
@@ -92,7 +110,13 @@ def _attention_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         if causal:
             rows = qi * block + lax.broadcasted_iota(jnp.int32, s.shape, 0)
             cols = ki * block + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(cols <= rows, s, _NEG)
+            seen = cols <= rows
+            if windowed:
+                # a row whose keys of this block are all out of the band
+                # sums garbage at weight exp(0); the first key it does
+                # see scales that to nothing (exp(_NEG - m) is 0)
+                seen = jnp.logical_and(seen, rows - cols < window)
+            s = jnp.where(seen, s, _NEG)
         m_old = m_ref[...]                                   # [block, 1]
         m_new = jnp.maximum(m_old, s.max(axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -103,18 +127,20 @@ def _attention_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
             preferred_element_type=f32)
         m_ref[...] = m_new
 
-    @pl.when(ki == nk - 1)
+    @pl.when(kj == nk - 1)
     def _():
         o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, donate_argnums=(), static_argnames=(
     "heads", "kv_heads", "block", "causal", "interpret", "precision"))
-def attention_kernel(q, k, v, *, heads: int, kv_heads: int,
+def attention_kernel(q, k, v, window=None, *, heads: int, kv_heads: int,
                      block: int = BLOCK, causal: bool = True,
                      interpret: bool = False, precision=None):
     """The Pallas kernel; on the chip ``hd`` is a multiple of 128 and
-    ``block`` of 8."""
+    ``block`` of 8.  ``window`` is data (an int32 scalar, so that layers
+    of both kinds run one compiled kernel under one ``lax.scan``) or
+    ``None``: the grid's key axis is then the whole row."""
     r, t, _ = q.shape
     hd = q.shape[-1] // heads
     rep = heads // kv_heads
@@ -122,39 +148,56 @@ def attention_kernel(q, k, v, *, heads: int, kv_heads: int,
     if t % block:
         raise ValueError(f"{t} positions are no multiple of the block {block}")
     nb = t // block
+    windowed = window is not None
+    if windowed and not causal:
+        raise ValueError("a window needs causal attention")
 
-    def key_block(i, h, qi, ki):
+    def key_block(i, h, qi, kj, *width):
         # above the diagonal nothing is computed: name the block that is
         # already there, so that nothing is fetched either
+        ki = kj + _first_key_block(qi, block, width[0][0]) if windowed else kj
         return (i, jnp.minimum(ki, qi) if causal else ki, h // rep)
 
-    query = pl.BlockSpec((1, block, hd), lambda i, h, qi, ki: (i, qi, h))
+    query = pl.BlockSpec((1, block, hd), lambda i, h, qi, kj, *_: (i, qi, h))
     keys = pl.BlockSpec((1, block, hd), key_block)
+    scalars = ()
+    nk = nb
+    if windowed:
+        width = jnp.maximum(jnp.asarray(window, jnp.int32), 1)
+        scalars = (width.reshape(1),)
+        # the band of a query block spans window - 1 + block keys
+        nk = jnp.minimum(nb, (width - 1 + block - 1) // block + 1)
     return pl.pallas_call(
-        functools.partial(_attention_kernel, causal=causal,
+        functools.partial(_attention_kernel, causal=causal, windowed=windowed,
                           precision=precision),
-        grid=(r, heads, nb, nb),
-        in_specs=[query, keys, keys],
-        out_specs=query,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(r, heads, nb, nk),
+            in_specs=[query, keys, keys],
+            out_specs=query,
+            scratch_shapes=[pltpu.VMEM((block, 1), jnp.float32),
+                            pltpu.VMEM((block, 1), jnp.float32),
+                            pltpu.VMEM((block, hd), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((block, 1), jnp.float32),
-                        pltpu.VMEM((block, 1), jnp.float32),
-                        pltpu.VMEM((block, hd), jnp.float32)],
         compiler_params=pltpu.CompilerParams(dimension_semantics=(
             "parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name=NAME,
-    )(q, k, v)
+    )(*scalars, q, k, v)
 
 
-def causal_attention(q, k, v, *, heads: int, kv_heads: int, precision=None,
+def causal_attention(q, k, v, *, heads: int, kv_heads: int, window=None,
+                     block: int = BLOCK, precision=None,
                      force: Optional[object] = None):
     """The kernel on the TPU, the blocked ``jax.numpy`` form on any
-    other platform; ``force`` is the tests' (``True``, ``"interpret"``,
-    ``False``)."""
+    other platform; ``window=None`` is causal over the whole row, else
+    an integer or an int32 scalar of the program (a window no shorter
+    than the row is the same sum); ``force`` is the tests' (``True``,
+    ``"interpret"``, ``False``)."""
     if _on_tpu() if force is None else force:
-        return attention_kernel(q, k, v, heads=heads, kv_heads=kv_heads,
+        return attention_kernel(q, k, v, window, heads=heads,
+                                kv_heads=kv_heads, block=block,
                                 interpret=(force == "interpret"),
                                 precision=precision)
     return attention_blocked(q, k, v, heads=heads, kv_heads=kv_heads,
-                             precision=precision)
+                             block=block, window=window, precision=precision)

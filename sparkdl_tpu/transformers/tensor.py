@@ -32,6 +32,47 @@ from sparkdl_tpu.persistence import PersistableModelFunctionMixin
 from sparkdl_tpu.transformers.base import Transformer
 
 
+def count_outputs(model_function, out, metrics, keep, tokens=None):
+    """What a stage does with a program's outputs that are no columns.
+    ``out`` is what the engine gathered (real rows only); ``keep`` the
+    outputs that become columns; ``tokens`` the positions of the input
+    rows, where rows are sequences.  A declared counter
+    (``ModelFunction.counter_names``) is added to ``metrics`` and taken
+    out; an output that is neither is taken out too, and NAMED in what
+    is returned beside the kept outputs — nothing is dropped without a
+    word.  ``({kept name: array}, {span attribute: value})``."""
+    if not isinstance(out, dict):
+        return {model_function.output_names[0]: out}, {}
+    attrs = {}
+    for name in model_function.counter_names:
+        if name in out:
+            attrs.update(COUNTERS[name](np.asarray(out[name]), metrics,
+                                        tokens))
+    unmapped = sorted(set(out) - set(keep) - set(model_function.counter_names))
+    if unmapped:
+        attrs["unmapped_outputs"] = ",".join(unmapped)
+    return {name: out[name] for name in keep}, attrs
+
+
+def _count_expert_load(load, metrics, tokens):
+    """``expert_load`` ``[rows, expert layers, held experts]``: tokens of
+    a row that each held expert of each layer took.  ``moe.pairs``:
+    token-expert pairs computed here; ``moe.tokens``: tokens x expert
+    layers that were routed; ``moe.busiest_expert_pairs``: the fullest
+    expert's pairs, summed over the layers, of this call's rows."""
+    pairs = int(load.sum())
+    metrics.incr("moe.pairs", pairs)
+    metrics.incr("moe.busiest_expert_pairs",
+                 int(load.sum(axis=0).max(axis=-1).sum()))
+    if tokens is not None:
+        metrics.incr("moe.tokens", tokens * load.shape[1])
+    return {"expert_pairs": pairs}
+
+
+#: how each counter a program may declare reaches the engine's metrics
+COUNTERS = {"expert_load": _count_expert_load}
+
+
 class ModelTransformer(PersistableModelFunctionMixin, Transformer,
                        HasInputCol, HasOutputCol, HasBatchSize):
     """Apply a ModelFunction to an array column (one row = one example)."""
@@ -81,7 +122,12 @@ class ModelTransformer(PersistableModelFunctionMixin, Transformer,
             root.annotate(rows=len(x))
             if x.ndim == 2:
                 root.annotate(tokens=int(x.size))
-            out = self.engine()(x)
+            engine, mf = self.engine(), self.getModelFunction()
+            kept, counted = count_outputs(
+                mf, engine(x), engine.metrics, mf.output_names[:1],
+                tokens=int(x.size) if x.ndim == 2 else None)
+            root.annotate(**counted)
+            out = kept[mf.output_names[0]]
             with tracer.span("transform.pack_out", rows=len(out),
                              values=int(np.size(out))) as sp:
                 col = list_column(out)
@@ -201,9 +247,10 @@ class TFTransformer(Transformer, HasBatchSize):
             for col, input_name in in_map.items()
         }
         eng = get_cached_engine(self, mf, device_batch_size=self.getBatchSize())
-        out = eng(x)
-        if not isinstance(out, dict):
-            out = {mf.output_names[0]: out}
+        with get_tracer().span("transform.run",
+                               batch_size=self.getBatchSize()) as root:
+            out, counted = count_outputs(mf, eng(x), eng.metrics, out_map)
+            root.annotate(**counted)
         for output_name, col in out_map.items():
             dataset = dataset.withColumn(
                 col, list_column(out[output_name]))

@@ -25,13 +25,17 @@ def _qkv(seed=0):
             jax.random.normal(k[2], (ROWS, T, KV * HD)))
 
 
-def _full(q, k, v, causal=True):
+def _full(q, k, v, causal=True, window=None):
     qh = q.reshape(ROWS, T, HEADS, HD)
     kh, vh = (jnp.repeat(u.reshape(ROWS, T, KV, HD), HEADS // KV, axis=2)
               for u in (k, v))
     s = jnp.einsum("rqhd,rkhd->rhqk", qh, kh)
     if causal:
-        s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, -jnp.inf)
+        seen = jnp.tril(jnp.ones((T, T), bool))
+        if window is not None:
+            at = jnp.arange(T)
+            seen &= at[:, None] - at[None, :] < window
+        s = jnp.where(seen, s, -jnp.inf)
     return jnp.einsum("rhqk,rkhd->rqhd", jax.nn.softmax(s, -1),
                       vh).reshape(ROWS, T, HEADS * HD)
 
@@ -39,8 +43,9 @@ def _full(q, k, v, causal=True):
 FORMS = {
     "jax.numpy": lambda q, k, v, **kw: attention.attention_blocked(
         q, k, v, heads=HEADS, kv_heads=KV, **kw),
-    "kernel, interpreted": lambda q, k, v, **kw: attention.attention_kernel(
-        q, k, v, heads=HEADS, kv_heads=KV, interpret=True, **kw),
+    "kernel, interpreted": lambda q, k, v, window=None, **kw:
+        attention.attention_kernel(q, k, v, window, heads=HEADS, kv_heads=KV,
+                                   interpret=True, **kw),
 }
 
 
@@ -60,6 +65,51 @@ def test_causal_false_sees_every_position(form):
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(_full(q, k, v, causal=False)), atol=1e-5)
     assert np.abs(np.asarray(got - _full(q, k, v))).max() > 1e-2
+
+
+@pytest.mark.parametrize("window", [1, 5, 8, 12, 16, T, T + 9])
+@pytest.mark.parametrize("form", list(FORMS))
+def test_a_window_is_the_masked_full_softmax(form, window):
+    """Blocks of 8: a window under the block, the block, between two
+    blocks, two blocks, the whole row and past it."""
+    q, k, v = _qkv(3)
+    got = FORMS[form](q, k, v, block=8, window=window)
+    want = _full(q, k, v, window=window)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+    if window < T:
+        assert np.abs(np.asarray(want - _full(q, k, v))).max() > 1e-2
+
+
+@pytest.mark.parametrize("form", list(FORMS))
+def test_no_window_is_bit_equal_to_a_window_past_the_row(form):
+    """``window=None`` is today's program; a window no query can reach
+    the end of computes the same sums in the same order."""
+    q, k, v = _qkv(4)
+    none = np.asarray(FORMS[form](q, k, v, block=8))
+    np.testing.assert_array_equal(
+        none, np.asarray(FORMS[form](q, k, v, block=8, window=None)))
+    np.testing.assert_array_equal(
+        none, np.asarray(FORMS[form](q, k, v, block=8, window=T)))
+
+
+@pytest.mark.parametrize("form", list(FORMS))
+def test_the_window_is_data(form):
+    """One compiled program, windows of both kinds of layer: the width
+    is an operand (and, in the kernel, the grid's key axis with it)."""
+    q, k, v = _qkv(5)
+    run = jax.jit(lambda w: FORMS[form](q, k, v, block=8, window=w))
+    for window in (5, 12, T):
+        np.testing.assert_allclose(
+            np.asarray(run(jnp.int32(window))),
+            np.asarray(_full(q, k, v, window=window)), atol=1e-5)
+    assert run._cache_size() == 1
+
+
+def test_a_window_needs_causal_attention():
+    q, k, v = _qkv()
+    for form in FORMS.values():
+        with pytest.raises(ValueError, match="window"):
+            form(q, k, v, block=8, window=4, causal=False)
 
 
 @pytest.mark.parametrize("form", list(FORMS))
