@@ -302,19 +302,35 @@ def arrowStructsToBatch(column, height: int, width: int,
     This is the zero-copy replacement for ``to_pylist()`` +
     :func:`structsToBatch` on the UDF/scoring hot path: child arrays are
     read as numpy views over Arrow buffers, and each row's pixel block is
-    sliced straight out of the binary child's value buffer.  When every
-    valid row is already ``height x width`` uint8 BGR (the common case for a
-    resized column), packing is one ~memcpy per row.  Chunked columns are
-    packed chunk by chunk (never ``combine_chunks``, whose int32 binary
-    offsets overflow past 2 GB of image bytes).
+    sliced straight out of the binary child's value buffer.  Chunked
+    columns are packed chunk by chunk (never ``combine_chunks``, whose
+    int32 binary offsets overflow past 2 GB of image bytes).  Two paths
+    (what each costs on the chip's host: ``PERF.md`` §5, §6):
+
+    * **uniform** — every valid row is already ``height x width`` uint8
+      BGR (a resized column): one memcpy a row on the caller's thread,
+      then for "rgb" one channel shuffle over the whole batch.
+    * **general** — any other column, on the shared io pool in tasks of
+      several rows (the task's size follows from the rows and the pool's
+      workers).  Each row takes the route its struct names: an 8-bit
+      three-channel row (``CV_8UC3``) is handed to PIL as the raw Arrow
+      slice — the unpacker swaps the channels while it reads, so every
+      source pixel is read once, into one image that a task's rows of
+      one size share — resized by :func:`resizeImage`'s filter, and
+      copied into its slot once; a row of any other kind
+      (one or four channels, 16-bit or float modes) goes through
+      :func:`resizeImage` as an array, as :func:`structToModelInput`
+      does.  The pixels are the same either way.  The span open around
+      the call (``transform.pack_in``) is annotated with ``raw_rows``,
+      the rows that took the raw route, and ``tasks``, the pool tasks
+      submitted (0 under 4 rows, which are packed on the caller's
+      thread).
 
     ``channel_order``: "rgb" (default) swaps BGR struct bytes to RGB on the
-    host; "bgr" returns the struct's native byte order untouched — the fast
+    host; "bgr" returns the struct's native byte order untouched — the
     feed for pipelines that fold the channel swap into the device program
     (as the reference's converter subgraph did: ``graph/pieces.py``
-    buildSpImageConverter swapped BGR->RGB *inside* the graph).  Host cost
-    measured at 299x299: ~0.01 ms/img for "bgr", ~0.25 ms/img for "rgb"
-    (the swap is the only non-memcpy work).
+    buildSpImageConverter swapped BGR->RGB *inside* the graph).
 
     ``compact``: when True the batch holds ONLY the ok rows (in row order) —
     row ``k`` of the batch is the ``k``-th True of the mask — so callers
@@ -395,36 +411,74 @@ def arrowStructsToBatch(column, height: int, width: int,
         ok[idx] = True
         return out, ok
 
-    # General path: per-row buffer views (still no dict round trip), then
-    # the normal channel normalization + resize, threaded for large rows.
-    out = np.zeros((nrows, height, width, 3), dtype=np.uint8)
+    # General path: the io pool packs tasks of several rows, each row by
+    # the route its struct names.  Every slot is written or (compact)
+    # dropped below, so nothing is zeroed up front.
+    from PIL import Image
 
-    def one(si):
-        s, i = si
-        t = imageTypeByMode(int(modes[i]))
-        h, w, c = int(heights[i]), int(widths[i]), int(channels[i])
-        row = values[offsets[i]:offsets[i + 1]]
-        arr = row.view(t.dtype) if t.dtype != "uint8" else row
-        if arr.size != h * w * c:
-            return
-        arr = arr.reshape(h, w, c)
-        if arr.dtype != np.uint8:
-            arr = np.clip(arr, 0, 255).astype(np.uint8)
-        if c == 1:
-            arr = np.repeat(arr, 3, axis=2)
-        elif c == 4:
-            arr = arr[:, :, :3]
-        resized = resizeImage(np.ascontiguousarray(arr), height, width)
-        out[s] = resized if channel_order == "bgr" else resized[:, :, ::-1]
-        ok[i] = True
+    out = np.empty((nrows, height, width, 3), dtype=np.uint8)
+    # the unpacker swaps the channels, or not, while it reads the slice
+    raw_mode = "BGR" if channel_order == "rgb" else "RGB"
 
-    pairs = list(zip(slots, idx))
-    if len(pairs) >= 4:
-        list(_io_executor().map(one, pairs))
+    def pack(lo, hi):
+        """Rows ``idx[lo:hi]`` into their slots -> how many went raw."""
+        raw = 0
+        src = None
+        for s, i in zip(slots[lo:hi], idx[lo:hi]):
+            h, w, c = int(heights[i]), int(widths[i]), int(channels[i])
+            row = values[offsets[i]:offsets[i + 1]]
+            if modes[i] == 16 and c == 3 and row.size == h * w * 3:
+                # CV_8UC3, the raw route: PIL reads the Arrow slice
+                # once, the resized row is copied once.  Rows of one size
+                # unpack into one image a task: a new one would be
+                # allocated and filled with the GIL held
+                if src is None or src.size != (w, h):
+                    src = Image.new("RGB", (w, h), None)
+                src.frombytes(row, "raw", (raw_mode, 0, 1))
+                img = src
+                if h != height or w != width:
+                    img = src.resize((width, height), Image.BILINEAR)
+                out[s] = np.asarray(img)
+                raw += 1
+            else:
+                t = imageTypeByMode(int(modes[i]))
+                arr = row.view(t.dtype) if t.dtype != "uint8" else row
+                if arr.size != h * w * c:
+                    continue
+                arr = arr.reshape(h, w, c)
+                if arr.dtype != np.uint8:
+                    arr = np.clip(arr, 0, 255).astype(np.uint8)
+                if c == 1:
+                    arr = np.repeat(arr, 3, axis=2)
+                elif c == 4:
+                    arr = arr[:, :, :3]
+                resized = resizeImage(np.ascontiguousarray(arr), height,
+                                      width)
+                out[s] = (resized if channel_order == "bgr"
+                          else resized[:, :, ::-1])
+            ok[i] = True
+        return raw
+
+    rows = len(idx)
+    if rows >= 4:
+        pool = _io_executor()
+        # a task's submit, wake-up and result are paid once for its rows;
+        # four tasks a worker still even out rows of unequal cost
+        per = min(32, max(1, rows // (4 * pool._max_workers)))
+        los = range(0, rows, per)
+        tasks = len(los)
+        raw_rows = sum(pool.map(lambda lo: pack(lo, lo + per), los))
     else:
-        for p in pairs:
-            one(p)
-    if compact and not ok[idx].all():
+        tasks, raw_rows = 0, pack(0, rows)
+    span = get_tracer().current()
+    if span is not None:
+        # summed: a chunked column packs chunk by chunk under one span
+        span.annotate(
+            raw_rows=span.attrs.get("raw_rows", 0) + raw_rows,
+            tasks=span.attrs.get("tasks", 0) + tasks)
+    if not compact:
+        out[~ok] = 0    # null rows, and valid ones that failed
+    elif not ok[idx].all():
         # a valid struct failed decode (size mismatch): drop its slot so
         # batch rows stay aligned with the True positions of the mask
         out = out[ok[idx]]

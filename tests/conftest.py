@@ -53,3 +53,28 @@ def fixture_images(tmp_path_factory, rng):
     bad = d / "not_an_image.jpg"
     bad.write_bytes(b"this is not a jpeg")
     return {"dir": str(d), "paths": paths, "bad": str(bad)}
+
+
+class _TinyZooModule:
+    """A zoo module's surface over a trivial function of the input."""
+
+    def apply(self, variables, x, train=False, features=False):
+        import jax.numpy as jnp
+
+        m = jnp.mean(x, axis=(1, 2, 3))
+        idx = jnp.arange(2048 if features else 1000, dtype=jnp.float32)
+        return m[:, None] * 0.01 + idx[None, :] * 1e-4
+
+
+@pytest.fixture()
+def tiny_resnet(monkeypatch):
+    """The zoo's ``ResNet50`` stages over a toy module: the spec of the
+    real one, an engine of their own."""
+    from sparkdl_tpu.models import get_model_spec
+    from sparkdl_tpu.transformers import named_image as ni
+
+    monkeypatch.setitem(ni._MODEL_CACHE, ("ResNet50", ""),
+                        (_TinyZooModule(), {}))
+    ni._ENGINE_CACHE.clear()
+    yield get_model_spec("ResNet50")
+    ni._ENGINE_CACHE.clear()

@@ -14,24 +14,11 @@ import pytest
 
 from sparkdl_tpu import obs
 from sparkdl_tpu.image import io as image_io
-from sparkdl_tpu.models import get_model_spec
 from sparkdl_tpu.transformers import (DeepImageFeaturizer,
                                       DeepImagePredictor)
-from sparkdl_tpu.transformers import named_image as ni
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FILES, BATCH = 12, 8          # one full dispatch and one of 4 rows + 4 pad
-
-
-class _TinyZooModule:
-    """A zoo module's surface over a trivial function of the input."""
-
-    def apply(self, variables, x, train=False, features=False):
-        import jax.numpy as jnp
-
-        m = jnp.mean(x, axis=(1, 2, 3))
-        idx = jnp.arange(2048 if features else 1000, dtype=jnp.float32)
-        return m[:, None] * 0.01 + idx[None, :] * 1e-4
 
 
 @pytest.fixture(autouse=True)
@@ -50,16 +37,6 @@ def jpeg_dir(tmp_path_factory):
         arr = (rng.random((20, 24, 3)) * 255).astype("uint8")
         Image.fromarray(arr).save(d / f"img_{i:02d}.jpg", quality=90)
     return str(d)
-
-
-@pytest.fixture()
-def tiny_resnet(monkeypatch):
-    spec = get_model_spec("ResNet50")
-    monkeypatch.setitem(ni._MODEL_CACHE, ("ResNet50", ""),
-                        (_TinyZooModule(), {}))
-    ni._ENGINE_CACHE.clear()
-    yield spec
-    ni._ENGINE_CACHE.clear()
 
 
 def _featurizer():
@@ -157,9 +134,14 @@ def test_first_chunk_packs_on_the_callers_thread_the_rest_in_prepare(job):
         ("transform.pack_in",) + UNDER_PREPARE]
     assert packs[0]["thread"] == threading.current_thread().name
     assert packs[1]["thread"] == "sparkdl-pipeline-prepare"
+    # JPEG-born structs are 8-bit BGR: every row takes the raw route, in
+    # pool tasks whose size follows from the host's cores
+    tasks = [s["attrs"].pop("tasks") for s in packs]
+    assert 1 <= tasks[0] <= BATCH and 1 <= tasks[1] <= FILES - BATCH
     assert [s["attrs"] for s in packs] == [
-        {"rows": BATCH, "valid": BATCH},
-        {"rows": FILES - BATCH, "valid": FILES - BATCH}]
+        {"rows": BATCH, "valid": BATCH, "raw_rows": BATCH},
+        {"rows": FILES - BATCH, "valid": FILES - BATCH,
+         "raw_rows": FILES - BATCH}]
 
 
 def test_one_trace_id_a_call_and_children_inside_parents(job):
@@ -232,7 +214,9 @@ def test_a_file_that_does_not_decode_is_counted_not_dropped(
     assert decode["attrs"] == {"rows": 4, "failed": 1}
     assert root["attrs"]["null_rows"] == 1 and root["attrs"]["rows"] == 4
     (pack,) = _named(spans, "transform.pack_in")
-    assert pack["attrs"] == {"rows": 4, "valid": 3}
+    # three valid rows are packed on the caller's thread: no pool task
+    assert pack["attrs"] == {"rows": 4, "valid": 3, "raw_rows": 3,
+                             "tasks": 0}
     (run,) = _named(spans, "transform.run")
     assert (run["attrs"]["rows"], run["attrs"]["valid_rows"]) == (4, 3)
     assert sum(f is None for f in _features(out)) == 1

@@ -713,6 +713,151 @@ def test_arrow_structs_compact(rng):
     assert [int(bc[k, 0, 0, 2]) for k in range(4)] == [10, 20, 30, 40]
 
 
+def _oracle_row(struct, height, width, channel_order):
+    """The recipe a row before the raw route, written out: reshape,
+    ``Image.fromarray``, ``resize(BILINEAR)``, flip.  ``None`` for a row
+    whose ``data`` length lies."""
+    from PIL import Image
+
+    t = imageTypeByMode(struct["mode"])
+    h, w, c = struct["height"], struct["width"], struct["nChannels"]
+    arr = np.frombuffer(struct["data"], dtype=t.dtype)
+    if arr.size != h * w * c:
+        return None
+    arr = arr.reshape(h, w, c)
+    if arr.dtype != np.uint8:
+        arr = np.clip(arr, 0, 255).astype(np.uint8)
+    if c == 1:
+        arr = np.repeat(arr, 3, axis=2)
+    elif c == 4:
+        arr = arr[:, :, :3]
+    if (h, w) != (height, width):
+        arr = np.asarray(Image.fromarray(np.ascontiguousarray(arr)).resize(
+            (width, height), Image.BILINEAR))
+    return arr if channel_order == "bgr" else arr[:, :, ::-1]
+
+
+def _u8(rng, h, w, c=3):
+    return rng.integers(0, 256, size=(h, w, c), dtype=np.uint8)
+
+
+def _pack_case(name, rng):
+    """``(column, structs a row or None, (height, width))`` of a case."""
+    size = (9, 11)
+    cut = None
+    if name == "down_375x500":
+        size = (299, 299)
+        rows = [_u8(rng, 375, 500) for _ in range(5)]
+    elif name == "up_odd_7x5":
+        rows = [_u8(rng, 7, 5) for _ in range(6)]
+    elif name == "mixed_sizes":     # above, below, odd, and the target's own
+        rows = [_u8(rng, h, w) for h, w in
+                [(20, 30), (9, 11), (7, 5), (9, 30), (31, 11), (9, 11)]]
+    elif name == "mixed_kinds":     # the route is chosen row by row
+        rows = [_u8(rng, 12, 14), _u8(rng, 12, 14, 1), _u8(rng, 9, 11, 4),
+                (rng.random((12, 14, 3)) * 300 - 20).astype(np.float32),
+                (rng.random((9, 11, 1)) * 255).astype(np.float32),
+                _u8(rng, 9, 11), _u8(rng, 13, 8, 4)]
+    elif name == "sliced":          # a non-zero offset in every child
+        rows = [_u8(rng, 10 + i, 17 - i) for i in range(9)]
+        cut = (2, 6)
+    elif name == "chunked":
+        rows = [_u8(rng, 12, 14), None, _u8(rng, 5, 7), _u8(rng, 9, 11, 1),
+                _u8(rng, 20, 30), _u8(rng, 9, 11), _u8(rng, 8, 8)]
+    elif name == "nulls_and_a_lying_length":
+        rows = [_u8(rng, 12, 14), None, _u8(rng, 10, 10), _u8(rng, 5, 7),
+                None, _u8(rng, 6, 6, 1), _u8(rng, 20, 30)]
+    elif name == "serial_under_4_rows":     # two rows share a source image
+        rows = [_u8(rng, 12, 14), _u8(rng, 12, 14), None, _u8(rng, 7, 5)]
+    elif name == "tasks_of_several_rows":   # sizes in runs of three
+        n = 8 * image_io._io_executor()._max_workers + 3
+        rows = [_u8(rng, 6 + (k // 3) % 2, 5) for k in range(n)]
+    structs = [None if a is None else imageArrayToStruct(a) for a in rows]
+    if name == "nulls_and_a_lying_length":
+        structs[2]["height"] += 1   # an 8-bit BGR row: the raw route's check
+        structs[5]["width"] -= 1    # a grayscale row: the other route's
+    column = pa.array(structs, type=imageSchema)
+    if cut is not None:
+        column = column.slice(*cut)
+        structs = structs[cut[0]:cut[0] + cut[1]]
+    if name == "chunked":
+        column = pa.chunked_array([column.slice(0, 3), column.slice(3, 0),
+                                   column.slice(3)])
+    return column, structs, size
+
+
+@pytest.mark.parametrize("channel_order", ["rgb", "bgr"])
+@pytest.mark.parametrize("compact", [False, True],
+                         ids=["row_aligned", "compact"])
+@pytest.mark.parametrize("case", [
+    "down_375x500", "up_odd_7x5", "mixed_sizes", "mixed_kinds", "sliced",
+    "chunked", "nulls_and_a_lying_length", "serial_under_4_rows",
+    "tasks_of_several_rows"])
+def test_arrow_structs_general_path_equals_the_recipe_a_row(
+        rng, case, compact, channel_order):
+    """The general path's pixels are those of the recipe a row, whatever
+    route a row takes, in tasks of several rows or on the caller's
+    thread; null rows and rows whose length lies are dropped when
+    ``compact`` and zeroed when not."""
+    from sparkdl_tpu.image import arrowStructsToBatch
+    column, structs, (height, width) = _pack_case(case, rng)
+    want = [None if s is None else _oracle_row(s, height, width,
+                                               channel_order)
+            for s in structs]
+    want_ok = np.array([w is not None for w in want])
+    if compact:
+        want = [w for w in want if w is not None]
+    else:
+        want = [np.zeros((height, width, 3), np.uint8) if w is None else w
+                for w in want]
+    batch, ok = arrowStructsToBatch(column, height, width,
+                                    channel_order=channel_order,
+                                    compact=compact)
+    assert batch.dtype == np.uint8
+    assert batch.shape == (len(want), height, width, 3)
+    np.testing.assert_array_equal(ok, want_ok)
+    np.testing.assert_array_equal(batch, np.stack(want))
+
+
+@pytest.mark.parametrize("born,raw", [("jpeg", True), ("grayscale", False)])
+def test_pack_in_span_says_how_many_rows_went_raw(rng, tmp_path, tiny_resnet,
+                                                  born, raw):
+    """``transform.pack_in`` of a toy ``DeepImageFeaturizer.transform``
+    carries ``raw_rows`` and ``tasks``: every JPEG-born struct is 8-bit
+    BGR and goes raw; no row of a grayscale column does."""
+    from PIL import Image
+
+    from sparkdl_tpu.frame import DataFrame
+    from sparkdl_tpu.transformers import DeepImageFeaturizer
+    rows, batch_size = 11, 8    # 8: the test mesh's data axis
+    if born == "jpeg":
+        for i in range(rows):
+            Image.fromarray(_u8(rng, 20, 24)).save(tmp_path / f"{i}.jpg")
+        df = readImages(str(tmp_path), numPartitions=1)
+    else:
+        df = DataFrame(pa.table({"image": pa.array(
+            [imageArrayToStruct(_u8(rng, 20, 24, 1)) for _ in range(rows)],
+            type=imageSchema)}))
+    tracer = obs.configure(enabled=True)
+    try:
+        out = DeepImageFeaturizer(
+            inputCol="image", outputCol="features", modelName="ResNet50",
+            batchSize=batch_size).transform(df)
+        packs = sorted((s for s in tracer.snapshot()
+                        if s["name"] == "transform.pack_in"),
+                       key=lambda s: s["ts_us"])
+    finally:
+        obs.configure_from_env()
+    assert out.count() == rows
+    # one chunk of 8 rows on the pool, one of 3 on the caller's thread
+    assert [p["attrs"]["rows"] for p in packs] == [batch_size,
+                                                   rows - batch_size]
+    assert [p["attrs"]["raw_rows"] for p in packs] == [
+        p["attrs"]["rows"] if raw else 0 for p in packs]
+    assert 1 <= packs[0]["attrs"]["tasks"] <= batch_size
+    assert packs[1]["attrs"]["tasks"] == 0
+
+
 def test_arrow_structs_multi_chunk_never_combines():
     """Chunked columns must be packed chunk by chunk: combine_chunks on a
     binary child overflows int32 offsets past 2 GB of image bytes
