@@ -219,20 +219,24 @@ def partition_sizes(rows: int, n: int) -> List[int]:
     return [rows // n + (1 if i < rows % n else 0) for i in range(n)]
 
 
-_FLOAT_LIST = pa.list_(pa.float32())
+#: the list types a stage's output column may have, by its values' dtype
+_LIST_TYPES = {np.dtype(np.float32): pa.list_(pa.float32()),
+               np.dtype(np.int32): pa.list_(pa.int32())}
 # values one list array's int32 offsets can address
 _LIST_VALUES_LIMIT = 2 ** 31 - 1
 
 
 def list_column(mat, valid_idx: Optional[Sequence[int]] = None,
-                num_rows: Optional[int] = None):
+                num_rows: Optional[int] = None, dtype=np.float32):
     """A ``list<item: float>`` column of ``num_rows`` rows from the rows
     of ``mat``: row ``k`` of ``mat`` at position ``valid_idx[k]``, nulls
     elsewhere; with no ``valid_idx``, row ``i`` at position ``i``.  The
-    inverse of :meth:`DataFrame.column_to_numpy`.
+    inverse of :meth:`DataFrame.column_to_numpy`.  With ``dtype``
+    ``numpy.int32`` the column is a ``list<item: int32>``: the ONE way
+    an integer output (ids, say) reaches a column as integers.
 
     ``mat`` is any real array of rows; each row is flattened (a vector
-    is rows of one value) and cast to float32 by NumPy.  ``valid_idx`` is
+    is rows of one value) and cast to ``dtype`` by NumPy.  ``valid_idx`` is
     strictly increasing (the order in which a stage met its valid rows),
     ``num_rows`` defaults to ``len(mat)``.  The column is built as Arrow
     buffers — values, int32 offsets (a null row has length 0), a validity
@@ -249,6 +253,10 @@ def list_column(mat, valid_idx: Optional[Sequence[int]] = None,
     one ``pa.ListArray``.  One row wider than the limit raises
     ``pa.ArrowCapacityError``."""
     mat = np.asarray(mat)
+    if np.dtype(dtype) not in _LIST_TYPES:
+        raise TypeError(f"list_column makes columns of "
+                        f"{sorted(map(str, _LIST_TYPES))}, not {dtype}")
+    list_type = _LIST_TYPES[np.dtype(dtype)]
     if mat.ndim < 1:
         raise ValueError("list_column needs rows of values, got a scalar")
     if np.iscomplexobj(mat):
@@ -274,7 +282,7 @@ def list_column(mat, valid_idx: Optional[Sequence[int]] = None,
                 f"[0, {n})")
         valid = np.zeros(n, dtype=bool)
         valid[idx] = True
-    values = np.empty(mat.shape, dtype=np.float32)
+    values = np.empty(mat.shape, dtype=dtype)
     np.copyto(values, mat, casting="unsafe")  # the one copy
     values = values.reshape(-1)
     ends = np.cumsum(valid, dtype=np.int64) * width  # a row's last value
@@ -292,9 +300,9 @@ def list_column(mat, valid_idx: Optional[Sequence[int]] = None,
         validity = None if rows == n else pa.py_buffer(
             np.packbits(valid[p:q], bitorder="little"))
         chunks.append(pa.Array.from_buffers(
-            _FLOAT_LIST, q - p, [validity, pa.py_buffer(offsets)],
+            list_type, q - p, [validity, pa.py_buffer(offsets)],
             children=[pa.Array.from_buffers(
-                _FLOAT_LIST.value_type, e - s,
+                list_type.value_type, e - s,
                 [None, pa.py_buffer(values[s:e])])]))
         p, s = q, e
         if p >= n:

@@ -189,16 +189,21 @@ def _rms_norm(x, scale, eps: float):
             * scale.astype(jnp.float32))
 
 
-def _normed_rotary(x, heads: int, scale, eps: float, theta: float, turn):
+def _normed_rotary(x, heads: int, scale, eps: float, theta: float, turn,
+                   first=None):
     """RMSNorm by head, then the rotary position over the whole head,
     halves paired, angles in float32 and multiplied by ``turn`` (1 in a
     layer that carries the position, 0 in one that does not: the turn by
-    no angle is exact).  ``x`` ``[R, T, heads*hd]`` float32."""
+    no angle is exact).  ``x`` ``[R, T, heads*hd]`` float32, its
+    positions ``first`` and on (0 and on where none is given)."""
     r, t, width = x.shape
     hd = width // heads
     xh = _rms_norm(x.reshape(r, t, heads, hd), scale, eps)
     inv = float(theta) ** (-jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
-    angle = turn * jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
+    at = jnp.arange(t, dtype=jnp.float32)
+    if first is not None:
+        at = at + jnp.asarray(first, jnp.float32)
+    angle = turn * at[:, None] * inv[None, :]
     cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
     lo, hi = xh[..., :hd // 2], xh[..., hd // 2:]
     return jnp.concatenate([lo * cos - hi * sin, hi * cos + lo * sin],
@@ -247,14 +252,22 @@ def _gated_mlp(h, gate_up, down, dtype, precision):
     return dot(act, down)
 
 
-def _route(config: Dict[str, Any], tokens, router, bias):
+#: an expert's score from its logit among all the router's
+SCORES = {"sigmoid": jax.nn.sigmoid,
+          "softmax": functools.partial(jax.nn.softmax, axis=-1)}
+
+
+def _route(config: Dict[str, Any], tokens, router, bias=None,
+           scores: str = "sigmoid"):
     """``(chosen experts [N, k] by their number among all, weights [N, k]
-    float32)`` of ``tokens`` ``[N, D]`` float32."""
-    scores = jax.nn.sigmoid(jnp.dot(
+    float32)`` of ``tokens`` ``[N, D]`` float32.  ``scores`` names how a
+    logit becomes a score (``SCORES``); a ``bias`` chooses only."""
+    scores = SCORES[scores](jnp.dot(
         tokens, router.astype(jnp.float32), precision=lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32))
-    _, chosen = lax.top_k(scores + bias.astype(jnp.float32),
-                          config["num_experts_per_tok"])
+    _, chosen = lax.top_k(
+        scores if bias is None else scores + bias.astype(jnp.float32),
+        config["num_experts_per_tok"])
     weight = jnp.take_along_axis(scores, chosen, axis=-1)
     if config["route_norm"]:
         weight = weight / jnp.sum(weight, axis=-1, keepdims=True)

@@ -6,6 +6,11 @@ head serving ``H // KV`` query heads; ``q`` comes already scaled (and
 turned by the rotary position, as ``k``).  Returns ``[R, T, H*hd]`` in
 ``q``'s dtype.  With ``window`` a query sees the ``window`` keys that
 end at its own position (``query - key < window``) and none before.
+With a ``block_length`` ``B`` over 1 the mask is by blocks of ``B``
+positions (``key // B <= query // B``): a query sees every earlier block
+and its OWN block both ways, which is how block diffusion reads a
+prompt; ``B`` is static and 1 is the causal mask, the same instruction
+as without it.
 
 On the TPU a Pallas kernel (``name="causal_attention"``): grid rows x
 query heads x query blocks x key blocks, the key axis sequential, the
@@ -48,9 +53,20 @@ def _first_key_block(qi, block: int, window):
     return jnp.maximum(qi * block - (window - 1), 0) // block
 
 
+def _check_block_length(block_length: int, block: int, causal: bool,
+                        window) -> None:
+    if block_length == 1:
+        return
+    if not causal or window is not None:
+        raise ValueError("a block length goes with the causal mask alone")
+    if block_length < 1 or block % block_length:
+        raise ValueError(f"blocks of {block_length} positions do not fill "
+                         f"a query block of {block}")
+
+
 def attention_blocked(q, k, v, *, heads: int, kv_heads: int,
                       block: int = BLOCK, causal: bool = True,
-                      window=None, precision=None):
+                      window=None, block_length: int = 1, precision=None):
     """``jax.numpy``: queries in blocks of ``block`` against all keys.
     ``causal=False`` lets every position see every other."""
     if window is not None and not causal:
@@ -62,6 +78,7 @@ def attention_blocked(q, k, v, *, heads: int, kv_heads: int,
     block = min(block, t)
     if t % block:
         raise ValueError(f"{t} positions are no multiple of the block {block}")
+    _check_block_length(block_length, block, causal, window)
     kh = k.reshape(r, t, kv_heads, hd)
     vh = v.reshape(r, t, kv_heads, hd)
     qb = jnp.moveaxis(q.reshape(r, t // block, block, kv_heads, rep, hd), 1, 0)
@@ -73,7 +90,7 @@ def attention_blocked(q, k, v, *, heads: int, kv_heads: int,
                        preferred_element_type=f32)
         if causal:
             query_at = i * block + jnp.arange(block)
-            seen = key_at[None, :] <= query_at[:, None]
+            seen = key_at[None, :] <= _last_seen(query_at, block_length)[:, None]
             if window is not None:
                 seen &= query_at[:, None] - key_at[None, :] < window
             s = jnp.where(seen, s, _NEG)
@@ -85,7 +102,16 @@ def attention_blocked(q, k, v, *, heads: int, kv_heads: int,
     return jnp.moveaxis(out, 0, 1).reshape(r, t, heads * hd).astype(q.dtype)
 
 
-def _attention_kernel(*refs, causal: bool, windowed: bool, precision):
+def _last_seen(query_at, block_length: int):
+    """The last key a query sees: itself, or with blocks the last
+    position of its own block."""
+    if block_length == 1:
+        return query_at
+    return query_at // block_length * block_length + (block_length - 1)
+
+
+def _attention_kernel(*refs, causal: bool, windowed: bool,
+                      block_length: int, precision):
     """One query block of one head against one key block; blocks are
     ``[1, block, hd]``.  With a window its width comes first, in scalar
     memory, and the grid's key axis counts ``kj`` from the first block
@@ -110,7 +136,7 @@ def _attention_kernel(*refs, causal: bool, windowed: bool, precision):
         if causal:
             rows = qi * block + lax.broadcasted_iota(jnp.int32, s.shape, 0)
             cols = ki * block + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            seen = cols <= rows
+            seen = cols <= _last_seen(rows, block_length)
             if windowed:
                 # a row whose keys of this block are all out of the band
                 # sums garbage at weight exp(0); the first key it does
@@ -133,10 +159,12 @@ def _attention_kernel(*refs, causal: bool, windowed: bool, precision):
 
 
 @functools.partial(jax.jit, donate_argnums=(), static_argnames=(
-    "heads", "kv_heads", "block", "causal", "interpret", "precision"))
+    "heads", "kv_heads", "block", "causal", "block_length", "interpret",
+    "precision"))
 def attention_kernel(q, k, v, window=None, *, heads: int, kv_heads: int,
                      block: int = BLOCK, causal: bool = True,
-                     interpret: bool = False, precision=None):
+                     block_length: int = 1, interpret: bool = False,
+                     precision=None):
     """The Pallas kernel; on the chip ``hd`` is a multiple of 128 and
     ``block`` of 8.  ``window`` is data (an int32 scalar, so that layers
     of both kinds run one compiled kernel under one ``lax.scan``) or
@@ -151,6 +179,7 @@ def attention_kernel(q, k, v, window=None, *, heads: int, kv_heads: int,
     windowed = window is not None
     if windowed and not causal:
         raise ValueError("a window needs causal attention")
+    _check_block_length(block_length, block, causal, window)
 
     def key_block(i, h, qi, kj, *width):
         # above the diagonal nothing is computed: name the block that is
@@ -169,7 +198,7 @@ def attention_kernel(q, k, v, window=None, *, heads: int, kv_heads: int,
         nk = jnp.minimum(nb, (width - 1 + block - 1) // block + 1)
     return pl.pallas_call(
         functools.partial(_attention_kernel, causal=causal, windowed=windowed,
-                          precision=precision),
+                          block_length=block_length, precision=precision),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
             grid=(r, heads, nb, nk),
@@ -187,17 +216,20 @@ def attention_kernel(q, k, v, window=None, *, heads: int, kv_heads: int,
 
 
 def causal_attention(q, k, v, *, heads: int, kv_heads: int, window=None,
-                     block: int = BLOCK, precision=None,
-                     force: Optional[object] = None):
+                     block: int = BLOCK, block_length: int = 1,
+                     precision=None, force: Optional[object] = None):
     """The kernel on the TPU, the blocked ``jax.numpy`` form on any
     other platform; ``window=None`` is causal over the whole row, else
     an integer or an int32 scalar of the program (a window no shorter
-    than the row is the same sum); ``force`` is the tests' (``True``,
-    ``"interpret"``, ``False``)."""
+    than the row is the same sum); ``block_length`` over 1 is the mask
+    by blocks; ``force`` is the tests' (``True``, ``"interpret"``,
+    ``False``)."""
     if _on_tpu() if force is None else force:
         return attention_kernel(q, k, v, window, heads=heads,
                                 kv_heads=kv_heads, block=block,
+                                block_length=block_length,
                                 interpret=(force == "interpret"),
                                 precision=precision)
     return attention_blocked(q, k, v, heads=heads, kv_heads=kv_heads,
-                             block=block, window=window, precision=precision)
+                             block=block, window=window,
+                             block_length=block_length, precision=precision)

@@ -13,7 +13,9 @@ the mesh.
     ModelTransformer.  Input rows are 1-D float arrays (reference contract).
   * :class:`TFTransformer` — multi-input/multi-output mapping form: a
     TFInputGraph/ModelFunction plus {column->input} / {output->column}
-    maps (reference's feed/fetch wiring).
+    maps (reference's feed/fetch wiring).  Integer columns stay
+    integers on the way in (token ids) and on the way out (an int32
+    list column: ``output_column``), as ``ModelTransformer``'s do.
 """
 
 from __future__ import annotations
@@ -35,8 +37,10 @@ from sparkdl_tpu.transformers.base import Transformer
 def count_outputs(model_function, out, metrics, keep, tokens=None):
     """What a stage does with a program's outputs that are no columns.
     ``out`` is what the engine gathered (real rows only); ``keep`` the
-    outputs that become columns; ``tokens`` the positions of the input
-    rows, where rows are sequences.  A declared counter
+    outputs that become columns; ``tokens`` the ids of the INPUT rows,
+    where rows are sequences (a program that makes positions of its own,
+    as a generator does, carries its own count in its counter).  A
+    declared counter
     (``ModelFunction.counter_names``) is added to ``metrics`` and taken
     out; an output that is neither is taken out too, and NAMED in what
     is returned beside the kept outputs — nothing is dropped without a
@@ -69,8 +73,47 @@ def _count_expert_load(load, metrics, tokens):
     return {"expert_pairs": pairs}
 
 
+def _count_diffusion(counts, metrics, tokens):
+    """``diffusion_counts`` ``[rows, 6]`` (``block_diffusion.COUNTS``): a
+    generator's own count of its passes, the ids it revealed, the
+    positions it routed (it makes positions of its own, so the input's
+    ids say nothing of them) and their pairs, summed over the rows, and
+    the experts its loop touched.  ``tokens`` is the prompts' ids."""
+    (denoise, commit, revealed, routed, pairs,
+     touched) = (int(v) for v in counts.sum(axis=0))
+    for name, value in (("diffusion.denoise_passes", denoise),
+                        ("diffusion.commit_passes", commit),
+                        ("diffusion.revealed_ids", revealed),
+                        ("diffusion.touched_experts", touched),
+                        ("moe.tokens", routed), ("moe.pairs", pairs)):
+        metrics.incr(name, value)
+    attrs = {"generated_ids": revealed, "denoise_passes": denoise,
+             "commit_passes": commit, "expert_pairs": pairs}
+    if tokens is not None:
+        attrs["prompt_tokens"] = tokens
+    return attrs
+
+
 #: how each counter a program may declare reaches the engine's metrics
-COUNTERS = {"expert_load": _count_expert_load}
+COUNTERS = {"expert_load": _count_expert_load,
+            "diffusion_counts": _count_diffusion}
+
+
+def output_column(out):
+    """A program's output as a list column (``frame.list_column``): an
+    integer output stays int32, any other is float32."""
+    out = np.asarray(out)
+    if np.issubdtype(out.dtype, np.integer):
+        return list_column(out, dtype=np.int32)
+    return list_column(out)
+
+
+def _model_input(dataset, col):
+    """A column as the array a program is given: token ids stay
+    integers; everything else is a float column and reaches the function
+    as float32."""
+    x = dataset.column_to_numpy(col)
+    return x if np.issubdtype(x.dtype, np.integer) else x.astype(np.float32)
 
 
 class ModelTransformer(PersistableModelFunctionMixin, Transformer,
@@ -113,11 +156,7 @@ class ModelTransformer(PersistableModelFunctionMixin, Transformer,
         with tracer.span("transform.run",
                          batch_size=self.getBatchSize()) as root:
             with tracer.span("transform.pack_in", rows=len(dataset)) as sp:
-                x = dataset.column_to_numpy(self.getInputCol())
-                # token ids stay integers; everything else is a float
-                # column and reaches the function as float32
-                if not np.issubdtype(x.dtype, np.integer):
-                    x = x.astype(np.float32)
+                x = _model_input(dataset, self.getInputCol())
                 sp.annotate(bytes=int(x.nbytes))
             root.annotate(rows=len(x))
             if x.ndim == 2:
@@ -130,7 +169,7 @@ class ModelTransformer(PersistableModelFunctionMixin, Transformer,
             out = kept[mf.output_names[0]]
             with tracer.span("transform.pack_out", rows=len(out),
                              values=int(np.size(out))) as sp:
-                col = list_column(out)
+                col = output_column(out)
                 sp.annotate(bytes=list_values_nbytes(col),
                             null_rows=col.null_count, py_values=0)
                 return dataset.withColumn(self.getOutputCol(), col)
@@ -228,6 +267,12 @@ class TFTransformer(Transformer, HasBatchSize):
     def getOutputMapping(self) -> Dict[str, str]:
         return self.getOrDefault(self.outputMapping)
 
+    def engine(self):
+        """The engine ``transform`` runs this stage on (as
+        ``ModelTransformer.engine``)."""
+        return get_cached_engine(self, self.getModelFunction(),
+                                 device_batch_size=self.getBatchSize())
+
     def _transform(self, dataset):
         mf = self.getModelFunction()
         in_map = self.getInputMapping()
@@ -242,16 +287,28 @@ class TFTransformer(Transformer, HasBatchSize):
             raise ValueError(
                 f"outputMapping refers to unknown model outputs "
                 f"{sorted(missing)}; model has {list(mf.output_names)}")
-        x = {
-            input_name: dataset.column_to_numpy(col).astype(np.float32)
-            for col, input_name in in_map.items()
-        }
-        eng = get_cached_engine(self, mf, device_batch_size=self.getBatchSize())
-        with get_tracer().span("transform.run",
-                               batch_size=self.getBatchSize()) as root:
-            out, counted = count_outputs(mf, eng(x), eng.metrics, out_map)
+        tracer = get_tracer()
+        with tracer.span("transform.run",
+                         batch_size=self.getBatchSize()) as root:
+            with tracer.span("transform.pack_in", rows=len(dataset)) as sp:
+                x = {input_name: _model_input(dataset, col)
+                     for col, input_name in in_map.items()}
+                sp.annotate(bytes=sum(int(a.nbytes) for a in x.values()))
+            root.annotate(rows=len(dataset))
+            ids = [a for a in x.values() if a.ndim == 2
+                   and np.issubdtype(a.dtype, np.integer)]
+            eng = self.engine()
+            out, counted = count_outputs(
+                mf, eng(x), eng.metrics, out_map,
+                tokens=sum(int(a.size) for a in ids) if ids else None)
             root.annotate(**counted)
-        for output_name, col in out_map.items():
-            dataset = dataset.withColumn(
-                col, list_column(out[output_name]))
+            for output_name, name in out_map.items():
+                values = out[output_name]
+                with tracer.span("transform.pack_out", rows=len(values),
+                                 values=int(np.size(values)), column=name,
+                                 dtype=str(values.dtype)) as sp:
+                    col = output_column(values)
+                    sp.annotate(bytes=list_values_nbytes(col),
+                                null_rows=col.null_count, py_values=0)
+                    dataset = dataset.withColumn(name, col)
         return dataset

@@ -300,3 +300,64 @@ def test_a_stage_writes_its_column_from_the_buffer(make, image_frame,
     assert span["attrs"] == {
         "rows": len(mat), "values": mat.size, "bytes": mat.size * 4,
         "null_rows": null_rows, "py_values": 0}
+
+
+# -- the int32 column (ISSUE 37): ids stay integers --------------------------
+
+INT_LIST = pa.list_(pa.int32())
+INT_CASES = [
+    ("int32", np.arange(12, dtype=np.int32).reshape(3, 4) - 5, None, None),
+    ("int64 ids fit int32", np.arange(6, dtype=np.int64).reshape(2, 3) * 70000,
+     None, None),
+    ("nulls at both ends", np.arange(8, dtype=np.int32).reshape(2, 4), [1, 2],
+     4),
+    ("gaps", np.arange(12, dtype=np.int32).reshape(3, 4), [1, 4, 5], 8),
+    ("an empty frame", np.zeros((0, 4), np.int32), None, None),
+    ("no row valid", np.zeros((0, 4), np.int32), [], 3),
+    ("rank 1: rows of one id", np.arange(5, dtype=np.int32), None, None),
+]
+
+
+@pytest.mark.parametrize("mat,valid_idx,num_rows",
+                         [c[1:] for c in INT_CASES],
+                         ids=[c[0] for c in INT_CASES])
+def test_an_int32_column_equals_the_plain_construction(mat, valid_idx,
+                                                       num_rows):
+    col = list_column(mat, valid_idx, num_rows, dtype=np.int32)
+    mat = np.asarray(mat)
+    flat = mat.reshape(len(mat), int(np.prod(mat.shape[1:])))
+    n = len(flat) if num_rows is None else num_rows
+    values = [None] * n
+    for row, i in zip(flat, range(len(flat)) if valid_idx is None
+                      else valid_idx):
+        values[i] = [int(v) for v in row]
+    assert col.type == INT_LIST
+    assert col.equals(pa.array(values, type=INT_LIST))
+    col.validate(full=True)
+
+
+def test_an_int32_column_round_trips_through_the_frame():
+    ids = np.arange(15, dtype=np.int32).reshape(5, 3) * 1000003 % 151936
+    df = DataFrame({"id": list(range(5))}).withColumn(
+        "ids", list_column(ids, dtype=np.int32))
+    back = df.column_to_numpy("ids")
+    assert back.dtype == np.int32
+    np.testing.assert_array_equal(back, ids)
+    assert [r["ids"] for r in df.collect()] == ids.tolist()
+
+
+def test_an_int32_column_in_chunks(monkeypatch):
+    """Past the values one list array's offsets address the column comes
+    back in chunks, as the float column does."""
+    monkeypatch.setattr(dataframe_module, "_LIST_VALUES_LIMIT", 10)
+    ids = np.arange(28, dtype=np.int32).reshape(7, 4)
+    col = list_column(ids, dtype=np.int32)
+    assert isinstance(col, pa.ChunkedArray) and col.num_chunks == 4
+    assert col.type == INT_LIST
+    df = DataFrame(pa.table({"ids": col}))
+    np.testing.assert_array_equal(df.column_to_numpy("ids"), ids)
+
+
+def test_only_float32_and_int32_columns_are_made():
+    with pytest.raises(TypeError, match="float32"):
+        list_column(np.zeros((2, 2)), dtype=np.float64)

@@ -135,3 +135,57 @@ def test_the_platform_picks_the_form(monkeypatch):
     monkeypatch.setattr(attention, "_on_tpu", lambda: False)
     assert attention.causal_attention(q, k, v, heads=HEADS,
                                       kv_heads=KV).shape == q.shape
+
+
+# -- the mask by blocks (a static block length) ------------------------------
+
+def _full_by_blocks(q, k, v, block_length):
+    qh = q.reshape(ROWS, T, HEADS, HD)
+    kh, vh = (jnp.repeat(u.reshape(ROWS, T, KV, HD), HEADS // KV, axis=2)
+              for u in (k, v))
+    at = jnp.arange(T)
+    seen = at[None, :] // block_length <= at[:, None] // block_length
+    s = jnp.where(seen, jnp.einsum("rqhd,rkhd->rhqk", qh, kh), -jnp.inf)
+    return jnp.einsum("rhqk,rkhd->rqhd", jax.nn.softmax(s, -1),
+                      vh).reshape(ROWS, T, HEADS * HD)
+
+
+@pytest.mark.parametrize("block_length", [2, 4, 8])
+@pytest.mark.parametrize("block", [8, 16, T])
+@pytest.mark.parametrize("form", list(FORMS))
+def test_a_block_length_is_the_mask_by_blocks(form, block, block_length):
+    """A position sees every earlier block and its own block both ways."""
+    q, k, v = _qkv(5)
+    got = FORMS[form](q, k, v, block=block, block_length=block_length)
+    want = _full_by_blocks(q, k, v, block_length)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+    assert np.abs(np.asarray(got - _full(q, k, v))).max() > 1e-2
+
+
+@pytest.mark.parametrize("form", list(FORMS))
+def test_block_length_1_is_the_causal_form_bit_for_bit(form):
+    q, k, v = _qkv(6)
+    np.testing.assert_array_equal(
+        np.asarray(FORMS[form](q, k, v, block=8, block_length=1)),
+        np.asarray(FORMS[form](q, k, v, block=8)))
+
+
+def test_block_length_1_is_the_same_kernel_program():
+    """Static and 1 by default: the kernel's program is the one the two
+    sequence cells ran before there was a block length."""
+    q, k, v = _qkv()
+    lowered = [attention.attention_kernel.lower(
+        q, k, v, heads=HEADS, kv_heads=KV, block=8, interpret=True,
+        **kw).as_text() for kw in ({}, {"block_length": 1})]
+    assert lowered[0] == lowered[1]
+
+
+def test_a_block_length_fills_the_query_block_and_takes_no_window():
+    q, k, v = _qkv()
+    for form in FORMS.values():
+        with pytest.raises(ValueError, match="do not fill"):
+            form(q, k, v, block=8, block_length=3)
+        with pytest.raises(ValueError, match="causal mask alone"):
+            form(q, k, v, block=8, block_length=4, window=4)
+        with pytest.raises(ValueError, match="causal mask alone"):
+            form(q, k, v, block=8, block_length=4, causal=False)

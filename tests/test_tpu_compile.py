@@ -325,3 +325,99 @@ def test_the_expert_trunk_program_compiles_for_v5e(v5e_chip, monkeypatch):
     assert 9.0e9 < mem.argument_size_in_bytes < 9.03e9
     assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
             + mem.output_size_in_bytes) < 13.5e9
+
+
+# -- block diffusion: the kernels as the generator calls them, and its program
+
+def test_block_mask_attention_compiles_for_v5e_at_published_widths(v5e_chip):
+    """The attention kernel under the mask by blocks of 4 at a group of
+    ``sdar_30b_a3b_chat.gen256``'s prefill: 8 prompts of 1024 ids, 32
+    query heads on 4 key/value heads of 128."""
+    from sparkdl_tpu.ops import attention
+
+    rows, t, heads, kv, hd = 8, 1024, 32, 4, 128
+    compiled = attention.attention_kernel.lower(
+        _on_chip((rows, t, heads * hd), jnp.bfloat16, v5e_chip),
+        _on_chip((rows, t, kv * hd), jnp.bfloat16, v5e_chip),
+        _on_chip((rows, t, kv * hd), jnp.bfloat16, v5e_chip),
+        heads=heads, kv_heads=kv, block_length=4).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and f"%{attention.NAME}." in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+def test_grouped_matmul_compiles_for_v5e_at_a_pass_of_the_loop(v5e_chip):
+    """The expert kernel as a pass of the generation loop calls it: a
+    chunk of 74 tiles of 256 rows for 2,048 pairs (16 rows an expert),
+    2048 wide, the 6 x 128 experts of width 768 read in place."""
+    from sparkdl_tpu.ops import grouped_matmul as gm
+
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    slots, width, f, experts = 74 * gm.TILE, 2048, 768, 6 * 128
+    compiled = gm.grouped_matmul_kernel.lower(
+        _on_chip((slots, width), bf16, v5e_chip),
+        _on_chip((experts, width, 2 * f), bf16, v5e_chip),
+        _on_chip((experts, f, width), bf16, v5e_chip),
+        _on_chip((slots // gm.TILE,), i32, v5e_chip),
+        _on_chip((), i32, v5e_chip), _on_chip((), i32, v5e_chip),
+        out_dtype=jnp.float32).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and f"%{gm.NAME}." in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+def test_the_block_diffusion_program_compiles_for_v5e(v5e_chip, monkeypatch):
+    """``sdar_30b_a3b_chat``'s whole program — prefill, the loop with its
+    cache, the head and the sampler — through the engine's dispatch
+    program (kernel paths on) at the cell's dispatch of 64 prompts of
+    1,024 ids: it compiles; the prefill's attention is ONE instruction
+    and the expert kernel two (the prefill's and the loop's), whatever
+    the depth and the number of passes; the generation loop is the one
+    ``while`` whose carry leads with the generated ids (the device
+    trace's line that ``diffusion_flops.loop_seconds`` reads); and the
+    dispatch fits the chip beside its 8.72 GB of weights."""
+    import json
+    import os
+    import re
+
+    from jax.sharding import Mesh
+
+    from benchmark import trace_reduce
+    from sparkdl_tpu.models import block_diffusion
+    from sparkdl_tpu.ops import attention, grouped_matmul
+    from sparkdl_tpu.parallel import mesh as mesh_lib
+    from sparkdl_tpu.parallel.engine import build_dispatch_jit
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "sdar_30b_a3b_chat.json")) as fh:
+        config = json.load(fh)
+    monkeypatch.setattr(grouped_matmul, "_on_tpu", lambda: True)
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    device = next(iter(v5e_chip.device_set))
+    mesh = Mesh(np.asarray([device]).reshape(1, 1),
+                (mesh_lib.DATA_AXIS, mesh_lib.MODEL_AXIS))
+    variables = jax.eval_shape(lambda k: block_diffusion.init(config, k),
+                               jax.random.PRNGKey(0))
+    fn = block_diffusion.model_function(
+        config, {}, generated_length=config["generated_length"],
+        denoise_steps=config["denoise_steps"]).fn
+    compiled = build_dispatch_jit(fn, mesh, donate_batch=False).lower(
+        variables, {"ids": jax.ShapeDtypeStruct(
+            (64, config["prompt_length"]), np.int32)}).compile()
+    text = compiled.as_text()
+    kernels = sorted(name.split(".")[0] for name in set(re.findall(
+        r"%((?:causal_attention|grouped_matmul)\.\d+) = ", text)))
+    assert kernels == ["causal_attention", "grouped_matmul",
+                       "grouped_matmul"], kernels
+    loops = [trace_reduce.short_op_name(line.strip())
+             for line in text.splitlines()
+             if re.match(r"\s*%while[\w.]* = ", line)]
+    assert [name.split(" ", 1)[1] for name in loops
+            if "s32[64,256]" in name] == ["s32[64,256] while"], loops
+    mem = compiled.memory_analysis()
+    # the issue's arithmetic: 6 x 1.246 + 1.244 GB of weights
+    assert 8.72e9 < mem.argument_size_in_bytes < 8.73e9
+    # the cache (1.007 GB) and the prefill's and a pass's temporaries
+    assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes) < 13.0e9
