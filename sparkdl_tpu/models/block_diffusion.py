@@ -46,7 +46,7 @@ a softmax.  The program returns, a row, ``generated`` int32 ``[L]``,
 ``revealed_at`` int32 ``[L]`` (the pass, 1 and on, that revealed the
 position), ``features`` float32 ``[3 L]`` (a position's chosen logit,
 the ``logsumexp`` over the vocabulary and a zero, at the pass that
-revealed it) and the counter ``diffusion_counts`` int32 ``[6]``
+revealed it) and the counter ``diffusion_counts`` int32 ``[7]``
 (``COUNTS``; ``ModelFunction.counter_names``).
 
 Weights, the cache and matrix-product operands are in the compute dtype
@@ -73,14 +73,16 @@ from sparkdl_tpu.ops.attention import causal_attention
 
 #: the program's outputs that are counters, not columns
 COUNTERS = ("diffusion_counts",)
-#: what ``diffusion_counts`` ``[rows, 6]`` counts, a row: the passes with
+#: what ``diffusion_counts`` ``[rows, 7]`` counts, a row: the passes with
 #: and without the head, the ids revealed, the tokens that were routed
 #: (positions x layers, the prompt's among them), the token-expert pairs
-#: computed, and — on a dispatch's FIRST row, 0 on the others — the
-#: experts with at least one pair, summed over the loop's passes and the
-#: layers (the prefill's are not among them)
+#: computed, and — on a dispatch's FIRST row, 0 on the others, summed
+#: over the loop's passes and the layers (the prefill's are not among
+#: them) — the experts with at least one pair and the slots that
+#: ``_held_experts``' chunk turns worked through (turns x chunk: the
+#: loop's pairs over them is the slots' fill)
 COUNTS = ("denoise_passes", "commit_passes", "revealed_ids", "tokens",
-          "pairs", "touched_experts")
+          "pairs", "touched_experts", "expert_slots")
 #: rows of prompts that go through the layers together in the prefill
 PREFILL_ROWS = 8
 _NEG = -1e30          # a score no softmax notices; finite, so no NaN
@@ -185,7 +187,8 @@ def _layer(config: Dict[str, Any], x, w, index, experts, first, attend,
     ``first`` and on.  ``attend(q, k, v)`` is the attention over whatever
     the caller lets these queries see.  Returns the new ``x``, the keys
     and values of these positions ``[R, T, KV*hd]`` in the compute dtype,
-    and the tokens by row and held expert."""
+    the tokens by row and held expert, and the expert slots worked
+    through."""
     c, f32 = config, jnp.float32
     eps, theta = c["rms_norm_eps"], c["rope_theta"]
     heads, kv_heads = c["num_attention_heads"], c["num_key_value_heads"]
@@ -210,11 +213,11 @@ def _layer(config: Dict[str, Any], x, w, index, experts, first, attend,
         chosen, weight = expert_trunk._route(
             routing, u.reshape(-1, u.shape[-1]), w["mlp.gate"],
             scores="softmax")
-    m, load = expert_trunk._held_experts(
+    m, load, slots = expert_trunk._held_experts(
         routing, u, chosen, weight, experts["mlp.experts.gate_up_proj"],
         experts["mlp.experts.down_proj"], index * routing["num_experts"],
         dtype, precision)
-    return x + m, k, v, load
+    return x + m, k, v, load, slots
 
 
 def _attend_cache(q, k, v, cache_k, cache_v, filled, *, heads: int,
@@ -273,7 +276,7 @@ def apply(variables: Dict[str, Any], ids, config: Dict[str, Any], *,
           generated_length: int, denoise_steps: int, precision=None):
     """``ids`` ``[R, P]`` integers -> ``{"generated": [R, L] int32,
     "revealed_at": [R, L] int32, "features": [R, 3 L] float32,
-    "diffusion_counts": [R, 6] int32}``.  The compute dtype is the
+    "diffusion_counts": [R, 7] int32}``.  The compute dtype is the
     weights' own."""
     c, f32, i32 = config, jnp.float32, jnp.int32
     dtype = variables["embed_tokens"].dtype
@@ -298,10 +301,10 @@ def apply(variables: Dict[str, Any], ids, config: Dict[str, Any], *,
     def through_layers(x, first, attend_layer):
         def layer(x, scanned):
             w, index = scanned
-            x, k, v, load = _layer(
+            x, k, v, load, slots = _layer(
                 c, x, w, index, experts, first,
                 functools.partial(attend_layer, index), dtype, precision)
-            return x, (k, v, load)
+            return x, (k, v, load, slots)
         return lax.scan(layer, x, (layers, layer_index))
 
     # -- the prompt, a few rows at a time, its keys and values into the cache
@@ -310,7 +313,7 @@ def apply(variables: Dict[str, Any], ids, config: Dict[str, Any], *,
     def prefill(i, carry):
         cache_k, cache_v, pairs = carry
         rows = lax.dynamic_slice_in_dim(ids, i * group, group)
-        _, (k, v, load) = through_layers(
+        _, (k, v, load, _) = through_layers(
             embed(rows), None,
             lambda index, q, k, v: causal_attention(
                 q, k, v, heads=heads, kv_heads=kv_heads, block_length=b,
@@ -330,8 +333,8 @@ def apply(variables: Dict[str, Any], ids, config: Dict[str, Any], *,
     turns = denoise_steps + 1               # a block's passes, the commit last
 
     def one_pass(state):
-        (generated, revealed_at, features, cache_k, cache_v, pairs, touched,
-         passes, n) = state
+        (generated, revealed_at, features, cache_k, cache_v, pairs,
+         expert_counts, passes, n) = state
         at, step = n // turns * b, n % turns
         first = p + at
         denoise_pass, commit = _turn(step, denoise_steps)
@@ -348,9 +351,12 @@ def apply(variables: Dict[str, Any], ids, config: Dict[str, Any], *,
                                  kv_heads=kv_heads, precision=precision)
 
         with jax.named_scope("block_pass"):
-            x, (k, v, load) = through_layers(embed(tokens), first, attend)
+            x, (k, v, load, slots) = through_layers(embed(tokens), first,
+                                                    attend)
         pairs = pairs + jnp.sum(load, axis=(0, 2))
-        touched = touched + jnp.sum(jnp.sum(load, axis=1) > 0, dtype=i32)
+        # the experts touched and the slots worked, all layers of this pass
+        expert_counts = expert_counts + jnp.stack([
+            jnp.sum(jnp.sum(load, axis=1) > 0, dtype=i32), jnp.sum(slots)])
         passes = passes + jnp.stack([denoise_pass, ~denoise_pass]).astype(i32)
 
         with jax.named_scope("commit_pass"):
@@ -386,21 +392,22 @@ def apply(variables: Dict[str, Any], ids, config: Dict[str, Any], *,
                                 start_index=at, axis=1)
         return (put(generated, block_ids), put(revealed_at, block_at),
                 put(features, block_features), cache_k, cache_v, pairs,
-                touched, passes, n + 1)
+                expert_counts, passes, n + 1)
 
     with jax.named_scope("generation"):
         blank = jnp.zeros((r, length), i32)
         state = lax.while_loop(
             lambda state: state[-1] < length // b * turns, one_pass,
             (blank, blank, jnp.zeros((r, length, 3), f32), cache_k, cache_v,
-             pairs, jnp.int32(0), jnp.zeros((2,), i32), jnp.int32(0)))
-    generated, revealed_at, features, _, _, pairs, touched, passes, _ = state
+             pairs, jnp.zeros((2,), i32), jnp.zeros((2,), i32), jnp.int32(0)))
+    (generated, revealed_at, features, _, _, pairs, expert_counts, passes,
+     _) = state
     positions = p + (passes[0] + passes[1]) * b
     counts = jnp.stack([
         jnp.broadcast_to(passes[0], (r,)), jnp.broadcast_to(passes[1], (r,)),
         jnp.sum(revealed_at > 0, axis=1, dtype=i32),
         jnp.broadcast_to(positions * depth, (r,)), pairs,
-        jnp.zeros((r,), i32).at[0].set(touched)], axis=1)
+        *jnp.zeros((2, r), i32).at[:, 0].set(expert_counts)], axis=1)
     return {"generated": generated, "revealed_at": revealed_at,
             "features": features.reshape(r, 3 * length),
             "diffusion_counts": counts}

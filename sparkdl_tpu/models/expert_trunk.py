@@ -275,27 +275,39 @@ def _route(config: Dict[str, Any], tokens, router, bias=None,
 
 
 def _held_experts(config: Dict[str, Any], h2, chosen, weight, gate_up, down,
-                  first_group, dtype, precision, tile: int = gm.TILE,
+                  first_group, dtype, precision, tile: Optional[int] = None,
                   chunk_tiles: Optional[int] = None):
     """``(sum over a token's chosen experts that are held here of w_e
     expert_e(h2) [R, T, D] float32, tokens by row and held expert [R,
-    held] int32)``.
+    held] int32, the slots worked through, an int32 scalar)``.
 
     The pairs of a token and a held expert are sorted by expert and laid
     out in slots, every expert on tiles of its own (``ops/grouped_matmul``).
-    The slots are worked off ``chunk_tiles`` tiles at a time under a
-    ``lax.while_loop``: a chunk's rows are gathered, run through the
-    experts, weighted and added to their tokens.  The chunk is a quarter
-    over what uniform routing needs, so the loop runs once then; under
-    any imbalance it runs as often as there are pairs to compute.  No
-    pair is dropped and memory is the chunk's, not the worst case's."""
+    The tile follows the rows a held expert expects under even routing,
+    read off the static shapes (``grouped_matmul.tile_for``): the gather,
+    the weighting and the scatter-add below cost by the slot, filled or
+    not.  The slots are worked off ``chunk_tiles`` tiles at a time under
+    a ``lax.while_loop``: a chunk's rows are gathered, run through the
+    experts, weighted and added to their tokens.  Where an expert
+    expects a tile or more, the chunk is a quarter over what uniform
+    routing needs plus half a tile an expert, so the loop runs once
+    then; where it expects less, every touched expert costs a whole tile
+    however few its rows, so the chunk is that quarter over plus a WHOLE
+    tile an expert — with every expert held here the worst fall's few
+    thousand slots: one turn whatever the routing.  Under any imbalance
+    the loop runs as often as there are pairs to compute.  No pair
+    is dropped and memory is the chunk's, not the worst case's.  The
+    slots worked through are the loop's turns times the chunk."""
     f32, i32 = jnp.float32, jnp.int32
     r, t, d = h2.shape
     held, k = config["num_experts"], chosen.shape[-1]
     pairs = r * t * k
+    expected = pairs * held / routed_experts(config)
+    if tile is None:
+        tile = gm.tile_for(expected / held)
     if chunk_tiles is None:
-        expected = pairs * held / routed_experts(config)
-        chunk_tiles = -(-int(1.25 * expected) // tile) + held // 2
+        spare = held // 2 if expected >= held * tile else held
+        chunk_tiles = -(-int(1.25 * expected) // tile) + spare
     slots = gm.slots_for(pairs, held, tile)
     chunk_tiles = min(chunk_tiles, slots // tile)
     chunk = chunk_tiles * tile
@@ -339,10 +351,10 @@ def _held_experts(config: Dict[str, Any], h2, chosen, weight, gate_up, down,
                 out, mode="drop")
         return at + 1, total
 
-    _, total = lax.while_loop(
+    turns, total = lax.while_loop(
         lambda state: state[0] * chunk_tiles < layout.tiles_in_use,
         one_chunk, (jnp.int32(0), jnp.zeros((r * t, d), f32)))
-    return total.reshape(r, t, d), load
+    return total.reshape(r, t, d), load, turns * chunk
 
 
 def apply(variables: Dict[str, Any], ids, config: Dict[str, Any], *,
@@ -382,7 +394,7 @@ def apply(variables: Dict[str, Any], ids, config: Dict[str, Any], *,
             chosen, weight = _route(c, h2.reshape(r * t, -1),
                                     w["mlp.router.gate"],
                                     w["mlp.expert_bias"])
-        m, load = _held_experts(
+        m, load, _ = _held_experts(
             c, h2, chosen, weight, stacks["mlp.experts.gate_up_proj"],
             stacks["mlp.experts.down_proj"], layer * held, dtype, precision)
         with jax.named_scope("shared_expert"):

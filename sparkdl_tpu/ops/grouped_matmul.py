@@ -9,8 +9,14 @@ of ``tile`` rows, and every group starts on a tile of its own
 (``aligned_layout`` says where, from the groups' lengths), so a tile
 belongs to ONE group and the kernel's grid step is one plain tile of a
 matrix product with the group's matrices.  What that costs is the empty
-end of each group's last tile (half a tile a group on average); what it
-saves is every mask inside the kernel.  ``gate_up`` ``[E, K, 2F]`` holds
+end of each group's last tile (half a tile a group on average where a
+group fills several, nearly the whole tile where it fills less than
+one); what it saves is every mask inside the kernel.  So the tile
+follows the rows a group is EXPECTED to get (``tile_for``, from the
+caller's static shapes): ``TILE`` where a group expects that many or
+more, down to ``MIN_TILE`` where it expects a handful — everything a
+caller lays out by the slot (its gather, its weighting, its scatter-add)
+costs by the slot, filled or not.  ``gate_up`` ``[E, K, 2F]`` holds
 ``W_gate | W_up`` side by side, ``down`` ``[E, F, N]``; both hold the
 groups' matrices from ``first_group`` on (a scalar of the program: the
 matrices of several layers lie stacked in one array and none is copied
@@ -23,7 +29,11 @@ blocks of ``F``.  A step multiplies the tile by a block of ``W_gate``
 and of ``W_up`` (``K`` whole in the block, float32 accumulation), gates
 in float32, rounds to the operands' dtype and adds the product with the
 block of ``W_down`` to the tile's float32 accumulator in VMEM: the
-``[rows, F]`` activations never reach memory.  Tiles past
+``[rows, F]`` activations never reach memory.  A block is ``F`` whole
+where a group's three matrices fit VMEM twice over (``block_f_for``):
+then consecutive tiles of one group stand still in EVERY index of the
+weights and the group's matrices are fetched once, however many tiles
+it fills; cut into blocks, every tile fetches them again.  Tiles past
 ``tiles_in_use`` name the last tile in use again, which fetches nothing,
 and compute nothing.  Elsewhere two ``jax.lax.ragged_dot`` with the same
 roundings.  The platform picks, as in ``ops/attention``.
@@ -32,6 +42,7 @@ roundings.  The platform picks, as in ``ops/attention``.
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple, Optional
 
 import jax
@@ -41,11 +52,19 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NAME = "grouped_matmul"
-TILE = 256          # rows: 256 operations a byte of weights, the chip's ridge
+#: rows of the largest tile: 256 operations a byte of weights, the
+#: chip's ridge — what a group that expects 256 rows or more is given,
+#: and ``tile_for``'s ceiling, not THE tile
+TILE = 256
+#: rows of the smallest: bfloat16 packs 16 rows to a sublane group (the
+#: float32 accumulator and output want a multiple of 8)
+MIN_TILE = 16
 BLOCK_F = 512
 #: two copies of each block at the sizes above and K, N of a few
 #: thousand (the default scoped limit is 16 MiB of the chip's 128)
 VMEM_LIMIT = 64 * 2**20
+#: what both copies of a step's three weight blocks may take of it
+WEIGHT_BLOCKS = 24 * 2**20
 
 
 def _on_tpu() -> bool:
@@ -60,6 +79,26 @@ class Layout(NamedTuple):
     slot_group: jax.Array      # [slots] the group a slot belongs to
     slot_rank: jax.Array       # [slots] which of its group's rows it holds
     slot_filled: jax.Array     # [slots] bool: it holds a row
+
+
+def tile_for(rows_a_group: float) -> int:
+    """The tile for groups that expect ``rows_a_group`` rows each under
+    even routing: that, rounded up to a power of two, held between
+    ``MIN_TILE`` and ``TILE``.  No room over the mean is worth its slots:
+    at 16 rows a group a tile of 16 ran the generation loop in 3.55 s a
+    dispatch, 32 in 3.70, 64 in 4.43, 128 in 6.05 (PERF.md, PR 38) — a
+    group over the mean takes a second tile of its own matrices, which
+    stand still in the kernel, and every slot costs its gather and its
+    scatter-add, filled or not."""
+    rows = max(1, math.ceil(rows_a_group))
+    return min(TILE, max(MIN_TILE, 1 << (rows - 1).bit_length()))
+
+
+def block_f_for(k: int, f: int, n: int, itemsize: int) -> int:
+    """The width a step's blocks of ``F`` should have: ``F`` whole where
+    both copies of the three blocks fit ``WEIGHT_BLOCKS``, else
+    ``BLOCK_F``."""
+    return f if 2 * (2 * k + n) * f * itemsize <= WEIGHT_BLOCKS else BLOCK_F
 
 
 def slots_for(rows: int, groups: int, tile: int = TILE) -> int:
@@ -147,15 +186,19 @@ def _kernel(group_ref, meta_ref, x_ref, gate_ref, up_ref, down_ref, out_ref,
     "tile", "block_f", "out_dtype", "interpret", "precision"))
 def grouped_matmul_kernel(x, gate_up, down, tile_group, tiles_in_use,
                           first_group=0, *, tile: int = TILE,
-                          block_f: int = BLOCK_F, out_dtype=None,
+                          block_f: Optional[int] = None, out_dtype=None,
                           interpret: bool = False, precision=None):
-    """The Pallas kernel; the slots are whole tiles."""
+    """The Pallas kernel; the slots are whole tiles.  ``block_f`` is the
+    tests'; ``block_f_for`` where none is given, and either way the next
+    narrower width that divides ``F``."""
     slots, k = x.shape
     f, n = down.shape[1:]
     if slots % tile or tile_group.shape[0] != slots // tile:
         raise ValueError(f"{slots} slots are not {tile_group.shape[0]} "
                          f"tiles of {tile}")
-    block_f = next(b for b in (block_f, 256, 128, f) if f % b == 0)
+    block_f = next(b for b in (
+        block_f or block_f_for(k, f, n, gate_up.dtype.itemsize), 256, 128, f)
+        if f % b == 0)
     meta = jnp.stack([jnp.asarray(tiles_in_use, jnp.int32),
                       jnp.asarray(first_group, jnp.int32)])
 

@@ -74,21 +74,25 @@ def _count_expert_load(load, metrics, tokens):
 
 
 def _count_diffusion(counts, metrics, tokens):
-    """``diffusion_counts`` ``[rows, 6]`` (``block_diffusion.COUNTS``): a
+    """``diffusion_counts`` ``[rows, 7]`` (``block_diffusion.COUNTS``): a
     generator's own count of its passes, the ids it revealed, the
     positions it routed (it makes positions of its own, so the input's
     ids say nothing of them) and their pairs, summed over the rows, and
-    the experts its loop touched.  ``tokens`` is the prompts' ids."""
-    (denoise, commit, revealed, routed, pairs,
-     touched) = (int(v) for v in counts.sum(axis=0))
+    the experts its loop touched and the slots the loop laid its pairs
+    out in (``diffusion.expert_slots``: the loop's pairs over them is
+    the slots' fill).  ``tokens`` is the prompts' ids."""
+    (denoise, commit, revealed, routed, pairs, touched,
+     slots) = (int(v) for v in counts.sum(axis=0))
     for name, value in (("diffusion.denoise_passes", denoise),
                         ("diffusion.commit_passes", commit),
                         ("diffusion.revealed_ids", revealed),
                         ("diffusion.touched_experts", touched),
+                        ("diffusion.expert_slots", slots),
                         ("moe.tokens", routed), ("moe.pairs", pairs)):
         metrics.incr(name, value)
     attrs = {"generated_ids": revealed, "denoise_passes": denoise,
-             "commit_passes": commit, "expert_pairs": pairs}
+             "commit_passes": commit, "expert_pairs": pairs,
+             "expert_slots": slots}
     if tokens is not None:
         attrs["prompt_tokens"] = tokens
     return attrs

@@ -122,7 +122,13 @@ def test_the_program_is_the_plain_sampler_and_reads_what_it_reads(
     np.testing.assert_array_equal(counts[:, 4], 2 * 2 * positions)
     # a dispatch's touched experts on its first row: at most all 8 a layer
     assert 0 < counts[0, 5] <= 2 * 8 * 4 * (steps + 1)
-    assert (counts[1:, 5] == 0).all()
+    # and the slots its chunk loop worked through, by hand: a pass routes
+    # 3 rows x 4 positions x 2 = 24 pairs to 8 experts, 3 an expert, so
+    # the tile is the smallest (16) and the chunk a whole tile an expert
+    # over the pairs' two: the worst fall's 10 tiles, ONE turn a layer
+    assert counts.shape == (3, len(bd.COUNTS)) == (3, 7)
+    assert counts[0, 6] == 10 * 16 * 2 * 4 * (steps + 1)
+    assert (counts[1:, 5:] == 0).all()
 
 
 def test_the_cache_holds_the_commit_passes_keys(weights, prompts,
@@ -169,8 +175,8 @@ def test_the_softmax_router_over_all_experts_is_the_dense_sum():
     np.testing.assert_allclose(np.sort(np.asarray(weight), axis=-1),
                                np.asarray(top / top.sum(-1, keepdims=True)),
                                rtol=1e-6)
-    got, load = et._held_experts(routing, h, chosen, weight, gate_up, down, 0,
-                                 f32, HIGHEST, tile=8)
+    got, load, _ = et._held_experts(routing, h, chosen, weight, gate_up, down,
+                                    0, f32, HIGHEST, tile=8)
     dense = jnp.zeros((10, 16), f32)
     for e in range(8):
         gate, up = jnp.split(jnp.dot(h.reshape(10, 16), gate_up[e],
@@ -226,12 +232,19 @@ def test_the_stage_writes_integer_columns_and_counts_its_passes(weights,
     assert counters["moe.tokens"] == 3 * 2 * (16 + 48)
     assert counters["moe.pairs"] == 2 * counters["moe.tokens"]
     assert counters["diffusion.touched_experts"] > 0
+    # the engine rounds the dispatch up to the mesh's 8 rows (3 real):
+    # 64 pairs a pass in the worst fall's 12 tiles of 16, one turn a
+    # layer, 12 passes x 2 layers; the slots' fill is the loop's pairs
+    # (the padded rows' too: they are laid out like any) over the slots
+    assert counters["diffusion.expert_slots"] == 12 * 2 * 12 * 16
+    assert 12 * 2 * 64 / counters["diffusion.expert_slots"] == 1 / 3
     assert counters["engine.rows"] == 3
     (run,), (pack_in,) = spans["transform.run"], spans["transform.pack_in"]
     assert run["attrs"]["prompt_tokens"] == 3 * 16
     assert run["attrs"]["generated_ids"] == 3 * 16
     assert run["attrs"]["denoise_passes"] == 3 * 8
     assert run["attrs"]["commit_passes"] == 3 * 4
+    assert run["attrs"]["expert_slots"] == counters["diffusion.expert_slots"]
     assert "unmapped_outputs" not in run["attrs"]
     assert pack_in["attrs"] == {"rows": 3, "bytes": 3 * 16 * 4}
     assert [(s["attrs"]["column"], s["attrs"]["dtype"])

@@ -15,6 +15,7 @@ import pytest
 
 from benchmark.reference import trinity as ref
 from sparkdl_tpu.models import expert_trunk as et
+from sparkdl_tpu.ops import grouped_matmul as gm
 
 #: hidden 64; one dense layer (MLP 160), then a sliding, a full and a
 #: sliding expert layer; 4 query / 2 key-value heads of 16; 8 experts of
@@ -226,7 +227,7 @@ def test_the_shares_of_a_layer_add_up_to_the_whole_layer():
         chosen, weight = et._route(config, h2.reshape(-1, 64),
                                    experts["mlp.router.gate"][0],
                                    experts["mlp.expert_bias"][0])
-        part, took = et._held_experts(
+        part, took, _ = et._held_experts(
             config, h2, chosen, weight, experts["mlp.experts.gate_up_proj"],
             experts["mlp.experts.down_proj"], 0, f32, HIGHEST)
         routed.append(part)
@@ -329,3 +330,122 @@ def test_uneven_routing_takes_more_chunks_and_drops_nothing(weights, ids):
         np.testing.assert_array_equal(np.asarray(run[0][1]),
                                       np.asarray(run[1][1]))
     assert int(run[1][1].sum()) == 3 * 32 * 4      # all held: none dropped
+
+
+# -- the tile is read off the shapes ------------------------------------------
+
+def _tile_and_chunk(monkeypatch, routing, rows, positions, width, **how):
+    """The tile and the chunk's slots ``_held_experts`` hands the kernel
+    at these shapes: traced abstractly, nothing is computed."""
+    seen = []
+
+    def record(x, gate_up, down, tile_group, tiles_in_use, first_group=0, *,
+               tile, out_dtype, precision):
+        seen.append((tile, x.shape[0], tile_group.shape[0]))
+        return jnp.zeros((x.shape[0], down.shape[-1]), out_dtype)
+
+    monkeypatch.setattr(gm, "grouped_matmul", record)
+    held, k = routing["num_experts"], routing["num_experts_per_tok"]
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    shape = jax.ShapeDtypeStruct
+    jax.eval_shape(
+        lambda h2, chosen, weight, gate_up, down: et._held_experts(
+            routing, h2, chosen, weight, gate_up, down, 0, bf16, None, **how),
+        shape((rows, positions, width), f32),
+        shape((rows * positions, k), jnp.int32),
+        shape((rows * positions, k), f32),
+        shape((held, width, 64), bf16), shape((held, 32, width), bf16))
+    (tile, slots, tiles), = seen
+    assert slots == tiles * tile
+    return tile, slots
+
+
+#: the three shapes the benchmark dispatches: the keys ``_held_experts``
+#: reads, rows x positions, and the tile and the chunk each is given
+TRINITY = {"num_experts": 32, "expert_share": [0, 8],
+           "num_experts_per_tok": 4}
+SDAR = {"num_experts": 128, "expert_share": [0, 1], "num_experts_per_tok": 8}
+
+
+@pytest.mark.parametrize("routing, rows, positions, tile, slots", [
+    (TRINITY, 2, 16384, 256, 96 * 256),        # 512 rows an expert
+    (SDAR, 8, 1024, 256, 384 * 256),           # the prefill's group: 512
+    # a pass of the loop, 16 rows an expert: a tile an expert expects to
+    # fill, a quarter over the pairs' 128 tiles and half a tile an expert
+    (SDAR, 64, 4, 16, (160 + 64) * 16),
+    # 17 rows an expert: less than its tile, so a WHOLE tile an expert —
+    # with every expert held, the worst fall's slots
+    (SDAR, 68, 4, 32, 2176 + 128 * 32),
+    (SDAR, 1, 4, 16, 32 + 128 * 16),           # a row alone: the floor
+], ids=["trinity_large_preview.rows16k", "sdar_30b_a3b_chat.gen256 prefill",
+        "sdar_30b_a3b_chat.gen256 loop", "a row over 16 an expert",
+        "one row"])
+def test_the_tile_is_read_off_the_static_shapes(monkeypatch, routing, rows,
+                                                positions, tile, slots):
+    assert _tile_and_chunk(monkeypatch, routing, rows, positions,
+                           128) == (tile, slots)
+
+
+def test_an_explicit_tile_wins(monkeypatch):
+    assert _tile_and_chunk(monkeypatch, SDAR, 64, 4, 128, tile=8) == (
+        8, 2048 + 128 * 8)
+    assert _tile_and_chunk(monkeypatch, SDAR, 64, 4, 128, tile=128,
+                           chunk_tiles=3) == (128, 3 * 128)
+
+
+#: 272 tokens, two experts a token of sixteen, the first of them held
+#: here (experts 0-7): 34 rows an expert when the routing is even
+HALF = {"num_experts": 8, "expert_share": [0, 2], "num_experts_per_tok": 2}
+TOKENS = 272
+
+
+def _first_choice(routing: str, tile: int):
+    at = np.arange(TOKENS)
+    if routing == "even":
+        return at % 8
+    if routing == "one expert takes all":
+        return np.full(TOKENS, 5)
+    # expert 3 one row over a tile, the others what is left
+    return np.where(at <= tile, 3, at % 8)
+
+
+@pytest.mark.parametrize("routing", ["even", "one expert takes all",
+                                     "an expert one row over a tile"])
+@pytest.mark.parametrize("tile", [gm.MIN_TILE, 32, 64, 256])
+def test_held_experts_are_the_dense_sum_at_every_tile(tile, routing):
+    """Every pair computed and none dropped, whatever the tile and
+    however the pairs fall: against the sum written densely over the
+    experts, float32 at ``highest``.  One expert taking all is worked
+    off two tiles a turn: as many turns as it takes."""
+    f32 = jnp.float32
+    d, f = 16, 12
+    keys = jax.random.split(jax.random.PRNGKey(tile), 4)
+    h = jax.random.normal(keys[0], (2, TOKENS // 2, d), f32)
+    gate_up = jax.random.normal(keys[1], (8, d, 2 * f), f32) / 4
+    down = jax.random.normal(keys[2], (8, f, d), f32) / 4
+    first = _first_choice(routing, tile)
+    chosen = jnp.asarray(np.stack([first, 8 + first], axis=1), jnp.int32)
+    weight = jax.random.uniform(keys[3], (TOKENS, 2), f32, 0.1, 1.0)
+    how = {"chunk_tiles": 2} if routing == "one expert takes all" else {}
+    got, load, worked = et._held_experts(
+        HALF, h, chosen, weight, gate_up, down, 0, f32, HIGHEST, tile=tile,
+        **how)
+    tokens = h.reshape(TOKENS, d)
+    dense = jnp.zeros((TOKENS, d), f32)
+    for e in range(8):
+        gate, up = jnp.split(jnp.dot(tokens, gate_up[e], precision=HIGHEST),
+                             2, axis=-1)
+        out = jnp.dot(jax.nn.silu(gate) * up, down[e], precision=HIGHEST)
+        dense = dense + jnp.where(chosen[:, 0] == e, weight[:, 0],
+                                  0.0)[:, None] * out
+    np.testing.assert_allclose(np.asarray(got).reshape(TOKENS, d),
+                               np.asarray(dense), atol=1e-5)
+    sizes = np.bincount(first, minlength=8)
+    np.testing.assert_array_equal(np.asarray(load).sum(axis=0), sizes)
+    # the slots worked through, by hand: whole chunks that cover the
+    # tiles in use; two tiles a turn where one expert takes all
+    in_use = int(np.ceil(sizes / tile).sum())
+    assert int(worked) % tile == 0 and int(worked) >= in_use * tile
+    if how:
+        assert in_use == -(-TOKENS // tile)
+        assert int(worked) == -(-in_use // 2) * 2 * tile

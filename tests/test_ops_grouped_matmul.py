@@ -151,3 +151,58 @@ def test_the_platform_picks_the_form(monkeypatch):
     assert calls[0]["interpret"] is False
     monkeypatch.setattr(gm, "_on_tpu", lambda: False)
     assert gm.grouped_matmul(*args, tile=TILE).shape == (x.shape[0], N)
+
+
+# -- the tile follows the rows a group expects --------------------------------
+
+@pytest.mark.parametrize("rows_a_group, tile", [
+    (512, gm.TILE),                # trinity_large_preview.rows16k; the prefill
+    (256, gm.TILE), (300, gm.TILE), (4096, gm.TILE),
+    (16, 16),                      # a pass of sdar_30b_a3b_chat.gen256's loop
+    (17, 32), (100, 128), (128, 128),        # rounded UP to a power of two
+    (1, 16), (0.25, 16), (0, 16),            # the floor
+], ids=lambda v: str(v))
+def test_the_tile_follows_the_rows_a_group_expects(rows_a_group, tile):
+    """Between bfloat16's sublane packing and the chip's ridge, a power
+    of two: the kernel's blocks stay whole sublane groups."""
+    assert gm.tile_for(rows_a_group) == tile
+    assert (gm.MIN_TILE, gm.TILE) == (16, 256)
+
+
+@pytest.mark.parametrize("k, f, n, itemsize, block", [
+    (3072, 3072, 3072, 2, 512),    # trinity_large_preview: as before the rule
+    (2048, 768, 2048, 2, 768),     # sdar_30b_a3b_chat: 9.4 MB an expert, whole
+    (5120, 1536, 5120, 2, 512),    # too wide to hold whole
+    # float32 operands are twice the bytes: the same rule, a narrower fit
+    (2048, 768, 2048, 4, 512), (2048, 384, 2048, 4, 384),
+], ids=lambda v: str(v))
+def test_a_block_is_f_whole_where_an_experts_matrices_fit_twice(
+        k, f, n, itemsize, block):
+    assert gm.block_f_for(k, f, n, itemsize) == block
+
+
+@pytest.mark.parametrize("block_f", [None, 128], ids=["F whole", "blocks"])
+def test_the_smallest_tile_in_bfloat16_with_an_expert_on_two_tiles(block_f):
+    """The kernel, interpreted, at ``MIN_TILE`` rows a tile against the
+    ``ragged_dot`` form on the same layout: group 1 lies on two
+    consecutive tiles and one row over them on a third (the steps whose
+    weights stand still), group 3 is empty, and tiles past the ones in
+    use are left alone."""
+    bf16, tile = jnp.bfloat16, gm.MIN_TILE
+    sizes = [5, 2 * tile + 1, tile, 0, 3]
+    slots = gm.slots_for(sum(sizes), len(sizes), tile)
+    layout = gm.aligned_layout(jnp.asarray(sizes, jnp.int32), slots, tile)
+    assert list(map(int, layout.tile_group[:6])) == [0, 1, 1, 1, 2, 4]
+    x = jax.random.normal(jax.random.PRNGKey(3), (slots, K), bf16)
+    gate_up, down = _weights(seed=3, dtype=bf16)
+    args = (x, gate_up, down, layout.tile_group, layout.tiles_in_use)
+    got = gm.grouped_matmul_kernel(*args, tile=tile, block_f=block_f,
+                                   out_dtype=jnp.float32, interpret=True)
+    want = gm.grouped_matmul_reference(*args, tile=tile,
+                                       out_dtype=jnp.float32)
+    used = int(layout.tiles_in_use) * tile
+    assert used == 6 * tile and got.dtype == jnp.float32
+    # the same roundings; the order of the float32 sum over F alone differs
+    np.testing.assert_allclose(np.asarray(got[:used]), np.asarray(want[:used]),
+                               atol=2e-3)
+    assert np.abs(np.asarray(want[:used])).max() > 0.1

@@ -320,6 +320,9 @@ def test_the_expert_trunk_program_compiles_for_v5e(v5e_chip, monkeypatch):
     kernels = sorted(set(re.findall(
         r"%(?:causal_attention|grouped_matmul)\.\d+ = ", text)))
     assert len(kernels) == 2, kernels
+    # 512 rows an expert: the tile the rule gives is the ceiling's, the
+    # chunk 96 tiles of 256 — the instruction this cell has run since PR 35
+    assert re.search(r"%grouped_matmul\.\d+ = f32\[24576,3072\]", text)
     mem = compiled.memory_analysis()
     # the issue's arithmetic: 0.242 + 4 x 1.886 + 1.230 GB of weights
     assert 9.0e9 < mem.argument_size_in_bytes < 9.03e9
@@ -348,19 +351,22 @@ def test_block_mask_attention_compiles_for_v5e_at_published_widths(v5e_chip):
 
 def test_grouped_matmul_compiles_for_v5e_at_a_pass_of_the_loop(v5e_chip):
     """The expert kernel as a pass of the generation loop calls it: a
-    chunk of 74 tiles of 256 rows for 2,048 pairs (16 rows an expert),
-    2048 wide, the 6 x 128 experts of width 768 read in place."""
+    chunk of 224 tiles of 16 rows for 2,048 pairs (16 rows an expert),
+    2048 wide, the 6 x 128 experts of width 768 read in place, a block
+    ``F`` whole."""
     from sparkdl_tpu.ops import grouped_matmul as gm
 
     bf16, i32 = jnp.bfloat16, jnp.int32
-    slots, width, f, experts = 74 * gm.TILE, 2048, 768, 6 * 128
+    tile = gm.tile_for(2048 / 128)
+    slots, width, f, experts = 224 * tile, 2048, 768, 6 * 128
+    assert tile == 16 and gm.block_f_for(width, f, width, 2) == f
     compiled = gm.grouped_matmul_kernel.lower(
         _on_chip((slots, width), bf16, v5e_chip),
         _on_chip((experts, width, 2 * f), bf16, v5e_chip),
         _on_chip((experts, f, width), bf16, v5e_chip),
-        _on_chip((slots // gm.TILE,), i32, v5e_chip),
+        _on_chip((slots // tile,), i32, v5e_chip),
         _on_chip((), i32, v5e_chip), _on_chip((), i32, v5e_chip),
-        out_dtype=jnp.float32).compile()
+        tile=tile, out_dtype=jnp.float32).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and f"%{gm.NAME}." in text
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
@@ -410,6 +416,11 @@ def test_the_block_diffusion_program_compiles_for_v5e(v5e_chip, monkeypatch):
         r"%((?:causal_attention|grouped_matmul)\.\d+) = ", text)))
     assert kernels == ["causal_attention", "grouped_matmul",
                        "grouped_matmul"], kernels
+    # the prefill's chunk of 384 tiles of 256 as before; the loop's, the
+    # one with the fewer rows, 224 tiles of 16
+    assert sorted(int(n) for n in re.findall(
+        r"%grouped_matmul\.\d+ = f32\[(\d+),2048\]", text)) == [
+            3584, 98304]
     loops = [trace_reduce.short_op_name(line.strip())
              for line in text.splitlines()
              if re.match(r"\s*%while[\w.]* = ", line)]
