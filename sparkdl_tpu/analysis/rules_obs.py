@@ -2,7 +2,8 @@
 
 * Names passed to ``Metrics`` recorders (``incr``/``gauge``/
   ``record_time``/``observe``) and to tracer span constructors
-  (``span``/``start_span``) must match the project's dotted-lowercase
+  (``span``/``start_span``, and ``record``, the span closed at birth)
+  must match the project's dotted-lowercase
   schema ``segment(.segment)*`` with ``[a-z0-9_]`` segments — the
   exporters (Prometheus text, Chrome trace, trace_summary) key on these
   strings, so one camelCase stray forks a time series forever.
@@ -27,6 +28,8 @@ from sparkdl_tpu.analysis.core import Finding, LintContext, Module
 _NAME_RE = re.compile(r"^[a-z][a-z0-9_]*(\.[a-z0-9_]+)*$")
 _METRIC_METHODS = {"incr", "gauge", "record_time", "observe"}
 _SPAN_METHODS = {"span", "start_span"}
+#: named like a span, but closed at birth: nothing to pair
+_CLOSED_SPAN_METHODS = {"record"}
 
 
 def _method_call(node: ast.AST, methods) -> Optional[str]:
@@ -39,7 +42,8 @@ def _method_call(node: ast.AST, methods) -> Optional[str]:
 def rule_sdl005_names(module: Module, ctx: LintContext) -> List[Finding]:
     findings: List[Finding] = []
     for node in ast.walk(module.tree):
-        method = _method_call(node, _METRIC_METHODS | _SPAN_METHODS)
+        method = _method_call(node, _METRIC_METHODS | _SPAN_METHODS
+                              | _CLOSED_SPAN_METHODS)
         if method is None or not node.args:
             continue
         first = node.args[0]

@@ -50,7 +50,10 @@ the way, 0 wherever ``frame.list_column`` built the column from the
 model's matrix, ``topK`` a row under ``decodePredictions``;
 ``transformers.tensor.ModelTransformer`` opens the same three spans),
 ``parallel.engine.
-InferenceEngine`` (call/dispatch spans; ``engine.pad`` —
+InferenceEngine`` (call/dispatch spans; ``engine.build`` once an
+engine — ``param_bytes``/``device_batch_size``/``jit_cached`` — around
+the cast, the weights' placement and the jit lookup, none on a
+``get_cached_engine`` hit; ``engine.pad`` —
 ``rows``/``pad_rows`` — where a piece is padded and ``engine.h2d`` —
 ``bytes`` — around the dispatch's ``device_put``, host side only;
 breaker open/half-open/close flight events; the
@@ -59,7 +62,14 @@ host wall seconds of ``__call__``, the cost ledger's conservation
 reference),
 ``parallel.compile_cache`` (``compile.persist``/``compile.invalidate``
 flight events + hit/miss counters for the persistent executable
-store), ``parallel.pipeline.PipelinedRunner`` (per-stage spans
+store; and every compile of the process, cache on or off: closed spans
+``compile.trace``/``compile.lower``/``compile.backend`` —
+``program``, and on the last ``cache`` hit/miss/off with ``load_s``/
+``saved_s`` on a hit — under the span whose call compiled
+(``Tracer.record``), their seconds in ``compile_cache.stats()`` as
+``trace_s``/``lower_s``/``backend_s``/``load_s``/``saved_s`` whether
+the tracer is on or not; ``tools/trace_summary.py`` folds them by
+program), ``parallel.pipeline.PipelinedRunner`` (per-stage spans
 with ``block_until_ready``-bracketed device time; ``pipeline.gather``
 carries ``rows``/``bytes`` beside ``device_us``),
 ``serving.fleet.Fleet`` (rollout start/promote/rollback + tenant-shed

@@ -155,7 +155,9 @@ class Span:
         lock hold, so concurrent finishers (worker demux vs. the stall
         watchdog settling the same batch) record the span exactly once —
         the first caller's timestamp/status win."""
-        t1 = time.perf_counter()
+        return self._finish_at(time.perf_counter(), status)
+
+    def _finish_at(self, t1: float, status: str = "ok") -> "Span":
         tracer = self.tracer
         with tracer._ring_lock:
             if self.t1 is not None:
@@ -288,6 +290,21 @@ class Tracer:
         if not self.enabled:
             return NULL_SPAN
         return self._make(name, parent, attrs)
+
+    def record(self, name: str, seconds: float,
+               parent: Optional[Span] = None, **attrs):
+        """A span that is closed at birth: it ended now and began
+        ``seconds`` ago, for an operation whose duration is only
+        reported when it is over (``jax.monitoring``'s compile phases).
+        Parented like :meth:`span` and appended to the ring under the
+        same lock; pushed on no thread stack.  :data:`NULL_SPAN` when
+        disabled."""
+        if not self.enabled:
+            return NULL_SPAN
+        span = self._make(name, parent, attrs)
+        t1 = span.t0                     # stamped on construction: now
+        span.t0 = t1 - seconds
+        return span._finish_at(t1)
 
     def _make(self, name, parent, attrs) -> Span:
         if parent is None:

@@ -63,6 +63,23 @@ ZERO fresh compiles (``misses == 0``) with bit-identical outputs; a
 tampered manifest fingerprint forces a purge + clean recompile instead
 of ever serving a stale executable.
 
+Compile seconds ride the same ``jax.monitoring`` (ISSUE 39), with the
+cache on or off: every program this process traces, lowers and compiles
+(or loads) adds its seconds to :func:`stats` (``trace_s``, ``lower_s``,
+``backend_s``; ``load_s`` and ``saved_s`` on a hit) and, where the
+tracer is on, leaves three closed spans ``compile.trace``,
+``compile.lower`` and ``compile.backend`` with ``program=<fun_name>``
+under whatever span the compiling thread has open (``engine.dispatch``,
+``engine.build``; none for a jit the caller made itself).
+``compile.backend`` says how the executable came: ``cache`` =
+``"hit"`` (with ``load_s``, the retrieval, and ``saved_s``, the compile
+the entry's writer paid less the retrieval), ``"miss"`` (XLA compiled
+and the cache took the executable) or ``"off"`` (no cache took part).
+JAX reports a phase when it ends, on the thread that called the jitted
+function, and traces every jitted function it meets under a program's
+trace too: only the outermost phase of a thread counts, the others'
+seconds are inside it.
+
 Sharing contract (ISSUE 14), for a directory this module chose (not a
 placed one): one cache directory serves ONE deployment configuration.
 The manifest's ``sharding_policies`` set accumulates every engine
@@ -82,11 +99,13 @@ from __future__ import annotations
 
 import json
 import os
-from typing import Any, Dict, List, Optional, Tuple
+import threading
+from typing import Any, Dict, List, Optional, Tuple, Union
 
 from sparkdl_tpu.analysis.lockcheck import named_lock
 from sparkdl_tpu.faults import inject
 from sparkdl_tpu.obs.flight import emit as flight_emit
+from sparkdl_tpu.obs.trace import get_tracer
 from sparkdl_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
@@ -123,8 +142,26 @@ _ON = ("1", "true", "on", "yes")
 _UNSET = object()
 _state: Any = _UNSET    # None = disabled; dict = the resolved snapshot
 _lock = named_lock("parallel.compile_cache")
-_counts = {"hits": 0, "misses": 0}
+_ZERO_COUNTS = {"hits": 0, "misses": 0, "trace_s": 0.0, "lower_s": 0.0,
+                "backend_s": 0.0, "load_s": 0.0, "saved_s": 0.0}
+_counts = dict(_ZERO_COUNTS)
+#: programs compile on whichever thread first calls them (the pipeline's
+#: dispatch and gather threads, a server's workers): ``+=`` is not atomic
+_counts_lock = named_lock("parallel.compile_cache.counts")
 _listener = [False]
+#: ``jax.monitoring``'s compile phases -> the span ``compile.<phase>``
+#: and the counter ``<phase>_s``
+_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+_SAVED = "/jax/compilation_cache/compile_time_saved_sec"
+#: per compiling thread: ``depth`` (compile phases open), and what the
+#: cache said of the backend phase that is open (``cache``, ``load_s``,
+#: ``saved_s``), kept until that phase reports
+_compiling = threading.local()
 #: JAX's own cache directory from before this module first re-pointed it
 #: (at most one element): what ``_reset_for_tests`` puts back
 _jax_dir_before: List[Optional[str]] = []
@@ -148,20 +185,68 @@ def _resolve_env() -> Tuple[Optional[str], bool]:
 
 
 def _install_listener() -> None:
-    """Count jax's compilation-cache monitoring events into
-    :func:`stats` (registered once; the events only fire while the
-    persistent cache is active, so an idle listener costs nothing)."""
+    """Count jax's compile and compilation-cache monitoring events into
+    :func:`stats` and, where the tracer is on, into ``compile.*`` spans
+    (module docstring).  Registered once a process, with the persistent
+    cache on or off: the events fire when something compiles and never
+    per dispatch, so an idle listener costs nothing."""
     if _listener[0]:
         return
     import jax.monitoring as monitoring
 
+    def _add(key: str, amount: Union[int, float]) -> None:
+        with _counts_lock:
+            _counts[key] += amount
+
     def _count(name: str, **kwargs: Any) -> None:
         if name == "/jax/compilation_cache/cache_hits":
-            _counts["hits"] += 1
+            _add("hits", 1)
+            _compiling.cache = "hit"
         elif name == "/jax/compilation_cache/cache_misses":
-            _counts["misses"] += 1
+            _add("misses", 1)
+            _compiling.cache = "miss"
+
+    def _begin(name: str, value: Any, **kwargs: Any) -> None:
+        # jax stamps a phase's start as a scalar of the phase's name
+        if name in _PHASES:
+            said = _compiling.__dict__
+            said["depth"] = said.get("depth", 0) + 1
+
+    def _seconds(name: str, seconds: float, **kwargs: Any) -> None:
+        phase = _PHASES.get(name)
+        if phase is None:
+            if name == _RETRIEVAL:
+                _add("load_s", seconds)
+                _compiling.load_s = seconds
+            elif name == _SAVED:
+                _add("saved_s", seconds)
+                _compiling.saved_s = seconds
+            return
+        said = _compiling.__dict__
+        depth = said["depth"] = max(0, said.get("depth", 1) - 1)
+        attrs = {}
+        if phase == "backend":      # what the cache said of it ends here
+            attrs["cache"] = said.pop("cache", "off")
+            load_s, saved_s = said.pop("load_s", 0.0), said.pop("saved_s", 0.0)
+            if attrs["cache"] == "hit":
+                attrs.update(load_s=load_s, saved_s=saved_s)
+        if depth:
+            # a jitted function met under a program's trace (or traced
+            # by a lowering rule): its seconds are inside the outer's
+            return
+        _add(phase + "_s", seconds)
+        # the function's own name in all three: jax wraps it, ``jit(f)``,
+        # once it is lowered
+        program = kwargs.get("fun_name", "")
+        if program.endswith(")"):
+            program = program[program.find("(") + 1:-1]
+        # looked up at the event: a later configure() replaces the tracer
+        get_tracer().record("compile." + phase, seconds, program=program,
+                            **attrs)
 
     monitoring.register_event_listener(_count)
+    monitoring.register_scalar_listener(_begin)
+    monitoring.register_event_duration_secs_listener(_seconds)
     _listener[0] = True
 
 
@@ -302,6 +387,7 @@ def _configure_locked(dir_path: Optional[str],
     :data:`PLACED_DIR_ENV` — no manifest, no purge, no re-pointing.
     Any failure degrades to DISABLED — the cache must never take down
     serving (an entry point that needs it checks for the ``None``)."""
+    _install_listener()     # compile seconds count with the cache off too
     if dir_path is None:
         return None, []
     try:
@@ -331,7 +417,6 @@ def _configure_locked(dir_path: Optional[str],
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           0.0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        _install_listener()
         return {"dir": dir_path, "placed": placed, **fields}, events
     # graftlint: allow=SDL003 reason=the cache is an optimization: any configure failure (unwritable dir, corrupt manifest, injected fault) is logged and degrades to fresh compiles
     except Exception as e:  # noqa: BLE001
@@ -451,11 +536,17 @@ def state() -> Optional[Dict[str, Any]]:
     return dict(st) if isinstance(st, dict) else None
 
 
-def stats() -> Dict[str, int]:
-    """Persistent-cache hit/miss counters (jax.monitoring events) for
-    THIS process: a warm restart serving lockfile-pinned programs shows
-    ``misses == 0`` — the zero-fresh-compiles proof."""
-    return dict(_counts)
+def stats() -> Dict[str, Union[int, float]]:
+    """This process's compile counters (jax.monitoring events).
+    ``hits``/``misses`` of the persistent cache: a warm restart serving
+    lockfile-pinned programs shows ``misses == 0`` — the
+    zero-fresh-compiles proof.  And the seconds every compile took,
+    cache on or off, by phase: ``trace_s``, ``lower_s``, ``backend_s``
+    (XLA's compile on a miss, the cache's retrieval on a hit), with
+    ``load_s`` (retrieval alone) and ``saved_s`` (what the hits spared,
+    by JAX's own reckoning) beside them."""
+    with _counts_lock:
+        return dict(_counts)
 
 
 def enabled() -> bool:
@@ -470,8 +561,8 @@ def _reset_for_tests() -> None:
     global _state
     with _lock:
         _state = _UNSET
-        _counts["hits"] = 0
-        _counts["misses"] = 0
+        with _counts_lock:
+            _counts.update(_ZERO_COUNTS)
         if _jax_dir_before:
             import jax
 

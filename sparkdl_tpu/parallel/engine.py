@@ -800,6 +800,22 @@ class InferenceEngine:
             threshold=breaker_threshold, cooldown_s=breaker_cooldown_s)
         self._on_dispatch_error = on_dispatch_error
 
+        with get_tracer().span("engine.build",
+                               device_batch_size=b) as build:
+            jit_cached = self._build(fn, variables, compute_dtype,
+                                     partition_rules, param_shardings,
+                                     donate_batch)
+            build.annotate(
+                param_bytes=self._sharding_stats["param_bytes_total"],
+                jit_cached=jit_cached)
+
+    def _build(self, fn, variables, compute_dtype, partition_rules,
+               param_shardings, donate_batch) -> bool:
+        """The part of construction that touches the weights and the
+        program — cast, sharding policy, compile cache, placement, the
+        jit lookup — under ``__init__``'s ``engine.build`` span, so a
+        compile it causes (a cast on the device) is parented there.
+        Returns whether ``_JIT_CACHE`` already held the dispatch jit."""
         if compute_dtype is not None:
             variables = _cast_floating(variables, compute_dtype)
         self._replicated = mesh_lib.replicated_sharding(self.mesh)
@@ -873,11 +889,13 @@ class InferenceEngine:
                     self.sharding_digest)
         key = (id(fn),) + mesh_key
         compiled = _JIT_CACHE.get(key)
-        if compiled is None:
+        jit_cached = compiled is not None
+        if not jit_cached:
             compiled = build_dispatch_jit(fn, self.mesh, donate_batch,
                                           param_shardings=self.param_shardings)
             _JIT_CACHE.put(key, compiled)
         self._compiled = compiled
+        return jit_cached
 
     # -- low level ---------------------------------------------------------
     @staticmethod

@@ -308,6 +308,16 @@ def test_sdl005_schema_names_pass():
     assert codes(src) == []
 
 
+def test_sdl005_covers_the_span_closed_at_birth():
+    bad = "def f(t):\n    t.record('compileBackend', 0.5, program='f')\n"
+    good = "def f(t):\n    t.record('compile.backend', 0.5, program='f')\n"
+    # named like a span; closed when made, so nothing to pair
+    assert codes(bad) == ["SDL005"]
+    assert codes(good) == []
+    dynamic = "def f(t, p):\n    t.record('compile.' + p, 0.5)\n"
+    assert codes(dynamic) == []
+
+
 def test_sdl005_leaked_span_fires():
     dead_local = ("def f(tracer):\n"
                   "    sp = tracer.start_span('serving.request')\n"

@@ -72,9 +72,10 @@ def _one_job(jpeg_dir):
     tracer = obs.configure(enabled=True)
     df = image_io.readImages(jpeg_dir, numPartitions=1)
     read = tracer.snapshot()
-    tracer.clear()
     stage = _featurizer()
+    # built here, outside any call: ``engine.build`` is a root of its own
     pad0 = stage.engine().metrics.counters.get("engine.pad_rows", 0.0)
+    tracer.clear()
     out = stage.transform(df)
     pad = stage.engine().metrics.counters["engine.pad_rows"] - pad0
     return read, tracer.snapshot(), _features(out), pad
@@ -295,7 +296,9 @@ def test_serial_path_yields_the_same_names_without_pipeline(
         InferenceEngine, "map_batches",
         lambda self, batches: map_batches(self, batches, pipeline=False))
     _, serial, serial_features, _ = _one_job(jpeg_dir)
-    names = lambda spans: {s["name"] for s in spans}  # noqa: E731
+    # the compile.* spans are the job's that met the program first
+    names = lambda spans: {  # noqa: E731
+        s["name"] for s in spans if not s["name"].startswith("compile.")}
     assert names(serial) == {n for n in names(piped)
                              if not n.startswith("pipeline.")}
     assert len({s["trace_id"] for s in serial}) == 1
@@ -335,3 +338,40 @@ def test_trace_summary_folds_the_new_names(job):
     table = tool.render(summary)
     assert "| engine.h2d | 2 |" in table
     assert "| io.decode | 1 |" in table
+    # the compiles fold by program: one row a program, a column a phase
+    programs = tool.summarize_compiles(run)
+    assert programs and all(p["count"] >= 1 for p in programs.values())
+    assert all(p["off"] == p["count"] and not p["hit"] + p["miss"]
+               for p in programs.values())       # no persistent cache here
+    mine = max(programs.values(), key=lambda p: p["trace_us"])
+    assert min(mine["trace_us"], mine["lower_us"], mine["backend_us"]) > 0
+    by_hand = [
+        {"name": "compile.trace", "dur_us": 2e6, "attrs": {"program": "f"}},
+        {"name": "compile.lower", "dur_us": 1e6, "attrs": {"program": "f"}},
+        {"name": "compile.backend", "dur_us": 5e5,
+         "attrs": {"program": "f", "cache": "hit", "load_s": 0.25,
+                   "saved_s": 30.0}},
+        {"name": "compile.backend", "dur_us": 4e6,
+         "attrs": {"program": "g", "cache": "miss"}},
+        {"name": "engine.build", "dur_us": 1e6,
+         "attrs": {"device_batch_size": 8, "param_bytes": 24,
+                   "jit_cached": False}},
+        {"name": "engine.build", "dur_us": 5e5,
+         "attrs": {"device_batch_size": 8, "param_bytes": 24,
+                   "jit_cached": True}},
+    ]
+    folded = tool.summarize_compiles(by_hand)
+    assert folded["f"] == {"trace_us": 2e6, "lower_us": 1e6,
+                           "backend_us": 5e5, "count": 1, "hit": 1,
+                           "miss": 0, "off": 0, "load_us": 2.5e5,
+                           "saved_us": 3e7}
+    assert (folded["g"]["miss"], folded["g"]["backend_us"]) == (1, 4e6)
+    lines = tool.render_compiles(folded).splitlines()
+    assert lines[2] == "| g | 1 | 0.0 | 0.0 | 4000.0 | 0/1/0 | 0.0 | 0.0 |"
+    assert lines[3] == ("| f | 1 | 2000.0 | 1000.0 | 500.0 | 1/0/0 | 250.0 "
+                        "| 30000.0 |")
+    assert "7.500 s in 2 programs" in lines[-1]
+    assert tool.summarize_builds(by_hand) == {
+        "count": 2, "total_us": 1.5e6, "jit_cached": 1, "param_bytes": 48,
+        "device_batch_sizes": [8]}
+    assert tool.summarize_builds(run)["count"] == 0    # built before it

@@ -21,6 +21,14 @@ start) unless ``--wall-span`` names a span (e.g. ``pipeline.run``) to
 use as the denominator.  Stage totals can sum past 100% of wall —
 overlapping stages are the point of the pipeline; the table makes the
 overlap quantitative.
+
+Where the trace holds ``compile.*`` spans (``parallel.compile_cache``'s
+listener: one closed span a compile phase, by ``program``), a second
+table folds them into programs × phases — trace, lower and backend ms,
+how the persistent cache answered (hit / miss / off), what the hits
+took to load and what they spared — so a cold start under
+``SPARKDL_TRACE=<dir>`` reads as the list of programs that made it
+slow; a last line counts the engines built (``engine.build``).
 """
 
 from __future__ import annotations
@@ -68,6 +76,67 @@ def summarize(spans: List[dict], wall_span: str = None) -> Dict:
     return {"wall_us": wall_us, "stages": stages}
 
 
+COMPILE_PHASES = ("trace", "lower", "backend")
+
+
+def summarize_compiles(spans: List[dict]) -> Dict[str, Dict]:
+    """``compile.*`` spans folded by their ``program``: microseconds a
+    phase, programs compiled (backend spans), the cache's answers, the
+    hits' load time and the compile time they spared."""
+    programs: Dict[str, Dict] = {}
+    for s in spans:
+        prefix, _, phase = s["name"].partition(".")
+        if prefix != "compile" or phase not in COMPILE_PHASES:
+            continue
+        attrs = s.get("attrs") or {}
+        row = programs.setdefault(str(attrs.get("program", "?")), {
+            **{f"{p}_us": 0.0 for p in COMPILE_PHASES}, "count": 0,
+            "hit": 0, "miss": 0, "off": 0, "load_us": 0.0, "saved_us": 0.0})
+        row[f"{phase}_us"] += float(s["dur_us"])
+        if phase == "backend":
+            row["count"] += 1
+            row[attrs.get("cache", "off")] += 1
+            row["load_us"] += 1e6 * float(attrs.get("load_s") or 0.0)
+            row["saved_us"] += 1e6 * float(attrs.get("saved_s") or 0.0)
+    return programs
+
+
+def summarize_builds(spans: List[dict]) -> Dict:
+    """``engine.build`` spans folded: engines built, how many found
+    their dispatch jit in the process's cache, the weights' bytes, the
+    device batch sizes."""
+    builds = [s for s in spans if s["name"] == "engine.build"]
+    attrs = [s.get("attrs") or {} for s in builds]
+    return {"count": len(builds),
+            "total_us": sum(float(s["dur_us"]) for s in builds),
+            "jit_cached": sum(bool(a.get("jit_cached")) for a in attrs),
+            "param_bytes": sum(int(a.get("param_bytes") or 0) for a in attrs),
+            "device_batch_sizes": sorted(
+                {a["device_batch_size"] for a in attrs
+                 if "device_batch_size" in a})}
+
+
+def render_compiles(programs: Dict[str, Dict]) -> str:
+    lines = [
+        "| program | compiles | trace ms | lower ms | backend ms "
+        "| hit/miss/off | load ms | saved ms |",
+        "|---|---:|---:|---:|---:|---:|---:|---:|",
+    ]
+    total = lambda row: sum(  # noqa: E731
+        row[f"{p}_us"] for p in COMPILE_PHASES)
+    for name, row in sorted(programs.items(), key=lambda kv: -total(kv[1])):
+        lines.append(
+            f"| {name} | {row['count']} | {row['trace_us'] / 1e3:.1f} "
+            f"| {row['lower_us'] / 1e3:.1f} | {row['backend_us'] / 1e3:.1f} "
+            f"| {row['hit']}/{row['miss']}/{row['off']} "
+            f"| {row['load_us'] / 1e3:.1f} | {row['saved_us'] / 1e3:.1f} |")
+    lines.append(
+        f"\ncompiles: {sum(total(r) for r in programs.values()) / 1e6:.3f} s "
+        f"in {len(programs)} programs (a phase inside another phase is "
+        f"counted in the outer one).")
+    return "\n".join(lines)
+
+
 def render(summary: Dict, sort: str = "total") -> str:
     wall_us = summary["wall_us"]
     key = {"total": lambda kv: -kv[1]["total_us"],
@@ -112,6 +181,15 @@ def main(argv=None) -> int:
         return 1
     print(render(summarize(spans, wall_span=args.wall_span),
                  sort=args.sort))
+    programs = summarize_compiles(spans)
+    if programs:
+        print("\n" + render_compiles(programs))
+    builds = summarize_builds(spans)
+    if builds["count"]:
+        print(f"\nengines built: {builds['count']} in "
+              f"{builds['total_us'] / 1e3:.1f} ms ({builds['jit_cached']} "
+              f"found their dispatch jit), {builds['param_bytes']} bytes of "
+              f"weights, device batch sizes {builds['device_batch_sizes']}")
     return 0
 
 
