@@ -26,7 +26,11 @@ and leaves its keys and values in the cache of all layers, which the
 loop carries.  Then block after block: the block starts as ``B`` copies
 of ``mask_token_id``; ``denoise_steps`` passes run its ``B`` positions
 through the layers (the queries see the cache's filled part and each
-other) and the head, and each reveals the ``B / denoise_steps`` still
+other: ``ops/cache_attention``, on the TPU ONE kernel a layer-pass that
+is handed the carried cache of all layers as it stands, with the layer
+and the filled length as data, and reads the layer's filled tiles in
+place; elsewhere ``jax.numpy`` over the layer's slice, masked) and the
+head, and each reveals the ``B / denoise_steps`` still
 masked positions whose choice (the largest logit, the mask id left out)
 has the largest confidence ``exp(logit - logsumexp)``, ties to the lower
 position; a last pass runs the clean block without the head and writes
@@ -37,7 +41,7 @@ device trace's line of the loop shows their shape): a turn runs the
 layers over the block as it stands, then EITHER the head and the choice
 (a denoise pass) or the write into the cache (the commit pass), so the
 layers' scan and the expert kernel are one instruction each in the loop
-and one in the prefill.
+and one in the prefill, and the loop's attention one in the loop.
 
 The experts are ``expert_trunk``'s dispatch and combine
 (``_held_experts`` over ``ops/grouped_matmul``), told which experts are
@@ -46,7 +50,7 @@ a softmax.  The program returns, a row, ``generated`` int32 ``[L]``,
 ``revealed_at`` int32 ``[L]`` (the pass, 1 and on, that revealed the
 position), ``features`` float32 ``[3 L]`` (a position's chosen logit,
 the ``logsumexp`` over the vocabulary and a zero, at the pass that
-revealed it) and the counter ``diffusion_counts`` int32 ``[7]``
+revealed it) and the counter ``diffusion_counts`` int32 ``[8]``
 (``COUNTS``; ``ModelFunction.counter_names``).
 
 Weights, the cache and matrix-product operands are in the compute dtype
@@ -69,23 +73,27 @@ import jax.numpy as jnp
 from jax import lax
 
 from sparkdl_tpu.models import expert_trunk
+from sparkdl_tpu.ops import cache_attention
 from sparkdl_tpu.ops.attention import causal_attention
 
 #: the program's outputs that are counters, not columns
 COUNTERS = ("diffusion_counts",)
-#: what ``diffusion_counts`` ``[rows, 7]`` counts, a row: the passes with
+#: what ``diffusion_counts`` ``[rows, 8]`` counts, a row: the passes with
 #: and without the head, the ids revealed, the tokens that were routed
 #: (positions x layers, the prompt's among them), the token-expert pairs
 #: computed, and — on a dispatch's FIRST row, 0 on the others, summed
 #: over the loop's passes and the layers (the prefill's are not among
-#: them) — the experts with at least one pair and the slots that
+#: them) — the experts with at least one pair, the slots that
 #: ``_held_experts``' chunk turns worked through (turns x chunk: the
-#: loop's pairs over them is the slots' fill)
+#: loop's pairs over them is the slots' fill) and the key positions a
+#: row's attention fetched from the cache (``cache_attention.
+#: fetched_positions``: the kernel's tiles x tile, the whole cache on the
+#: ``jax.numpy`` path; the filled lengths' sum over them is the share
+#: that was needed)
 COUNTS = ("denoise_passes", "commit_passes", "revealed_ids", "tokens",
-          "pairs", "touched_experts", "expert_slots")
+          "pairs", "touched_experts", "expert_slots", "cache_positions")
 #: rows of prompts that go through the layers together in the prefill
 PREFILL_ROWS = 8
-_NEG = -1e30          # a score no softmax notices; finite, so no NaN
 
 
 def _routing(config: Dict[str, Any]) -> Dict[str, Any]:
@@ -220,30 +228,18 @@ def _layer(config: Dict[str, Any], x, w, index, experts, first, attend,
     return x + m, k, v, load, slots
 
 
-def _attend_cache(q, k, v, cache_k, cache_v, filled, *, heads: int,
+def _attend_cache(q, k, v, cache_k, cache_v, layer, filled, *, heads: int,
                   kv_heads: int, precision):
-    """The block's queries ``[R, B, H*hd]`` (scaled) against the cache's
-    first ``filled`` positions ``[R, T, KV*hd]`` and the block's own keys
-    and values, both ways: plain ``jax.numpy``, scores and softmax
-    float32."""
-    f32 = jnp.float32
-    r, b, _ = q.shape
-    t = cache_k.shape[1]
-    hd = q.shape[-1] // heads
-    rep = heads // kv_heads
-    qh = q.reshape(r, b, kv_heads, rep, hd)
-    score = functools.partial(jnp.einsum, "rbgjd,rtgd->rgjbt",
-                              precision=precision, preferred_element_type=f32)
-    mix = functools.partial(jnp.einsum, "rgjbt,rtgd->rbgjd",
-                            precision=precision, preferred_element_type=f32)
-    before = jnp.where(jnp.arange(t) < filled,
-                       score(qh, cache_k.reshape(r, t, kv_heads, hd)), _NEG)
-    own = score(qh, k.reshape(r, -1, kv_heads, hd))
-    p = jax.nn.softmax(jnp.concatenate([before, own], axis=-1), axis=-1)
-    p = p.astype(q.dtype)
-    out = (mix(p[..., :t], cache_v.reshape(r, t, kv_heads, hd))
-           + mix(p[..., t:], v.reshape(r, -1, kv_heads, hd)))
-    return out.reshape(r, b, heads * hd).astype(q.dtype)
+    """The block's queries ``[R, B, H*hd]`` (scaled) against the first
+    ``filled`` positions of layer ``layer`` of the carried cache
+    ``[depth, R, T, KV*hd]`` and the block's own keys and values, both
+    ways; scores and softmax float32.  ``ops/cache_attention``: on the
+    TPU, with as many own keys as queries, its kernel reads the layer's
+    filled tiles in place; anywhere else (and at any other shapes)
+    plain ``jax.numpy`` over the layer's slice."""
+    return cache_attention.cache_attention(
+        q, k, v, cache_k, cache_v, layer, filled, heads=heads,
+        kv_heads=kv_heads, precision=precision)
 
 
 def _choose(logits, still_masked, mask_id: int, reveal: int):
@@ -276,7 +272,7 @@ def apply(variables: Dict[str, Any], ids, config: Dict[str, Any], *,
           generated_length: int, denoise_steps: int, precision=None):
     """``ids`` ``[R, P]`` integers -> ``{"generated": [R, L] int32,
     "revealed_at": [R, L] int32, "features": [R, 3 L] float32,
-    "diffusion_counts": [R, 7] int32}``.  The compute dtype is the
+    "diffusion_counts": [R, 8] int32}``.  The compute dtype is the
     weights' own."""
     c, f32, i32 = config, jnp.float32, jnp.int32
     dtype = variables["embed_tokens"].dtype
@@ -334,7 +330,7 @@ def apply(variables: Dict[str, Any], ids, config: Dict[str, Any], *,
 
     def one_pass(state):
         (generated, revealed_at, features, cache_k, cache_v, pairs,
-         expert_counts, passes, n) = state
+         loop_counts, passes, n) = state
         at, step = n // turns * b, n % turns
         first = p + at
         denoise_pass, commit = _turn(step, denoise_steps)
@@ -344,19 +340,21 @@ def apply(variables: Dict[str, Any], ids, config: Dict[str, Any], *,
         tokens = jnp.where(block_at > 0, block_ids, mask_id)
 
         def attend(index, q, k, v):
-            layer_cache = functools.partial(lax.dynamic_index_in_dim,
-                                            index=index, keepdims=False)
-            return _attend_cache(q, k, v, layer_cache(cache_k),
-                                 layer_cache(cache_v), first, heads=heads,
-                                 kv_heads=kv_heads, precision=precision)
+            return _attend_cache(q, k, v, cache_k, cache_v, index, first,
+                                 heads=heads, kv_heads=kv_heads,
+                                 precision=precision)
 
         with jax.named_scope("block_pass"):
             x, (k, v, load, slots) = through_layers(embed(tokens), first,
                                                     attend)
         pairs = pairs + jnp.sum(load, axis=(0, 2))
-        # the experts touched and the slots worked, all layers of this pass
-        expert_counts = expert_counts + jnp.stack([
-            jnp.sum(jnp.sum(load, axis=1) > 0, dtype=i32), jnp.sum(slots)])
+        # the experts touched, the slots worked and the cache's positions
+        # fetched, all layers of this pass
+        fetched = cache_attention.fetched_positions(
+            first, p + length, cache_attention.key_tile(b, b, p + length))
+        loop_counts = loop_counts + jnp.stack([
+            jnp.sum(jnp.sum(load, axis=1) > 0, dtype=i32), jnp.sum(slots),
+            depth * fetched])
         passes = passes + jnp.stack([denoise_pass, ~denoise_pass]).astype(i32)
 
         with jax.named_scope("commit_pass"):
@@ -392,22 +390,22 @@ def apply(variables: Dict[str, Any], ids, config: Dict[str, Any], *,
                                 start_index=at, axis=1)
         return (put(generated, block_ids), put(revealed_at, block_at),
                 put(features, block_features), cache_k, cache_v, pairs,
-                expert_counts, passes, n + 1)
+                loop_counts, passes, n + 1)
 
     with jax.named_scope("generation"):
         blank = jnp.zeros((r, length), i32)
         state = lax.while_loop(
             lambda state: state[-1] < length // b * turns, one_pass,
             (blank, blank, jnp.zeros((r, length, 3), f32), cache_k, cache_v,
-             pairs, jnp.zeros((2,), i32), jnp.zeros((2,), i32), jnp.int32(0)))
-    (generated, revealed_at, features, _, _, pairs, expert_counts, passes,
+             pairs, jnp.zeros((3,), i32), jnp.zeros((2,), i32), jnp.int32(0)))
+    (generated, revealed_at, features, _, _, pairs, loop_counts, passes,
      _) = state
     positions = p + (passes[0] + passes[1]) * b
     counts = jnp.stack([
         jnp.broadcast_to(passes[0], (r,)), jnp.broadcast_to(passes[1], (r,)),
         jnp.sum(revealed_at > 0, axis=1, dtype=i32),
         jnp.broadcast_to(positions * depth, (r,)), pairs,
-        *jnp.zeros((2, r), i32).at[:, 0].set(expert_counts)], axis=1)
+        *jnp.zeros((3, r), i32).at[:, 0].set(loop_counts)], axis=1)
     return {"generated": generated, "revealed_at": revealed_at,
             "features": features.reshape(r, 3 * length),
             "diffusion_counts": counts}

@@ -8,9 +8,11 @@ fallbacks for CPU/debug: the separable convolutions of the CNN zoo
 (``sepconv``), the two parts of a hybrid sequence block that have no
 lowering worth having — the chunked state-space scan (``ssd``) and
 causal grouped-query attention without the score matrix, over the whole
-row or a window of it (``attention``) — and an expert layer's products
-by group over the tokens routed to each expert (``grouped_matmul``; the
-module is imported by its own name, which its entry point shares).
+row or a window of it (``attention``) — an expert layer's products by
+group over the tokens routed to each expert (``grouped_matmul``; the
+module is imported by its own name, which its entry point shares), and
+a generation loop's few queries against the key/value cache it carries,
+read in place (``cache_attention``, imported by its own name too).
 """
 
 from sparkdl_tpu.ops.attention import causal_attention

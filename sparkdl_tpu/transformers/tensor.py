@@ -74,20 +74,24 @@ def _count_expert_load(load, metrics, tokens):
 
 
 def _count_diffusion(counts, metrics, tokens):
-    """``diffusion_counts`` ``[rows, 7]`` (``block_diffusion.COUNTS``): a
+    """``diffusion_counts`` ``[rows, 8]`` (``block_diffusion.COUNTS``): a
     generator's own count of its passes, the ids it revealed, the
     positions it routed (it makes positions of its own, so the input's
     ids say nothing of them) and their pairs, summed over the rows, and
-    the experts its loop touched and the slots the loop laid its pairs
-    out in (``diffusion.expert_slots``: the loop's pairs over them is
-    the slots' fill).  ``tokens`` is the prompts' ids."""
-    (denoise, commit, revealed, routed, pairs, touched,
-     slots) = (int(v) for v in counts.sum(axis=0))
+    the experts its loop touched, the slots the loop laid its pairs out
+    in (``diffusion.expert_slots``: the loop's pairs over them is the
+    slots' fill) and the key positions a row's attention fetched from
+    the cache (``diffusion.cache_positions``: the filled lengths' sum
+    over them is the share that was needed).  ``tokens`` is the prompts'
+    ids."""
+    (denoise, commit, revealed, routed, pairs, touched, slots,
+     fetched) = (int(v) for v in counts.sum(axis=0))
     for name, value in (("diffusion.denoise_passes", denoise),
                         ("diffusion.commit_passes", commit),
                         ("diffusion.revealed_ids", revealed),
                         ("diffusion.touched_experts", touched),
                         ("diffusion.expert_slots", slots),
+                        ("diffusion.cache_positions", fetched),
                         ("moe.tokens", routed), ("moe.pairs", pairs)):
         metrics.incr(name, value)
     attrs = {"generated_ids": revealed, "denoise_passes": denoise,

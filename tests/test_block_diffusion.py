@@ -126,8 +126,13 @@ def test_the_program_is_the_plain_sampler_and_reads_what_it_reads(
     # 3 rows x 4 positions x 2 = 24 pairs to 8 experts, 3 an expert, so
     # the tile is the smallest (16) and the chunk a whole tile an expert
     # over the pairs' two: the worst fall's 10 tiles, ONE turn a layer
-    assert counts.shape == (3, len(bd.COUNTS)) == (3, 7)
+    assert counts.shape == (3, len(bd.COUNTS)) == (3, 8)
     assert counts[0, 6] == 10 * 16 * 2 * 4 * (steps + 1)
+    # the cache's positions its attention fetched: off the TPU the
+    # ``jax.numpy`` form reads a layer's whole cache (16 + 16 positions)
+    # at every pass of every layer
+    assert bd.COUNTS[7] == "cache_positions"
+    assert counts[0, 7] == 4 * (steps + 1) * 2 * (16 + 16)
     assert (counts[1:, 5:] == 0).all()
 
 
@@ -238,6 +243,12 @@ def test_the_stage_writes_integer_columns_and_counts_its_passes(weights,
     # (the padded rows' too: they are laid out like any) over the slots
     assert counters["diffusion.expert_slots"] == 12 * 2 * 12 * 16
     assert 12 * 2 * 64 / counters["diffusion.expert_slots"] == 1 / 3
+    # that dispatch's 12 passes x 2 layers over the whole cache of 32
+    # (the ``jax.numpy`` path); the filled lengths' sum over it is the
+    # share of the fetched positions that were needed
+    assert counters["diffusion.cache_positions"] == 12 * 2 * 32
+    filled = 2 * 3 * sum(16 + 4 * block for block in range(4))
+    assert filled / counters["diffusion.cache_positions"] == 0.6875
     assert counters["engine.rows"] == 3
     (run,), (pack_in,) = spans["transform.run"], spans["transform.pack_in"]
     assert run["attrs"]["prompt_tokens"] == 3 * 16

@@ -372,16 +372,46 @@ def test_grouped_matmul_compiles_for_v5e_at_a_pass_of_the_loop(v5e_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
 
 
+def test_cache_attention_compiles_for_v5e_at_a_pass_of_the_loop(v5e_chip):
+    """The loop's attention as a pass of ``sdar_30b_a3b_chat.gen256``
+    calls it: 64 rows of 4 queries, 32 query heads on 4 key/value heads
+    of 128, the carried cache of all 6 layers ``[6, 64, 1280, 512]``
+    handed over whole, the layer and the filled length as data."""
+    import re
+
+    from sparkdl_tpu.ops import cache_attention as ca
+
+    rows, b, heads, kv, hd, depth, t = 64, 4, 32, 4, 128, 6, 1280
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    tile = ca.key_tile(b, b, t, True)
+    assert tile == ca.TILE
+    compiled = ca.cache_attention_kernel.lower(
+        _on_chip((rows, b, heads * hd), bf16, v5e_chip),
+        _on_chip((rows, b, kv * hd), bf16, v5e_chip),
+        _on_chip((rows, b, kv * hd), bf16, v5e_chip),
+        _on_chip((depth, rows, t, kv * hd), bf16, v5e_chip),
+        _on_chip((depth, rows, t, kv * hd), bf16, v5e_chip),
+        _on_chip((), i32, v5e_chip), _on_chip((), i32, v5e_chip),
+        heads=heads, kv_heads=kv, tile=tile, rows=ca.ROWS).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and f"%{ca.NAME}." in text
+    # no layer's cache beside the cache: nothing but the queries' layout
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+    assert not re.search(r"bf16\[(1,)?64,1280,512\]", text)
+
+
 def test_the_block_diffusion_program_compiles_for_v5e(v5e_chip, monkeypatch):
     """``sdar_30b_a3b_chat``'s whole program — prefill, the loop with its
     cache, the head and the sampler — through the engine's dispatch
     program (kernel paths on) at the cell's dispatch of 64 prompts of
-    1,024 ids: it compiles; the prefill's attention is ONE instruction
-    and the expert kernel two (the prefill's and the loop's), whatever
-    the depth and the number of passes; the generation loop is the one
-    ``while`` whose carry leads with the generated ids (the device
-    trace's line that ``diffusion_flops.loop_seconds`` reads); and the
-    dispatch fits the chip beside its 8.72 GB of weights."""
+    1,024 ids: it compiles; the prefill's attention is ONE instruction,
+    the loop's attention ONE (it is handed the carried cache whole: no
+    layer's cache is sliced or copied) and the expert kernel two (the
+    prefill's and the loop's), whatever the depth and the number of
+    passes; the generation loop is the one ``while`` whose carry leads
+    with the generated ids (the device trace's line that
+    ``diffusion_flops.loop_seconds`` reads); and the dispatch fits the
+    chip beside its 8.72 GB of weights."""
     import json
     import os
     import re
@@ -390,7 +420,7 @@ def test_the_block_diffusion_program_compiles_for_v5e(v5e_chip, monkeypatch):
 
     from benchmark import trace_reduce
     from sparkdl_tpu.models import block_diffusion
-    from sparkdl_tpu.ops import attention, grouped_matmul
+    from sparkdl_tpu.ops import attention, cache_attention, grouped_matmul
     from sparkdl_tpu.parallel import mesh as mesh_lib
     from sparkdl_tpu.parallel.engine import build_dispatch_jit
 
@@ -398,8 +428,8 @@ def test_the_block_diffusion_program_compiles_for_v5e(v5e_chip, monkeypatch):
     with open(os.path.join(root, "benchmark", "configs",
                            "sdar_30b_a3b_chat.json")) as fh:
         config = json.load(fh)
-    monkeypatch.setattr(grouped_matmul, "_on_tpu", lambda: True)
-    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    for module in (grouped_matmul, attention, cache_attention):
+        monkeypatch.setattr(module, "_on_tpu", lambda: True)
     device = next(iter(v5e_chip.device_set))
     mesh = Mesh(np.asarray([device]).reshape(1, 1),
                 (mesh_lib.DATA_AXIS, mesh_lib.MODEL_AXIS))
@@ -413,9 +443,13 @@ def test_the_block_diffusion_program_compiles_for_v5e(v5e_chip, monkeypatch):
             (64, config["prompt_length"]), np.int32)}).compile()
     text = compiled.as_text()
     kernels = sorted(name.split(".")[0] for name in set(re.findall(
-        r"%((?:causal_attention|grouped_matmul)\.\d+) = ", text)))
-    assert kernels == ["causal_attention", "grouped_matmul",
-                       "grouped_matmul"], kernels
+        r"%((?:causal_attention|cache_attention|grouped_matmul)\.\d+) = ",
+        text)))
+    assert kernels == ["cache_attention", "causal_attention",
+                       "grouped_matmul", "grouped_matmul"], kernels
+    # the carried cache goes to the loop's attention as it stands: no
+    # instruction holds one layer's cache
+    assert not re.search(r"bf16\[(1,)?64,1280,512\]", text)
     # the prefill's chunk of 384 tiles of 256 as before; the loop's, the
     # one with the fewer rows, 224 tiles of 16
     assert sorted(int(n) for n in re.findall(
