@@ -33,7 +33,9 @@ of a row of ``T`` positions (``first`` is data, an int32 scalar):
   step, the selection read from ``scores`` and ``sel`` tile by tile and
   applied as a mask: exact, but a tile is fetched and computed whole
   whatever share of it was selected (``fetched_keys`` counts what it
-  reads; a gather of the selected rows alone is not written yet).
+  reads; a gather of the selected rows alone is not written yet).  A
+  step's heads and sub-tiles of keys are one software pipeline, so that the
+  MXU's products of one overlap the softmax of another.
 
 Scores, softmax and the accumulator are float32; matrix products take
 their operands in the inputs' dtype.
@@ -58,10 +60,12 @@ INDEX_QUERIES = 16
 INDEX_KEYS = 1024
 #: queries whose scores a selection step holds (8 x 32,768 float32: 1 MB)
 SELECT_QUERIES = 8
-#: the attention's queries, keys and heads a step
+#: the attention's queries, keys and heads a step, and the keys a head
+#: scores at a time (256 x 256 float32: the 64 vector registers)
 QUERIES = 256
 KEYS = 512
 HEADS = 16
+SUB_KEYS = 256
 VMEM_LIMIT = 64 * 2**20
 _NEG = -1e30          # a score no softmax notices; finite, so no NaN
 _INT_MIN = -2**31
@@ -298,11 +302,31 @@ def sparse_attention_plain(q_nope, q_pe, k_nope, v, k_pe, scores, sel,
 
 
 def _attention_kernel(at_ref, qn_ref, qp_ref, kn_ref, v_ref, kp_ref, s_ref,
-                      sel_ref, o_ref, m_ref, l_ref, acc_ref, *, precision):
+                      sel_ref, o_ref, m_ref, l_ref, acc_ref, bias_ref, *,
+                      sub: int, precision):
+    """A step: a block of queries, a group of heads, a tile of keys.
+
+    Scores are taken transposed, ``[keys, queries]``: the queries are the
+    lanes, so a head's running maximum and sum are one row of lanes
+    (``m_ref``, ``l_ref`` ``[group, 1, block]``) and its accumulator is
+    ``[dv, block]``.  The selection is applied as a bias of 0 or ``_NEG``
+    (``bias_ref`` ``[tile, block]``), made once a step for every head.
+
+    A head takes the tile's keys ``sub`` at a time, each an online
+    softmax step, and the step's (head, sub-tile) pieces run as ONE
+    pipeline: a piece's scores go to the MXU before the softmax and the
+    weighted sum of the piece before it, so that the MXU works on one
+    piece while the vector units work on the other.  The MXU takes its
+    work in program order, so under a loop of heads each head's products
+    waited behind the previous head's softmax: in
+    ``deepseek_v3_2_exp.rows32k`` 18.3 µs a step, against 10.0 µs so
+    (``PERF.md`` §6)."""
     f32 = jnp.float32
     i, j, nk = pl.program_id(0), pl.program_id(2), pl.num_programs(2)
     heads, block, _ = qn_ref.shape
     tile = kn_ref.shape[1]
+    by_row = (((1,), (1,)), ((), ()))       # a . b^T
+    by_key = (((0,), (0,)), ((), ()))       # a^T . b
 
     @pl.when(j == 0)
     def _():
@@ -315,52 +339,76 @@ def _attention_kernel(at_ref, qn_ref, qp_ref, kn_ref, v_ref, kp_ref, s_ref,
         query_at = (at_ref[0] + i * block
                     + lax.broadcasted_iota(jnp.int32, (block, 1), 0))
         key_at = j * tile + lax.broadcasted_iota(jnp.int32, (1, tile), 1)
-        # a query none of whose keys here was chosen sums garbage at
-        # weight exp(0); the first key it does see scales that to nothing
+        # a query none of whose keys in a piece was chosen sums garbage
+        # at weight exp(0); the first key it does see scales that to
+        # nothing.  s + _NEG is _NEG for any score under 1e22
         seen = selected(s_ref[...], sel_ref[...], query_at, key_at)
-        k_pe = kp_ref[...]
+        bias_ref[...] = jnp.where(seen, 0.0, _NEG).T
 
-        def head(h, _):
-            s = (lax.dot_general(qn_ref[h], kn_ref[h], (((1,), (1,)), ((), ())),
-                                 precision=precision, preferred_element_type=f32)
-                 + lax.dot_general(qp_ref[h], k_pe, (((1,), (1,)), ((), ())),
-                                   precision=precision,
-                                   preferred_element_type=f32))
-            s = jnp.where(seen, s, _NEG)
-            m_old = m_ref[h]                                  # [block, 1]
-            m_new = jnp.maximum(m_old, s.max(axis=1, keepdims=True))
+        def scores(h, c):
+            keys = pl.ds(c * sub, sub)
+            return (lax.dot_general(kn_ref[h, keys], qn_ref[h], by_row,
+                                    precision=precision,
+                                    preferred_element_type=f32)
+                    + lax.dot_general(kp_ref[keys], qp_ref[h], by_row,
+                                      precision=precision,
+                                      preferred_element_type=f32)
+                    + bias_ref[keys])                       # [sub, block]
+
+        # unrolled, not a loop of heads: a loop body is a scheduling
+        # boundary.  The step's 32 pieces cost 0.26 s more of tracing and
+        # lowering at every start of deepseek_v3_2_exp.rows32k's program
+        # (PERF.md §6); pipelined under a loop of two heads a turn, a
+        # chunk took 43.0 ms where this takes 39.1 and the parent 68.5
+        subs = tile // sub
+        pieces = [(h, c) for h in range(heads) for c in range(subs)]
+        s = scores(*pieces[0])
+        for n, (h, c) in enumerate(pieces):
+            if c == 0:
+                m, l, acc = m_ref[h], l_ref[h], acc_ref[h]
+            later = scores(*pieces[n + 1]) if n + 1 < len(pieces) else None
+            m_new = jnp.maximum(m, s.max(axis=0, keepdims=True))
             p = jnp.exp(s - m_new)
-            scale = jnp.exp(m_old - m_new)
-            l_ref[h] = scale * l_ref[h] + p.sum(axis=1, keepdims=True)
-            acc_ref[h] = scale * acc_ref[h] + jnp.dot(
-                p.astype(v_ref.dtype), v_ref[h], precision=precision,
-                preferred_element_type=f32)
-            m_ref[h] = m_new
-            return _
-
-        lax.fori_loop(0, heads, head, None)
+            scale = jnp.exp(m - m_new)
+            l = scale * l + p.sum(axis=0, keepdims=True)
+            acc = scale * acc + lax.dot_general(
+                v_ref[h, pl.ds(c * sub, sub)], p.astype(v_ref.dtype), by_key,
+                precision=precision, preferred_element_type=f32)  # [dv, block]
+            m, s = m_new, later
+            if c == subs - 1:
+                m_ref[h], l_ref[h], acc_ref[h] = m, l, acc
 
     @pl.when(j == nk - 1)
     def _():
-        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+        def out(h, _):
+            o_ref[h] = (acc_ref[h] / l_ref[h]).T.astype(o_ref.dtype)
+            return _
+
+        lax.fori_loop(0, heads, out, None)
 
 
 @functools.partial(jax.jit, donate_argnums=(), static_argnames=(
-    "block", "tile", "group", "interpret", "precision"))
+    "block", "tile", "group", "sub", "interpret", "precision"))
 def sparse_attention_kernel(q_nope, q_pe, k_nope, v, k_pe, scores, sel,
                             first=0, *, block: int = QUERIES,
                             tile: int = KEYS, group: int = HEADS,
-                            interpret: bool = False, precision=None):
+                            sub: int = SUB_KEYS, interpret: bool = False,
+                            precision=None):
     """The Pallas kernel; ``k_pe`` and the selection are shared by the
-    heads, a group of which a step takes."""
+    heads, a group of which a step takes.  The grid's key axis is as long
+    as the last query block's causal tiles (a grid dimension that is
+    data): the steps past a block's diagonal fetch and compute nothing."""
     heads, q, dn = q_nope.shape
     t, dr, dv = k_nope.shape[1], q_pe.shape[-1], v.shape[-1]
     block, tile, group = min(block, q), min(tile, t), min(group, heads)
-    if q % block or t % tile or heads % group:
+    sub = min(sub, tile)
+    if q % block or t % tile or heads % group or tile % sub:
         raise ValueError(f"{q} queries in blocks of {block}, {t} keys in "
-                         f"tiles of {tile}, {heads} heads in groups of "
-                         f"{group}: no whole number of one of them")
+                         f"tiles of {tile} in sub-tiles of {sub}, {heads} "
+                         f"heads in groups of {group}: no whole number of "
+                         "one of them")
     at = jnp.asarray(first, jnp.int32).reshape(1)
+    tiles = jnp.minimum(_tiles(at[0], q // block - 1, block, tile), t // tile)
 
     def key_tile(i, g, j, at):
         # above the diagonal nothing is computed: name the tile that is
@@ -375,10 +423,10 @@ def sparse_attention_kernel(q_nope, q_pe, k_nope, v, k_pe, scores, sel,
                             lambda i, g, j, at: (g, key_tile(i, g, j, at), 0))
 
     return pl.pallas_call(
-        functools.partial(_attention_kernel, precision=precision),
+        functools.partial(_attention_kernel, sub=sub, precision=precision),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(q // block, heads // group, t // tile),
+            grid=(q // block, heads // group, tiles),
             in_specs=[by_query(dn), by_query(dr), by_key(dn), by_key(dv),
                       pl.BlockSpec((tile, dr), lambda i, g, j, at: (
                           key_tile(i, g, j, at), 0)),
@@ -386,9 +434,10 @@ def sparse_attention_kernel(q_nope, q_pe, k_nope, v, k_pe, scores, sel,
                           i, key_tile(i, g, j, at))),
                       pl.BlockSpec((block, 2), lambda i, g, j, at: (i, 0))],
             out_specs=by_query(dv),
-            scratch_shapes=[pltpu.VMEM((group, block, 1), jnp.float32),
-                            pltpu.VMEM((group, block, 1), jnp.float32),
-                            pltpu.VMEM((group, block, dv), jnp.float32)]),
+            scratch_shapes=[pltpu.VMEM((group, 1, block), jnp.float32),
+                            pltpu.VMEM((group, 1, block), jnp.float32),
+                            pltpu.VMEM((group, dv, block), jnp.float32),
+                            pltpu.VMEM((tile, block), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((heads, q, dv), q_nope.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),

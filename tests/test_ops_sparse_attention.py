@@ -114,16 +114,27 @@ def _attention_operands(seed=3):
             jax.random.normal(ks[4], (T, DR)))
 
 
+@pytest.mark.parametrize("block, tile, group, sub", [
+    (8, 16, 2, 16),     # a tile a piece, two heads a step
+    (8, 32, 4, 16),     # two sub-tiles a tile, every head in one step
+    (16, 16, 1, 8),     # two sub-tiles, one head a step
+    (32, 64, 2, 16),    # four sub-tiles, one block of all the queries
+])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("first", [0, FIRST])
-def test_the_attention_kernel_is_the_jax_numpy_form(first, dtype):
-    """Blocks of 8 queries, tiles of 16 keys, heads 2 a step."""
+def test_the_attention_kernel_is_the_jax_numpy_form(first, dtype, block, tile,
+                                                    group, sub):
+    """The step's (head, sub-tile) pieces, pipelined, against the plain
+    form: from position 0 (the first block's partial diagonal) and from
+    ``FIRST``, every causal tile visited and none after the last block's
+    diagonal."""
     q, k, w = _index_operands(4)
     scores = sa.indexer_scores(q, k, w, first, force=False)
     sel = sa.topk_select(scores, first, TOP, force=False)
     ops = [a.astype(dtype) for a in _attention_operands()]
-    got = sa.sparse_attention_kernel(*ops, scores, sel, first, block=8,
-                                     tile=16, group=2, interpret=True)
+    got = sa.sparse_attention_kernel(*ops, scores, sel, first, block=block,
+                                     tile=tile, group=group, sub=sub,
+                                     interpret=True)
     want = sa.sparse_attention(*ops, scores, sel, first, force=False)
     assert got.shape == want.shape == (H, Q, DV) and got.dtype == want.dtype
     atol = 1e-5 if dtype == "float32" else 2e-2
@@ -152,6 +163,34 @@ def test_nothing_outside_the_selection_is_attended():
                                        scores, sel, FIRST, block=8, tile=16,
                                        group=2, interpret=True)
     np.testing.assert_allclose(np.asarray(other), want, atol=1e-4)
+
+
+def test_keys_past_the_last_diagonal_are_never_read():
+    """Keys and values after the last query's tile are NaN: the kernel's
+    key axis ends at that tile, and before it the index maps name causal
+    tiles only."""
+    q, k, w = _index_operands(6)
+    scores = sa.indexer_scores(q, k, w, 0, force=False)
+    sel = sa.topk_select(scores, 0, TOP, force=False)
+    q_nope, q_pe, k_nope, v, k_pe = _attention_operands()
+    want = sa.sparse_attention(q_nope, q_pe, k_nope, v, k_pe, scores, sel, 0,
+                               force=False)
+    after = jnp.arange(T)[None, :, None] >= Q      # past position Q - 1
+    k_nope, v = (jnp.where(after, jnp.nan, a) for a in (k_nope, v))
+    k_pe = jnp.where(after[0], jnp.nan, k_pe)
+    got = sa.sparse_attention_kernel(q_nope, q_pe, k_nope, v, k_pe, scores,
+                                     sel, 0, block=8, tile=16, group=2,
+                                     sub=8, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+
+
+def test_a_sub_tile_must_divide_the_tile():
+    ops = _attention_operands()
+    scores = jnp.zeros((Q, T))
+    sel = sa.topk_select(scores, 0, TOP, force=False)
+    with pytest.raises(ValueError, match="tiles of 16 in sub-tiles of 12"):
+        sa.sparse_attention_kernel(*ops, scores, sel, 0, block=8, tile=16,
+                                   sub=12, interpret=True)
 
 
 def test_the_counts_of_pairs_and_fetched_keys():

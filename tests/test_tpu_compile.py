@@ -474,7 +474,13 @@ def test_sparse_attention_kernels_compile_for_v5e_at_published_widths(
         v5e_chip):
     """The three kernels at a chunk of ``deepseek_v3_2_exp.rows32k``:
     4096 queries of a row of 32,768 positions, the indexer's 64 heads of
-    128, the top 2,048, 128 heads of 128 + 64 over keys read in place."""
+    128, the top 2,048, 128 heads of 128 + 64 over keys read in place.
+    The attention at the block, group and sub-tile it takes (256
+    queries, 16 heads unrolled into 32 pipelined pieces of 256 keys, the
+    key axis as data) compiles within ``VMEM_LIMIT``: Mosaic refuses a
+    kernel whose blocks and scratch do not fit the limit it is given."""
+    import re
+
     from sparkdl_tpu.ops import sparse_attention as sa
 
     bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
@@ -498,6 +504,10 @@ def test_sparse_attention_kernels_compile_for_v5e_at_published_widths(
             ).compile())):
         text = compiled.as_text()
         assert "tpu_custom_call" in text and f"%{name}." in text, name
+        kernel = [line for line in text.splitlines()
+                  if f"%{name}." in line and "custom-call(" in line]
+        assert re.findall(r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"',
+                          "".join(kernel)) == [str(sa.VMEM_LIMIT)], name
         # the selection reads the scores in place; the others at most
         # lay out their queries anew (the 64-wide q_pe: 67 MB)
         limit = 2**20 if name == sa.SELECT_NAME else 2**28
