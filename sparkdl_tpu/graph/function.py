@@ -39,6 +39,10 @@ class ModelFunction:
     # it does a column's), and a stage adds what the real rows counted
     # to its engine's metrics instead of writing it to the frame.
     counter_names: Sequence[str] = ()
+    # The input that holds token ids, where one does: a stage that feeds
+    # several inputs counts this one's values as its rows' tokens, which
+    # the counters take (``transformers/tensor.count_outputs``).
+    token_input: Optional[str] = None
 
     def __call__(self, x):
         return self.fn(self.variables, x)
@@ -96,7 +100,7 @@ class ModelFunction:
         return ModelFunction(
             fn=fn, variables={"f": f.variables, "g": g.variables},
             input_names=f.input_names, output_names=g.output_names,
-            counter_names=g.counter_names)
+            counter_names=g.counter_names, token_input=f.token_input)
 
     def jit(self):
         """Eagerly jit-compile (otherwise the engine jits with shardings)."""

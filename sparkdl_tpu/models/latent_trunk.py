@@ -503,4 +503,4 @@ def model_function(config: Dict[str, Any], variables: Dict[str, Any], *,
 
     return ModelFunction(fn=fn, variables=variables, input_names=("ids",),
                          output_names=("features",) + COUNTERS,
-                         counter_names=COUNTERS)
+                         counter_names=COUNTERS, token_input="ids")

@@ -26,6 +26,22 @@ queries after the other (``lax.map``), so that the largest score array
 is ``[R, H, block, T]``.  Scores, softmax and the accumulator are
 float32; the two matrix products take their operands in ``q``'s dtype.
 The platform picks, as in ``ops/sepconv``, and nothing else does.
+
+``frame_attention`` is the same sum both ways over each row alone
+(``causal=False``), for rows as short as a frame of image patches and
+heads narrower than a lane (a vision tower's 72): one frame is one row,
+so a query sees every key of its own frame and none of another's.  On
+the TPU its own Pallas kernel (``name="frame_attention"``), not
+``causal_attention``'s, whose blocks slice a head's columns out of the
+heads side by side and want ``hd`` a multiple of 128: the heads go
+first (``[R, H, T, hd]``, ``hd`` the whole last axis, so no head is
+padded in memory), a step takes a block of queries of a group of heads
+against ALL of its frame's keys, which fit VMEM whole, and the softmax
+is taken at once, with no running maximum.  It turns ``q`` and ``k``
+by the rotary ``angle`` itself, on the whole head (``x cos + (x P) sin``,
+``P`` the halves swapped on the MXU): an array of half a head, 36 lanes,
+would be padded to 128 in memory.  Elsewhere ``turn_halves`` and
+``attention_blocked(..., causal=False)``.
 """
 
 from __future__ import annotations
@@ -41,6 +57,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 NAME = "causal_attention"
 BLOCK = 512
+FRAME_NAME = "frame_attention"
+#: frame attention's queries and heads a step (scores [512, 1024] float32
+#: a head at a time: 2 MB)
+FRAME_QUERIES = 512
+FRAME_HEADS = 4
+FRAME_VMEM_LIMIT = 32 * 2**20
 _NEG = -1e30          # a score no softmax notices; finite, so no NaN
 
 
@@ -233,3 +255,109 @@ def causal_attention(q, k, v, *, heads: int, kv_heads: int, window=None,
     return attention_blocked(q, k, v, heads=heads, kv_heads=kv_heads,
                              block=block, window=window,
                              block_length=block_length, precision=precision)
+
+
+
+def turn_halves(x, angle):
+    """``x`` ``[R, T, H, hd]`` turned by the rotary ``angle`` ``[T, hd/2]``:
+    pairs ``(i, i + hd/2)``, float32."""
+    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    half = x.shape[-1] // 2
+    lo, hi = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
+    return jnp.concatenate([lo * cos - hi * sin, hi * cos + lo * sin], axis=-1)
+
+
+def _half_swap(hd: int):
+    """``[hd, hd]`` with ``x @ P = (-x[hd/2:], x[:hd/2])``: entries 0 and
+    +-1, exact in any float type."""
+    half = hd // 2
+    p = jnp.zeros((hd, hd), jnp.float32)
+    p = p.at[jnp.arange(half) + half, jnp.arange(half)].set(-1.0)
+    return p.at[jnp.arange(half), jnp.arange(half) + half].set(1.0)
+
+
+def _frame_kernel(q_ref, k_ref, v_ref, cq_ref, sq_ref, ck_ref, sk_ref, p_ref,
+                  o_ref, *, precision):
+    """A block of queries of a group of heads against all keys of their
+    frame; blocks are ``[1, group, block, hd]`` and ``[1, group, T,
+    hd]``.  The heads one after the other, unrolled.  The queries and
+    keys are turned here: ``x cos + (x P) sin`` over the whole head
+    (``P`` swaps and negates the halves on the MXU, exactly), so no
+    array in memory holds a half of a head."""
+    f32 = jnp.float32
+
+    def turned(x, cos, sin):
+        swapped = jnp.dot(x, p_ref[...], preferred_element_type=f32)
+        return (x.astype(f32) * cos + swapped * sin).astype(x.dtype)
+
+    for h in range(q_ref.shape[1]):
+        q = turned(q_ref[0, h], cq_ref[...], sq_ref[...])
+        k = turned(k_ref[0, h], ck_ref[...], sk_ref[...])
+        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            precision=precision, preferred_element_type=f32)
+        p = jnp.exp(s - s.max(axis=1, keepdims=True))
+        out = jnp.dot(p.astype(v_ref.dtype), v_ref[0, h], precision=precision,
+                      preferred_element_type=f32)
+        o_ref[0, h] = (out / p.sum(axis=1, keepdims=True)).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, donate_argnums=(), static_argnames=(
+    "block", "group", "interpret", "precision"))
+def frame_attention_kernel(q, k, v, angle, *, block: int = FRAME_QUERIES,
+                           group: int = FRAME_HEADS, interpret: bool = False,
+                           precision=None):
+    """The Pallas kernel over heads first: ``q``, ``k``, ``v`` ``[R, H,
+    T, hd]`` (``q`` scaled), each row attended both ways within itself,
+    ``q`` and ``k`` turned first by the rotary ``angle`` ``[T, hd/2]``;
+    ``[R, H, T, hd]`` in ``q``'s dtype."""
+    r, heads, t, hd = q.shape
+    block, group = min(block, t), min(group, heads)
+    if t % block or heads % group:
+        raise ValueError(f"{t} positions in blocks of {block}, {heads} heads "
+                         f"in groups of {group}: no whole number of either")
+    query = pl.BlockSpec((1, group, block, hd),
+                         lambda i, g, qi: (i, g, qi, 0))
+    keys = pl.BlockSpec((1, group, t, hd), lambda i, g, qi: (i, g, 0, 0))
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    cos, sin = (jnp.concatenate([cos, cos], axis=-1),
+                jnp.concatenate([sin, sin], axis=-1))
+    by_query = pl.BlockSpec((block, hd), lambda i, g, qi: (qi, 0))
+    whole = pl.BlockSpec((t, hd), lambda i, g, qi: (0, 0))
+    return pl.pallas_call(
+        functools.partial(_frame_kernel, precision=precision),
+        grid=(r, heads // group, t // block),
+        in_specs=[query, keys, keys, by_query, by_query, whole, whole,
+                  pl.BlockSpec((hd, hd), lambda i, g, qi: (0, 0))],
+        out_specs=query,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=FRAME_VMEM_LIMIT),
+        interpret=interpret,
+        name=FRAME_NAME,
+    )(q, k, v, cos, sin, cos, sin, _half_swap(hd).astype(q.dtype))
+
+
+def frame_attention(q, k, v, angle, *, heads: int, precision=None,
+                    force: Optional[object] = None):
+    """``q``, ``k``, ``v`` ``[R, T, H*hd]`` (a row a frame, ``q`` scaled)
+    -> ``[R, T, H*hd]``: every query over every key of its own row, ``q``
+    and ``k`` turned first by the rotary ``angle`` ``[T, hd/2]`` (pairs
+    ``(i, i + hd/2)``).  The kernel on the TPU (heads put first around
+    it, the turn inside it), the blocked ``jax.numpy`` form elsewhere;
+    ``force`` as ``causal_attention``'s."""
+    r, t, width = q.shape
+    hd = width // heads
+    if _on_tpu() if force is None else force:
+
+        def heads_first(x):
+            return jnp.swapaxes(x.reshape(r, t, heads, hd), 1, 2)
+
+        out = frame_attention_kernel(
+            heads_first(q), heads_first(k), heads_first(v), angle,
+            interpret=(force == "interpret"), precision=precision)
+        return jnp.swapaxes(out, 1, 2).reshape(r, t, width)
+    q, k = (turn_halves(x.reshape(r, t, heads, hd), angle).reshape(
+        r, t, width).astype(x.dtype) for x in (q, k))
+    return attention_blocked(q, k, v, heads=heads, kv_heads=heads,
+                             causal=False, precision=precision)

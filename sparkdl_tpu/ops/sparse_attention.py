@@ -27,8 +27,13 @@ of a row of ``T`` positions (``first`` is data, an int32 scalar):
   first)``: for each head ``softmax((q_nope . k_nope + q_pe . k_pe)
   over the selected keys) v`` — heads first: ``q_*`` ``[H, Q, d]``
   already scaled (and turned by the rotary position), ``k_nope``, ``v``
-  ``[H, T, d]``, ``k_pe`` ``[T, dr]`` one for all heads; ``[H, Q, dv]``
-  in ``q``'s dtype.  The kernel (``name="sparse_attention"``) is an
+  ``[KV, T, d]``, ``k_pe`` ``[T, dr]`` one for all heads; ``[H, Q, dv]``
+  in ``q``'s dtype.  ``KV`` divides ``H``: a key/value head serves the
+  ``H // KV`` query heads beside it (grouped-query attention; ``KV = H``
+  is latent attention's per-head keys), read in place for each of them,
+  never repeated in memory.  The rotary part is optional: with
+  ``q_pe`` and ``k_pe`` ``None`` the score is ``q_nope . k_nope`` alone
+  (keys already turned, as grouped-query attention's are).  The kernel (``name="sparse_attention"``) is an
   online softmax over the causal key tiles of a block of queries, a group of heads a
   step, the selection read from ``scores`` and ``sel`` tile by tile and
   applied as a mask: exact, but a tile is fetched and computed whole
@@ -287,24 +292,29 @@ def sparse_attention_plain(q_nope, q_pe, k_nope, v, k_pe, scores, sel,
                            first=0, *, precision=None):
     f32 = jnp.float32
     q, t = scores.shape
+    heads, kv_heads = q_nope.shape[0], k_nope.shape[0]
     query_at = (jnp.asarray(first, jnp.int32)
                 + jnp.arange(q, dtype=jnp.int32)[:, None])
     mask = selected(scores, sel, query_at,
                     jnp.arange(t, dtype=jnp.int32)[None, :])
-    s = (jnp.einsum("hqd,hkd->hqk", q_nope, k_nope, precision=precision,
-                    preferred_element_type=f32)
-         + jnp.einsum("hqd,kd->hqk", q_pe, k_pe, precision=precision,
-                      preferred_element_type=f32))
+    grouped = q_nope.reshape(kv_heads, heads // kv_heads, q, -1)
+    s = jnp.einsum("grqd,gkd->grqk", grouped, k_nope, precision=precision,
+                   preferred_element_type=f32).reshape(heads, q, t)
+    if q_pe is not None:
+        s = s + jnp.einsum("hqd,kd->hqk", q_pe, k_pe, precision=precision,
+                           preferred_element_type=f32)
     p = jax.nn.softmax(jnp.where(mask[None], s, _NEG), axis=-1)
-    out = jnp.einsum("hqk,hkd->hqd", p.astype(v.dtype), v,
+    out = jnp.einsum("grqk,gkd->grqd",
+                     p.astype(v.dtype).reshape(kv_heads, -1, q, t), v,
                      precision=precision, preferred_element_type=f32)
-    return out.astype(q_nope.dtype)
+    return out.reshape(heads, q, -1).astype(q_nope.dtype)
 
 
-def _attention_kernel(at_ref, qn_ref, qp_ref, kn_ref, v_ref, kp_ref, s_ref,
-                      sel_ref, o_ref, m_ref, l_ref, acc_ref, bias_ref, *,
-                      sub: int, precision):
-    """A step: a block of queries, a group of heads, a tile of keys.
+def _attention_kernel(*refs, sub: int, rep: int, rotary: bool, precision):
+    """A step: a block of queries, a group of heads, a tile of keys; query
+    head ``h`` of the group reads key/value head ``h // rep`` of the
+    step's (``rep`` query heads a key/value head), and with ``rotary``
+    the shared ``k_pe`` beside it.
 
     Scores are taken transposed, ``[keys, queries]``: the queries are the
     lanes, so a head's running maximum and sum are one row of lanes
@@ -321,6 +331,12 @@ def _attention_kernel(at_ref, qn_ref, qp_ref, kn_ref, v_ref, kp_ref, s_ref,
     waited behind the previous head's softmax: in
     ``deepseek_v3_2_exp.rows32k`` 18.3 µs a step, against 10.0 µs so
     (``PERF.md`` §6)."""
+    if rotary:
+        (at_ref, qn_ref, qp_ref, kn_ref, v_ref, kp_ref, s_ref, sel_ref, o_ref,
+         m_ref, l_ref, acc_ref, bias_ref) = refs
+    else:
+        (at_ref, qn_ref, kn_ref, v_ref, s_ref, sel_ref, o_ref, m_ref, l_ref,
+         acc_ref, bias_ref) = refs
     f32 = jnp.float32
     i, j, nk = pl.program_id(0), pl.program_id(2), pl.num_programs(2)
     heads, block, _ = qn_ref.shape
@@ -347,13 +363,14 @@ def _attention_kernel(at_ref, qn_ref, qp_ref, kn_ref, v_ref, kp_ref, s_ref,
 
         def scores(h, c):
             keys = pl.ds(c * sub, sub)
-            return (lax.dot_general(kn_ref[h, keys], qn_ref[h], by_row,
-                                    precision=precision,
-                                    preferred_element_type=f32)
-                    + lax.dot_general(kp_ref[keys], qp_ref[h], by_row,
-                                      precision=precision,
-                                      preferred_element_type=f32)
-                    + bias_ref[keys])                       # [sub, block]
+            s = lax.dot_general(kn_ref[h // rep, keys], qn_ref[h], by_row,
+                                precision=precision,
+                                preferred_element_type=f32)
+            if rotary:
+                s = s + lax.dot_general(kp_ref[keys], qp_ref[h], by_row,
+                                        precision=precision,
+                                        preferred_element_type=f32)
+            return s + bias_ref[keys]                       # [sub, block]
 
         # unrolled, not a loop of heads: a loop body is a scheduling
         # boundary.  The step's 32 pieces cost 0.26 s more of tracing and
@@ -372,7 +389,8 @@ def _attention_kernel(at_ref, qn_ref, qp_ref, kn_ref, v_ref, kp_ref, s_ref,
             scale = jnp.exp(m - m_new)
             l = scale * l + p.sum(axis=0, keepdims=True)
             acc = scale * acc + lax.dot_general(
-                v_ref[h, pl.ds(c * sub, sub)], p.astype(v_ref.dtype), by_key,
+                v_ref[h // rep, pl.ds(c * sub, sub)], p.astype(v_ref.dtype),
+                by_key,
                 precision=precision, preferred_element_type=f32)  # [dv, block]
             m, s = m_new, later
             if c == subs - 1:
@@ -395,18 +413,23 @@ def sparse_attention_kernel(q_nope, q_pe, k_nope, v, k_pe, scores, sel,
                             sub: int = SUB_KEYS, interpret: bool = False,
                             precision=None):
     """The Pallas kernel; ``k_pe`` and the selection are shared by the
-    heads, a group of which a step takes.  The grid's key axis is as long
-    as the last query block's causal tiles (a grid dimension that is
-    data): the steps past a block's diagonal fetch and compute nothing."""
+    heads, a group of which a step takes with the key/value heads they
+    read (a group is a whole number of key/value heads' query heads).
+    The grid's key axis is as long as the last query block's causal
+    tiles (a grid dimension that is data): the steps past a block's
+    diagonal fetch and compute nothing."""
     heads, q, dn = q_nope.shape
-    t, dr, dv = k_nope.shape[1], q_pe.shape[-1], v.shape[-1]
+    kv_heads, t, dv = k_nope.shape[0], k_nope.shape[1], v.shape[-1]
+    rotary = q_pe is not None
     block, tile, group = min(block, q), min(tile, t), min(group, heads)
     sub = min(sub, tile)
-    if q % block or t % tile or heads % group or tile % sub:
+    rep = heads // kv_heads
+    if (q % block or t % tile or heads % group or tile % sub
+            or heads % kv_heads or group % rep):
         raise ValueError(f"{q} queries in blocks of {block}, {t} keys in "
                          f"tiles of {tile} in sub-tiles of {sub}, {heads} "
-                         f"heads in groups of {group}: no whole number of "
-                         "one of them")
+                         f"heads in groups of {group} over {kv_heads} "
+                         "key/value heads: no whole number of one of them")
     at = jnp.asarray(first, jnp.int32).reshape(1)
     tiles = jnp.minimum(_tiles(at[0], q // block - 1, block, tile), t // tile)
 
@@ -419,17 +442,24 @@ def sparse_attention_kernel(q_nope, q_pe, k_nope, v, k_pe, scores, sel,
         return pl.BlockSpec((group, block, width), lambda i, g, j, at: (g, i, 0))
 
     def by_key(width):
-        return pl.BlockSpec((group, tile, width),
+        return pl.BlockSpec((group // rep, tile, width),
                             lambda i, g, j, at: (g, key_tile(i, g, j, at), 0))
 
+    rotary_specs, rotary_args = [], []
+    if rotary:
+        dr = q_pe.shape[-1]
+        rotary_specs = [by_query(dr), pl.BlockSpec(
+            (tile, dr), lambda i, g, j, at: (key_tile(i, g, j, at), 0))]
+        rotary_args = [q_pe, k_pe]
+    key_specs = [by_key(dn), by_key(dv)]
     return pl.pallas_call(
-        functools.partial(_attention_kernel, sub=sub, precision=precision),
+        functools.partial(_attention_kernel, sub=sub, rep=rep, rotary=rotary,
+                          precision=precision),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(q // block, heads // group, tiles),
-            in_specs=[by_query(dn), by_query(dr), by_key(dn), by_key(dv),
-                      pl.BlockSpec((tile, dr), lambda i, g, j, at: (
-                          key_tile(i, g, j, at), 0)),
+            in_specs=[by_query(dn), *rotary_specs[:1], *key_specs,
+                      *rotary_specs[1:],
                       pl.BlockSpec((block, tile), lambda i, g, j, at: (
                           i, key_tile(i, g, j, at))),
                       pl.BlockSpec((block, 2), lambda i, g, j, at: (i, 0))],
@@ -444,7 +474,7 @@ def sparse_attention_kernel(q_nope, q_pe, k_nope, v, k_pe, scores, sel,
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
         name=ATTENTION_NAME,
-    )(at, q_nope, q_pe, k_nope, v, k_pe, scores, sel)
+    )(at, q_nope, *rotary_args[:1], k_nope, v, *rotary_args[1:], scores, sel)
 
 
 def sparse_attention(q_nope, q_pe, k_nope, v, k_pe, scores, sel, first=0, *,

@@ -119,10 +119,27 @@ def _count_sparse(counts, metrics, tokens):
     return {"selected_pairs": chosen, "fetched_keys": fetched}
 
 
+def _count_vision(counts, metrics, tokens):
+    """``vision_counts`` ``[rows, 3]`` (``vl_trunk.VISION_COUNTS``),
+    summed over rows: the frames and the patches that went through the
+    vision tower (``vision.frames``, ``vision.patches``) and the tower's
+    tokens that took the places of placeholder ids
+    (``vision.spliced_tokens``)."""
+    del tokens
+    frames, patches, spliced = (int(v) for v in
+                                counts.astype("int64").sum(axis=0))
+    for name, value in (("vision.frames", frames),
+                        ("vision.patches", patches),
+                        ("vision.spliced_tokens", spliced)):
+        metrics.incr(name, value)
+    return {"frames": frames, "spliced_tokens": spliced}
+
+
 #: how each counter a program may declare reaches the engine's metrics
 COUNTERS = {"expert_load": _count_expert_load,
             "diffusion_counts": _count_diffusion,
-            "sparse_counts": _count_sparse}
+            "sparse_counts": _count_sparse,
+            "vision_counts": _count_vision}
 
 
 def output_column(out):
@@ -321,12 +338,11 @@ class TFTransformer(Transformer, HasBatchSize):
                      for col, input_name in in_map.items()}
                 sp.annotate(bytes=sum(int(a.nbytes) for a in x.values()))
             root.annotate(rows=len(dataset))
-            ids = [a for a in x.values() if a.ndim == 2
-                   and np.issubdtype(a.dtype, np.integer)]
+            ids = x.get(mf.token_input)
             eng = self.engine()
             out, counted = count_outputs(
                 mf, eng(x), eng.metrics, out_map,
-                tokens=sum(int(a.size) for a in ids) if ids else None)
+                tokens=None if ids is None else int(ids.size))
             root.annotate(**counted)
             for output_name, name in out_map.items():
                 values = out[output_name]

@@ -189,3 +189,88 @@ def test_a_block_length_fills_the_query_block_and_takes_no_window():
             form(q, k, v, block=8, block_length=4, window=4)
         with pytest.raises(ValueError, match="causal mask alone"):
             form(q, k, v, block=8, block_length=4, causal=False)
+
+
+# -- frame attention: each row alone, both ways, heads narrower than a lane ---
+
+FRAMES, PATCHES, VHEADS, VHD = 3, 32, 4, 72
+
+
+def _frames(seed=0):
+    k = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return tuple(s * jax.random.normal(key, (FRAMES, PATCHES, VHEADS * VHD))
+                 for s, key in zip((0.3, 1.0, 1.0), k))
+
+
+#: a rotary angle of 0 at every position: no turn
+STILL = jnp.zeros((PATCHES, VHD // 2))
+
+
+@pytest.mark.parametrize("block, group", [(8, 1), (16, 2), (PATCHES, 4)])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_frame_attention_is_the_blocked_form_both_ways(block, group, dtype):
+    """The kernel, heads first, 72 wide, against ``attention_blocked(...,
+    causal=False)`` with one frame a row: every query over every key of
+    its own frame."""
+    q, k, v = (a.astype(dtype) for a in _frames())
+    want = attention.attention_blocked(q, k, v, heads=VHEADS, kv_heads=VHEADS,
+                                       causal=False)
+
+    def heads_first(x):
+        return jnp.swapaxes(x.reshape(FRAMES, PATCHES, VHEADS, VHD), 1, 2)
+
+    got = jnp.swapaxes(attention.frame_attention_kernel(
+        heads_first(q), heads_first(k), heads_first(v), STILL, block=block,
+        group=group, interpret=True), 1, 2).reshape(q.shape)
+    assert got.dtype == want.dtype
+    atol = 1e-5 if dtype == "float32" else 2e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=atol)
+    forced = attention.frame_attention(q, k, v, STILL, heads=VHEADS,
+                                       force="interpret")
+    np.testing.assert_allclose(np.asarray(forced, np.float32),
+                               np.asarray(got, np.float32), atol=atol)
+
+
+@pytest.mark.parametrize("force", ["interpret", False])
+def test_a_frame_sees_no_other_frame(force):
+    """A query's output is its own frame's softmax written out, and does
+    not move when another frame's keys and values do."""
+    q, k, v = _frames(1)
+    got = attention.frame_attention(q, k, v, STILL, heads=VHEADS, force=force)
+    qh, kh, vh = (a.reshape(FRAMES, PATCHES, VHEADS, VHD) for a in (q, k, v))
+    p = jax.nn.softmax(jnp.einsum("fqhd,fkhd->fhqk", qh, kh), -1)
+    want = jnp.einsum("fhqk,fkhd->fqhd", p, vh).reshape(q.shape)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+    other = attention.frame_attention(q, k.at[1].multiply(-3.0),
+                                      v.at[1].add(5.0), STILL, heads=VHEADS,
+                                      force=force)
+    np.testing.assert_allclose(np.asarray(other)[0], np.asarray(got)[0],
+                               atol=1e-6)
+    np.testing.assert_allclose(np.asarray(other)[2], np.asarray(got)[2],
+                               atol=1e-6)
+    assert np.abs(np.asarray(other)[1] - np.asarray(got)[1]).max() > 0.1
+
+
+def test_frame_attention_turns_the_heads_inside_the_kernel():
+    """The kernel turns ``q`` and ``k`` by the rotary angle itself (the
+    halves swapped by an exact product) and agrees with the turn written
+    out before the blocked form; an angle of 0 is no turn."""
+    q, k, v = _frames(2)
+    angle = jax.random.uniform(jax.random.PRNGKey(3), (PATCHES, VHD // 2),
+                               maxval=6.0)
+    got = attention.frame_attention(q, k, v, angle, heads=VHEADS,
+                                    force="interpret")
+    want = attention.frame_attention(q, k, v, angle, heads=VHEADS, force=False)
+    by_hand = attention.attention_blocked(
+        *(attention.turn_halves(x.reshape(FRAMES, PATCHES, VHEADS, VHD),
+                                angle).reshape(q.shape) for x in (q, k)), v,
+        heads=VHEADS, kv_heads=VHEADS, causal=False)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+    np.testing.assert_allclose(np.asarray(want), np.asarray(by_hand), atol=1e-6)
+    still = attention.frame_attention(q, k, v, STILL, heads=VHEADS,
+                                      force="interpret")
+    plain = attention.attention_blocked(q, k, v, heads=VHEADS, kv_heads=VHEADS,
+                                        causal=False)
+    np.testing.assert_allclose(np.asarray(still), np.asarray(plain), atol=1e-5)
+    assert np.abs(np.asarray(got) - np.asarray(plain)).max() > 1e-2

@@ -215,3 +215,56 @@ def test_the_counts_of_pairs_and_fetched_keys():
     whole = sum(int(sa.fetched_keys(f, q, t, force=True))
                 for f in range(0, t, q))
     assert t * (t + 1) // 2 < whole < t * t
+
+
+# -- grouped-query heads, no rotary part ---------------------------------------
+
+KV = 2
+
+
+def _grouped_operands(seed=7):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (0.3 * jax.random.normal(ks[0], (H, Q, DN)),
+            jax.random.normal(ks[1], (KV, T, DN)),
+            jax.random.normal(ks[2], (KV, T, DV)))
+
+
+@pytest.mark.parametrize("block, tile, group, sub", [
+    (8, 16, 2, 16),     # one key/value head a step
+    (8, 32, 4, 16),     # both key/value heads in one step
+    (32, 64, 2, 16),    # one block of all the queries
+])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_grouped_heads_read_their_key_value_head_in_place(dtype, block, tile,
+                                                          group, sub):
+    """``kv_heads`` < heads and no rotary part: the kernel against the
+    plain form, and the plain form against every key/value head repeated
+    for its query heads (the per-head form the module had)."""
+    q, k, w = _index_operands(8)
+    scores = sa.indexer_scores(q, k, w, FIRST, force=False)
+    sel = sa.topk_select(scores, FIRST, TOP, force=False)
+    q_g, k_g, v_g = (a.astype(dtype) for a in _grouped_operands())
+    want = sa.sparse_attention(q_g, None, k_g, v_g, None, scores, sel, FIRST,
+                               force=False)
+    got = sa.sparse_attention_kernel(q_g, None, k_g, v_g, None, scores, sel,
+                                     FIRST, block=block, tile=tile,
+                                     group=group, sub=sub, interpret=True)
+    assert got.shape == want.shape == (H, Q, DV)
+    atol = 1e-5 if dtype == "float32" else 2e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=atol)
+    repeated = [jnp.repeat(a, H // KV, axis=0) for a in (k_g, v_g)]
+    zeros_q, zeros_k = jnp.zeros((H, Q, DR), dtype), jnp.zeros((T, DR), dtype)
+    per_head = sa.sparse_attention(q_g, zeros_q, repeated[0], repeated[1],
+                                   zeros_k, scores, sel, FIRST, force=False)
+    np.testing.assert_allclose(np.asarray(want, np.float32),
+                               np.asarray(per_head, np.float32), atol=atol)
+
+
+def test_a_group_must_hold_whole_key_value_heads():
+    q_g, k_g, v_g = _grouped_operands()
+    scores = jnp.zeros((Q, T))
+    sel = sa.topk_select(scores, 0, TOP, force=False)
+    with pytest.raises(ValueError, match="over 2 key/value heads"):
+        sa.sparse_attention_kernel(q_g, None, k_g, v_g, None, scores, sel, 0,
+                                   block=8, tile=16, group=1, interpret=True)

@@ -578,3 +578,126 @@ def test_the_latent_trunk_weights_are_drawn_in_place_on_v5e(v5e_chip):
     mem = compiled.memory_analysis()
     assert 8.80e9 < mem.output_size_in_bytes < 8.82e9
     assert mem.temp_size_in_bytes < 3e9
+
+
+# -- the vision-language trunk: frame attention, grouped sparse attention ------
+
+def test_vl_trunk_kernels_compile_for_v5e_at_published_widths(v5e_chip):
+    """``keye_vl_2_30b_a3b.video32k``'s kernels at their shapes: the
+    tower's attention over a chunk of 8 frames of 1024 patches, 16 heads
+    of 72 first (72 the whole last axis), turned by the 2-D rotary
+    position inside the kernel (no array of half a head); the
+    indexer at 16 heads of 64 against one key head, and the attention of
+    32 query heads over 4 key/value heads of 128 with no rotary part, at
+    a chunk of 4096 queries of a row of 32,768."""
+    import re
+
+    from sparkdl_tpu.ops import attention
+    from sparkdl_tpu.ops import sparse_attention as sa
+
+    bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+    frames = _on_chip((8, 16, 1024, 72), bf16, v5e_chip)
+    q, t = 4096, 32768
+    at = _on_chip((), i32, v5e_chip)
+    scores = _on_chip((q, t), f32, v5e_chip)
+    sel = _on_chip((q, 2), i32, v5e_chip)
+    for name, compiled in (
+            (attention.FRAME_NAME, attention.frame_attention_kernel.lower(
+                frames, frames, frames,
+                _on_chip((1024, 36), f32, v5e_chip)).compile()),
+            (sa.INDEXER_NAME, sa.indexer_scores_kernel.lower(
+                _on_chip((q, 16, 64), bf16, v5e_chip),
+                _on_chip((t, 64), bf16, v5e_chip),
+                _on_chip((q, 16), f32, v5e_chip), at).compile()),
+            (sa.ATTENTION_NAME, sa.sparse_attention_kernel.lower(
+                _on_chip((32, q, 128), bf16, v5e_chip), None,
+                _on_chip((4, t, 128), bf16, v5e_chip),
+                _on_chip((4, t, 128), bf16, v5e_chip), None, scores, sel,
+                at).compile())):
+        text = compiled.as_text()
+        assert "tpu_custom_call" in text and f"%{name}." in text, name
+    assert not re.search(r"bf16\[8,16,1024,128\]", text)
+
+
+def test_the_vl_trunk_program_compiles_for_v5e(v5e_chip, monkeypatch):
+    """``keye_vl_2_30b_a3b``'s whole program through the engine's dispatch
+    program (kernel paths on) at the cell's dispatch of 1 row: 120 frames
+    of 448 x 448 x 3 uint8 beside 32,768 ids.  It compiles, every kernel
+    is ONE instruction whatever the depth, the chunks of frames and of
+    queries, and the dispatch fits the chip beside its 9.01 GB of
+    weights."""
+    import json
+    import re
+
+    from jax.sharding import Mesh
+
+    from sparkdl_tpu.models import vl_trunk
+    from sparkdl_tpu.ops import attention, grouped_matmul, sparse_attention
+    from sparkdl_tpu.parallel import mesh as mesh_lib
+    from sparkdl_tpu.parallel.engine import build_dispatch_jit
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "keye_vl_2_30b_a3b.json")) as fh:
+        config = json.load(fh)
+    for module in (attention, grouped_matmul, sparse_attention):
+        monkeypatch.setattr(module, "_on_tpu", lambda: True)
+    device = next(iter(v5e_chip.device_set))
+    mesh = Mesh(np.asarray([device]).reshape(1, 1),
+                (mesh_lib.DATA_AXIS, mesh_lib.MODEL_AXIS))
+    variables = jax.eval_shape(lambda k: vl_trunk.init(config, k),
+                               jax.random.PRNGKey(0))
+    fn = vl_trunk.model_function(config, {}).fn
+    compiled = build_dispatch_jit(fn, mesh, donate_batch=False).lower(
+        variables, {"pixels": jax.ShapeDtypeStruct((1, 120 * 448 * 448 * 3),
+                                                   np.uint8),
+                    "ids": jax.ShapeDtypeStruct((1, 32768), np.int32)}
+    ).compile()
+    text = compiled.as_text()
+    kernels = sorted(name.split(".")[0] for name in set(re.findall(
+        r"%((?:frame_attention|indexer_scores|topk_select|sparse_attention"
+        r"|grouped_matmul)\.\d+) = ", text)))
+    assert kernels == ["frame_attention", "grouped_matmul", "indexer_scores",
+                       "sparse_attention", "topk_select"], kernels
+    mem = compiled.memory_analysis()
+    print("arguments", mem.argument_size_in_bytes, "temporaries",
+          mem.temp_size_in_bytes)
+    # the configuration's arithmetic: 0.885 + 0.622 + 6 x 1.251 = 9.013 GB of
+    # weights as drawn, laid out in the chip's tiles, and the dispatch's
+    # 72 MB of pixels
+    assert 9.2e9 < mem.argument_size_in_bytes < 9.35e9
+    assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes) < 15.0e9
+
+
+def test_the_vl_trunk_weights_are_drawn_in_place_on_v5e(v5e_chip):
+    """``keye_vl_2_30b_a3b``'s weights, drawn by the reference's rule in
+    ONE program that takes the seed as its argument (one compiled draw
+    for every seed): 9.01 GB out, and beside it no more than a layer's
+    pieces and their float32 draw, never a second copy of a stack."""
+    import json
+
+    from benchmark.reference import keye_vl2
+    from sparkdl_tpu.models import vision_tower, vl_trunk
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "keye_vl_2_30b_a3b.json")) as fh:
+        config = json.load(fh)
+
+    def draw(parts):
+        w = keye_vl2.draw_weights(config, parts)
+        return {"vision": vision_tower.stack_tower(
+                    w.vision_leaf, config["vision_config"],
+                    config["hidden_size"]),
+                "embed_tokens": w.embedding(),
+                **vl_trunk.stack_layers(w.leaf, config),
+                "norm": w.final_norm()}
+
+    compiled = jax.jit(draw, out_shardings=v5e_chip).lower(
+        _on_chip((2,), jnp.int32, v5e_chip)).compile()
+    mem = compiled.memory_analysis()
+    print("draw out", mem.output_size_in_bytes, "temporaries",
+          mem.temp_size_in_bytes)
+    assert 9.0e9 < mem.output_size_in_bytes < 9.3e9
+    assert mem.temp_size_in_bytes < 3e9

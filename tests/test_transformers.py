@@ -157,6 +157,35 @@ def test_tf_transformer_mapping(rng):
                       outputMapping={"sum": "s"}).transform(df)
 
 
+@pytest.mark.parametrize("id_type", [pa.int32(), pa.uint16()])
+def test_tf_transformer_counts_the_named_token_input(id_type):
+    """Of several integer columns, the tokens the counters take are the
+    values of the input the program names as its ids, whatever their
+    integer type; the pixel column beside them is none."""
+    import jax.numpy as jnp
+
+    def fn(v, d):
+        rows = d["ids"].shape[0]
+        return {"y": d["pixels"].astype(jnp.float32).sum(axis=1),
+                "expert_load": jnp.ones((rows, 3, 2), jnp.int32)}
+
+    mf = ModelFunction(fn=fn, variables={}, input_names=("pixels", "ids"),
+                       output_names=("y", "expert_load"),
+                       counter_names=("expert_load",), token_input="ids")
+    df = DataFrame(pa.table({
+        "clip": pa.array([[1] * 12] * 5, type=pa.list_(pa.uint8())),
+        "text": pa.array([[7, 8, 9]] * 5, type=pa.list_(id_type))}))
+    stage = TFTransformer(modelFunction=mf,
+                          inputMapping={"clip": "pixels", "text": "ids"},
+                          outputMapping={"y": "y"}, batchSize=2)
+    out = stage.transform(df)
+    np.testing.assert_allclose(out.column_to_numpy("y").reshape(-1), [12.0] * 5)
+    counters = stage.engine().metrics.snapshot_raw()["counters"]
+    # 5 rows of 3 ids, over 3 expert layers
+    assert counters["moe.tokens"] == 5 * 3 * 3
+    assert counters["moe.pairs"] == 5 * 3 * 2
+
+
 def test_pipeline_chains_stages(fake_resnet, image_df):
     class _Renamer(Transformer):
         def _transform(self, ds):
