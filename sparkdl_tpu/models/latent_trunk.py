@@ -65,8 +65,9 @@ indexer's weights and scores, softmax, the router's product (at
 Beside the features the program returns two COUNTERS
 (``ModelFunction.counter_names``): ``expert_load`` ``[rows, expert
 layers, held]`` as ``expert_trunk``'s, and ``sparse_counts`` ``[rows,
-layers, 3]`` int32: the causal pairs the indexer scored, the pairs
-selected, and the key rows the attention read (``SPARSE_COUNTS``).
+layers, 4]`` int32: the causal pairs the indexer scored, the pairs
+selected, the key rows the attention read, and the queries whose
+selection needed the tie passes (``SPARSE_COUNTS``).
 Names of weights are the published checkpoint's and matrices ``[in,
 out]``; ``gate_proj`` and ``up_proj`` lie side by side
 (``gate_up_proj``), a layer's routed experts stacked on a leading axis.
@@ -88,7 +89,8 @@ from sparkdl_tpu.ops import sparse_attention as sa
 #: the program's outputs that are counters, not columns
 COUNTERS = ("expert_load", "sparse_counts")
 #: what ``sparse_counts`` holds a row and a layer, in order
-SPARSE_COUNTS = ("indexed_pairs", "selected_pairs", "fetched_keys")
+SPARSE_COUNTS = ("indexed_pairs", "selected_pairs", "fetched_keys",
+                 "tied_queries")
 #: a row's queries worked together (scores [chunk, T] float32: 0.5 GB at
 #: 4096 x 32,768)
 QUERY_CHUNK = 4096
@@ -320,7 +322,7 @@ def index_queries(config: Dict[str, Any], h, c_q, at, w, dtype, precision):
 def attention_row(config: Dict[str, Any], x, w, dtype, precision,
                   query_chunk: int = QUERY_CHUNK):
     """``x`` ``[T, D]`` float32 (one row) plus the layer's attention
-    branch, and the row's ``[3]`` int32 counts (``SPARSE_COUNTS``)."""
+    branch, and the row's ``[4]`` int32 counts (``SPARSE_COUNTS``)."""
     c, f32, i32 = config, jnp.float32, jnp.int32
     eps, t = c["rms_norm_eps"], x.shape[0]
     heads, nope, rope = (c["num_attention_heads"], c["qk_nope_head_dim"],
@@ -381,7 +383,8 @@ def attention_row(config: Dict[str, Any], x, w, dtype, precision,
             x, lax.dynamic_slice_in_dim(x, first, chunk) + out, first, 0)
         counts = counts + jnp.stack([
             sa.indexed_pairs(first, chunk), sa.selected_pairs(first, chunk, top),
-            sa.fetched_keys(first, chunk, t)]).astype(i32)
+            sa.fetched_keys(first, chunk, t),
+            sa.tied_queries(sel, first)]).astype(i32)
         return x, counts
 
     return lax.fori_loop(0, t // chunk, one_chunk,
@@ -393,7 +396,7 @@ def apply(variables: Dict[str, Any], ids, config: Dict[str, Any], *,
           token_chunk: int = TOKEN_CHUNK):
     """``ids`` ``[R, T]`` integers -> ``{"features": [R, D] float32,
     "expert_load": [R, expert layers, held] int32, "sparse_counts": [R,
-    layers, 3] int32}``.  The compute dtype is the weights' own."""
+    layers, 4] int32}``.  The compute dtype is the weights' own."""
     c = config
     f32, i32 = jnp.float32, jnp.int32
     dtype = variables["embedding"].dtype

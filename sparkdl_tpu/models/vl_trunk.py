@@ -56,7 +56,7 @@ and the router are float32.
 
 Beside the features the program returns two COUNTERS
 (``ModelFunction.counter_names``): ``sparse_counts`` ``[rows, layers,
-3]`` as ``latent_trunk``'s, and ``vision_counts`` ``[rows, 3]`` int32:
+4]`` as ``latent_trunk``'s, and ``vision_counts`` ``[rows, 3]`` int32:
 the frames and patches through the tower and the tokens spliced into
 the row (``VISION_COUNTS``).
 """
@@ -236,7 +236,7 @@ def index_sections(config: Dict[str, Any]):
 def attention_row(config: Dict[str, Any], x, positions, w, dtype, precision,
                   query_chunk: int = QUERY_CHUNK):
     """``x`` ``[T, D]`` float32 (one row, its positions ``[T, 3]``) plus
-    the layer's attention branch, and the row's ``[3]`` int32 counts
+    the layer's attention branch, and the row's ``[4]`` int32 counts
     (``SPARSE_COUNTS``)."""
     c, sc, f32, i32 = config, config["sa_config"], jnp.float32, jnp.int32
     eps, theta, t = c["rms_norm_eps"], c["rope_theta"], x.shape[0]
@@ -299,7 +299,8 @@ def attention_row(config: Dict[str, Any], x, positions, w, dtype, precision,
             x, lax.dynamic_slice_in_dim(x, first, chunk) + out, first, 0)
         counts = counts + jnp.stack([
             sa.indexed_pairs(first, chunk), sa.selected_pairs(first, chunk, top),
-            sa.fetched_keys(first, chunk, t)]).astype(i32)
+            sa.fetched_keys(first, chunk, t),
+            sa.tied_queries(sel, first)]).astype(i32)
         return x, counts
 
     return lax.fori_loop(0, t // chunk, one_chunk,
@@ -337,7 +338,7 @@ def embed(variables: Dict[str, Any], pixels, ids, config: Dict[str, Any], *,
 def apply(variables: Dict[str, Any], pixels, ids, config: Dict[str, Any], *,
           precision=None, query_chunk: int = QUERY_CHUNK):
     """``pixels`` uint8 and ``ids`` ``[R, T]`` integers -> ``{"features":
-    [R, D] float32, "sparse_counts": [R, layers, 3] int32,
+    [R, D] float32, "sparse_counts": [R, layers, 4] int32,
     "vision_counts": [R, 3] int32}``.  The compute dtype is the weights'
     own."""
     c, f32, i32 = config, jnp.float32, jnp.int32

@@ -21,8 +21,10 @@ of a row of ``T`` positions (``first`` is data, an int32 scalar):
   and, of ``key(score) == tau``, those at positions ``<= last``
   (``key`` orders float32 as int32 do: ``order_key``).  Exact: the
   kernel (``name="topk_select"``) finds ``tau`` by counting over its
-  bits, 32 passes over a query block's scores in VMEM (no sort), and
-  ``last`` by counting over the positions of the ties.
+  bits, 32 passes over a query block's causal keys in VMEM (no sort),
+  and ``last`` by counting over the positions of the ties — passes that
+  run only for a block where ties at ``tau`` lie on both sides of the
+  boundary; elsewhere ``last`` is the query's own position.
 * ``sparse_attention(q_nope, q_pe, k_nope, v, k_pe, scores, sel,
   first)``: for each head ``softmax((q_nope . k_nope + q_pe . k_pe)
   over the selected keys) v`` — heads first: ``q_*`` ``[H, Q, d]``
@@ -63,8 +65,12 @@ ATTENTION_NAME = "sparse_attention"
 #: the indexer's queries a step (x its heads: the product's rows) and keys
 INDEX_QUERIES = 16
 INDEX_KEYS = 1024
-#: queries whose scores a selection step holds (8 x 32,768 float32: 1 MB)
+#: queries whose scores a selection step holds (8 x 32,768 float32: 1 MB),
+#: the keys a counting pass takes at a time, and the lanes its partial
+#: counts are kept in ([8, 512] int32: 4 vector registers)
 SELECT_QUERIES = 8
+SELECT_LANES = 4096
+SELECT_FOLD = 512
 #: the attention's queries, keys and heads a step, and the keys a head
 #: scores at a time (256 x 256 float32: the 64 vector registers)
 QUERIES = 256
@@ -118,6 +124,14 @@ def selected_pairs(first, queries: int, top: int):
     """``sum min(t + 1, top)`` over the queries (int32)."""
     at = jnp.asarray(first, jnp.int32) + jnp.arange(queries, dtype=jnp.int32)
     return jnp.sum(jnp.minimum(at + 1, top))
+
+
+def tied_queries(sel, first):
+    """Queries from ``first`` whose ``last`` the tie passes placed: those
+    with ties at ``tau`` on both sides of the boundary (int32)."""
+    query_at = (jnp.asarray(first, jnp.int32)
+                + jnp.arange(sel.shape[0], dtype=jnp.int32))
+    return jnp.sum((sel[:, 1] < query_at).astype(jnp.int32))
 
 
 def fetched_keys(first, queries: int, positions: int,
@@ -207,33 +221,53 @@ def indexer_scores(q, k, w, first=0, *, precision=None,
 
 # -- the selection -------------------------------------------------------------
 
-def _select(key, query_at, key_at, top: int, loop):
-    """``(tau, last)`` ``[Q, 1]`` of ``key`` ``[Q, T]`` int32 (keys after
-    the query already ``_INT_MIN``, which no score's key is).  ``loop``
-    is ``lax.fori_loop`` or a kernel's."""
+def _select(count, query_at, top: int, width: int):
+    """``(tau, last)`` ``[Q, 1]`` of a query block's keys.  ``count(pred)``
+    is ``[Q, 1]``, the number of a query's keys ``s`` with ``pred(key,
+    key_at)``, keys after the query being ``_INT_MIN`` (which no score's
+    key is) and positions below ``2**width``.
+
+    ``tau`` is the ``need``-th largest key, found bit by bit from the top;
+    its pass also says how many keys reach it.  Where that is ``need``
+    every tie at ``tau`` is taken, and ``last`` is the query's own
+    position.  Only where ties lie on both sides of the boundary does
+    ``last`` take ``width`` more passes, over the positions of the ties,
+    and those run only for a block that holds such a query."""
     i32 = jnp.int32
     need = jnp.minimum(query_at + 1, top)
 
-    def count(mask):
-        return jnp.sum(mask.astype(i32), axis=1, keepdims=True)
-
-    def bit(b, ans):
+    def bit(b, state):
         # the largest tau (bits set from the top, in the order of
         # unsigned numbers: the sign bit flipped) with need keys >= it
+        ans, reach = state
         cand = ans | lax.shift_left(jnp.int32(1), 31 - b)
-        return jnp.where(count(key >= (cand ^ _INT_MIN)) >= need, cand, ans)
+        n = count(lambda key, key_at: key >= (cand ^ _INT_MIN))
+        return jnp.where(n >= need, cand, ans), jnp.where(n >= need, n, reach)
 
-    tau = loop(0, 32, bit, jnp.zeros_like(need)) ^ _INT_MIN
-    ties = key == tau
-    wanted = need - count(key > tau)                 # >= 1 of the ties
-    width = max(key.shape[1] - 1, 1).bit_length()
+    zeros = jnp.zeros_like(need)
+    ans, reach = lax.fori_loop(0, 32, bit, (zeros, zeros))
+    tau = ans ^ _INT_MIN
+    split = reach > need                    # ties left out at the boundary
 
-    def place(b, ans):
-        # the largest position with fewer than ``wanted`` ties before it
-        cand = ans + lax.shift_left(jnp.int32(1), width - 1 - b)
-        return jnp.where(count(ties & (key_at < cand)) < wanted, cand, ans)
+    def placed():
+        wanted = need - count(lambda key, key_at: key > tau)  # >= 1 of ties
 
-    return tau, loop(0, width, place, jnp.zeros_like(need))
+        def place(b, ans):
+            # the largest position with fewer than ``wanted`` ties before it
+            cand = ans + lax.shift_left(jnp.int32(1), width - 1 - b)
+            n = count(lambda key, key_at: (key == tau) & (key_at < cand))
+            return jnp.where(n < wanted, cand, ans)
+
+        return jnp.where(split, lax.fori_loop(0, width, place, zeros),
+                         query_at)
+
+    last = lax.cond(jnp.max(split.astype(i32)) > 0, placed,
+                    lambda: query_at)
+    return tau, last
+
+
+def _width(positions: int) -> int:
+    return max(positions - 1, 1).bit_length()
 
 
 def topk_select_plain(scores, first, top: int):
@@ -242,35 +276,86 @@ def topk_select_plain(scores, first, top: int):
                 + jnp.arange(q, dtype=jnp.int32)[:, None])
     key_at = jnp.arange(t, dtype=jnp.int32)[None, :]
     key = jnp.where(key_at <= query_at, order_key(scores), _INT_MIN)
-    tau, last = _select(key, query_at, key_at, top, lax.fori_loop)
+
+    def count(pred):
+        return jnp.sum(pred(key, key_at).astype(jnp.int32), axis=1,
+                       keepdims=True)
+
+    tau, last = _select(count, query_at, top, _width(t))
     return jnp.concatenate([tau, last], axis=1)
 
 
-def _select_kernel(at_ref, s_ref, o_ref, *, top: int):
+def _select_kernel(at_ref, s_ref, o_ref, key_ref, *, top: int, lanes: int,
+                   fold: int):
+    """A block of queries: their order keys are built once, over the
+    ``lanes``-wide chunks of keys up to the block's last query (the causal
+    prefix), into ``key_ref``; a pass counts over those chunks alone into
+    ``[block, fold]`` partial counts, summed across lanes once a pass.
+
+    On a v5e chip at ``deepseek_v3_2_exp.rows32k``'s shape a layer-row's
+    eight chunks take 27.0 ms, against 90.2 counting every key 47 times;
+    2,048-wide chunks added into ``[8, 2048]`` counts took 32.6
+    (``PERF.md`` §6)."""
+    i32 = jnp.int32
     block, t = s_ref.shape
-    query_at = (at_ref[0] + pl.program_id(0) * block
-                + lax.broadcasted_iota(jnp.int32, (block, 1), 0))
-    key_at = lax.broadcasted_iota(jnp.int32, (block, t), 1)
-    key = jnp.where(key_at <= query_at, order_key(s_ref[...]), _INT_MIN)
-    tau, last = _select(key, query_at, key_at, top, lax.fori_loop)
+    i = pl.program_id(0)
+    query_at = (at_ref[0] + i * block
+                + lax.broadcasted_iota(i32, (block, 1), 0))
+    chunks = jnp.minimum((at_ref[0] + (i + 1) * block - 1) // lanes + 1,
+                         t // lanes)
+
+    def at(c):
+        return pl.ds(pl.multiple_of(c * lanes, lanes), lanes)
+
+    def key_at(c):
+        return c * lanes + lax.broadcasted_iota(i32, (1, lanes), 1)
+
+    def build(c, carry):
+        key_ref[:, at(c)] = jnp.where(key_at(c) <= query_at,
+                                      order_key(s_ref[:, at(c)]), _INT_MIN)
+        return carry
+
+    lax.fori_loop(0, chunks, build, 0)
+
+    def count(pred):
+        def add(c, acc):
+            hit = pred(key_ref[:, at(c)], key_at(c))
+            for k in range(0, lanes, fold):
+                acc = jnp.where(hit[:, k:k + fold], acc + 1, acc)
+            return acc
+
+        acc = lax.fori_loop(0, chunks, add, jnp.zeros((block, fold), i32))
+        # a count is below 2**24: exact in float32, whose sum across
+        # lanes is one reduction where int32's takes two
+        return jnp.sum(acc.astype(jnp.float32), axis=1,
+                       keepdims=True).astype(i32)
+
+    tau, last = _select(count, query_at, top, _width(t))
     o_ref[...] = jnp.concatenate([tau, last], axis=1)
 
 
-@functools.partial(jax.jit, donate_argnums=(),
-                   static_argnames=("top", "block", "interpret"))
+@functools.partial(jax.jit, donate_argnums=(), static_argnames=(
+    "top", "block", "lanes", "fold", "interpret"))
 def topk_select_kernel(scores, first, *, top: int,
-                       block: int = SELECT_QUERIES, interpret: bool = False):
+                       block: int = SELECT_QUERIES,
+                       lanes: int = SELECT_LANES, fold: int = SELECT_FOLD,
+                       interpret: bool = False):
     q, t = scores.shape
-    block = min(block, q)
-    if q % block:
-        raise ValueError(f"{q} queries in blocks of {block}")
+    block, lanes = min(block, q), min(lanes, t)
+    fold = min(fold, lanes)
+    if q % block or t % lanes or lanes % fold:
+        raise ValueError(f"{q} queries in blocks of {block}, {t} keys in "
+                         f"chunks of {lanes} counted {fold} lanes wide: no "
+                         "whole number of one of them")
     at = jnp.asarray(first, jnp.int32).reshape(1)
     return pl.pallas_call(
-        functools.partial(_select_kernel, top=top),
+        functools.partial(_select_kernel, top=top, lanes=lanes,
+                          fold=fold),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(q // block,),
             in_specs=[pl.BlockSpec((block, t), lambda i, at: (i, 0))],
-            out_specs=pl.BlockSpec((block, 2), lambda i, at: (i, 0))),
+            out_specs=pl.BlockSpec((block, 2), lambda i, at: (i, 0)),
+            scratch_shapes=[pltpu.VMEM((block, t), jnp.int32)]),
         out_shape=jax.ShapeDtypeStruct((q, 2), jnp.int32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",), vmem_limit_bytes=VMEM_LIMIT),

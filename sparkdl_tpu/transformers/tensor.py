@@ -103,20 +103,24 @@ def _count_diffusion(counts, metrics, tokens):
 
 
 def _count_sparse(counts, metrics, tokens):
-    """``sparse_counts`` ``[rows, layers, 3]`` (``latent_trunk.
+    """``sparse_counts`` ``[rows, layers, 4]`` (``latent_trunk.
     SPARSE_COUNTS``), summed over rows and layers: the causal pairs the
     indexer scored (``sparse.indexed_pairs``), the query-key pairs
-    attended (``sparse.selected_pairs``) and the key rows the attention
+    attended (``sparse.selected_pairs``), the key rows the attention
     read (``sparse.fetched_keys``: over the selected pairs, how much more
-    it read than it needed)."""
+    it read than it needed) and the queries whose selection took the tie
+    passes (``sparse.tied_queries``: ties at the boundary on both
+    sides)."""
     del tokens
-    indexed, chosen, fetched = (int(v) for v in
-                                counts.astype("int64").sum(axis=(0, 1)))
+    indexed, chosen, fetched, tied = (int(v) for v in
+                                      counts.astype("int64").sum(axis=(0, 1)))
     for name, value in (("sparse.indexed_pairs", indexed),
                         ("sparse.selected_pairs", chosen),
-                        ("sparse.fetched_keys", fetched)):
+                        ("sparse.fetched_keys", fetched),
+                        ("sparse.tied_queries", tied)):
         metrics.incr(name, value)
-    return {"selected_pairs": chosen, "fetched_keys": fetched}
+    return {"selected_pairs": chosen, "fetched_keys": fetched,
+            "tied_queries": tied}
 
 
 def _count_vision(counts, metrics, tokens):

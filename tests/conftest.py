@@ -78,3 +78,39 @@ def tiny_resnet(monkeypatch):
     ni._ENGINE_CACHE.clear()
     yield get_model_spec("ResNet50")
     ni._ENGINE_CACHE.clear()
+
+
+@pytest.fixture()
+def tied_by_hand(monkeypatch):
+    """The selection of ``ops/sparse_attention`` (``topk_select``, as the
+    trunks call it) with every call's scores and first position sent to
+    the host; the fixture's value counts, by hand over those, the
+    queries that leave out a key of the same order key as their last
+    chosen one (ties on both sides of the boundary): what the trunks'
+    ``sparse_counts`` column ``tied_queries`` sums."""
+    from sparkdl_tpu.ops import sparse_attention as sa
+
+    seen = []
+    real = sa.topk_select
+
+    def recorded(scores, first, top, **kw):
+        jax.debug.callback(
+            lambda s, f: seen.append((np.asarray(s), int(f), top)),
+            scores, first)
+        return real(scores, first, top, **kw)
+
+    monkeypatch.setattr(sa, "topk_select", recorded)
+
+    def count():
+        tied = 0
+        for scores, first, top in seen:
+            bits = scores.astype(np.float32).view(np.int32)
+            key = bits ^ ((bits >> 31) & 0x7FFFFFFF)   # -0.0 below 0.0
+            for row, t in enumerate(range(first, first + len(scores))):
+                causal = key[row, :t + 1]
+                need = min(t + 1, top)
+                boundary = np.sort(causal)[::-1][need - 1]
+                tied += int((causal >= boundary).sum() > need)
+        return tied
+
+    return count

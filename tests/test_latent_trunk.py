@@ -109,9 +109,11 @@ def test_one_program_stacks_every_weight_as_drawn(weights):
 # -- (a) the trunk is the reference ------------------------------------------
 
 @pytest.mark.parametrize("chunk", [T, 16])
-def test_float32_trunk_is_the_reference(weights, ids, want, chunk):
+def test_float32_trunk_is_the_reference(weights, ids, want, chunk,
+                                        tied_by_hand):
     """Float32 at 'highest' over the same numbers, the queries in one
-    chunk or four: the order of the sums alone differs."""
+    chunk or four: the order of the sums alone differs.  The queries
+    whose selection took the tie passes are those counted by hand."""
     got = lt.apply(program_variables(weights, jnp.float32), ids, TOY,
                    precision=HIGHEST, query_chunk=chunk, token_chunk=64)
     features, load = want
@@ -122,11 +124,12 @@ def test_float32_trunk_is_the_reference(weights, ids, want, chunk):
     assert got["expert_load"].shape == (3, 2, 8)
     np.testing.assert_array_equal(np.asarray(got["expert_load"]), load)
     counts = np.asarray(got["sparse_counts"])
-    assert counts.shape == (3, 3, 3)
+    assert counts.shape == (3, 3, 4)
     np.testing.assert_array_equal(counts[..., 0], lf.causal_pairs(T))
     np.testing.assert_array_equal(counts[..., 1], lf.selected_pairs(T, 8))
     # the jax.numpy form reads every key for every query
     np.testing.assert_array_equal(counts[..., 2], T * T)
+    assert counts[..., 3].sum() == tied_by_hand()
 
 
 def test_bfloat16_trunk_stays_in_its_band(weights, ids, want):
@@ -344,6 +347,7 @@ def test_model_function_through_model_transformer(weights, ids, want):
     assert counters["sparse.selected_pairs"] == \
         3 * layers * lf.selected_pairs(T, 8)
     assert counters["sparse.fetched_keys"] == 3 * layers * T * T
+    assert 0 <= counters["sparse.tied_queries"] <= 3 * layers * T
 
 
 def test_the_count_of_operations_by_hand():

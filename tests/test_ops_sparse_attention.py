@@ -77,25 +77,90 @@ def test_the_indexer_kernel_is_the_jax_numpy_form(first):
     assert np.all(np.asarray(got)[:8, 32:] == 0) if first == 0 else True
 
 
+def _tied_by_hand(scores, first=FIRST, top=TOP):
+    """``[Q]`` bool: the queries that leave out a key of the same score
+    (the same float bits) as the last of ``lax.top_k``'s ``min(t + 1,
+    top)`` — ties on both sides of the boundary."""
+    query_at, key_at = _positions(first, scores.shape[0])
+    causal = key_at <= query_at
+    _, chosen = lax.top_k(jnp.where(causal, scores, -jnp.inf), top)
+    bits = np.asarray(scores, np.float32).view(np.int32)
+    tied = np.zeros(scores.shape[0], bool)
+    for row in range(scores.shape[0]):
+        n = min(int(query_at[row, 0]) + 1, top)
+        taken = np.zeros(scores.shape[1], bool)
+        taken[np.asarray(chosen[row, :n])] = True
+        boundary = bits[row, int(chosen[row, n - 1])]
+        tied[row] = np.any(causal[row] & ~taken & (bits[row] == boundary))
+    return tied
+
+
+@pytest.mark.parametrize("first, top, lanes, fold, quantum", [
+    (0, TOP, 16, 16, None),     # the first chunk: prefixes end inside one
+    (FIRST, TOP, 16, 8, None),  # a middle chunk of queries
+    (32, TOP, 16, 16, None),    # the last: the prefix reaches the row's end
+    (12, TOP, 16, 16, None),    # a block's queries on both sides of a chunk
+    (FIRST, TOP, 32, 8, None),  # prefixes of 24 and 40 keys, chunks of 32
+    (FIRST, TOP, T, 16, None),  # the whole row one chunk
+    (FIRST, T, 16, 16, None),   # every block below top: all its keys
+    (0, TOP, 16, 16, 0.5),      # halves: two blocks need the tie passes
+    (FIRST, TOP, 32, 8, 0.5),
+    (FIRST, TOP, 16, 16, 4.0),  # fours: ties in every block
+])
+def test_the_selection_is_top_k_exactly_ties_included(first, top, lanes,
+                                                      fold, quantum):
+    """Scores as drawn (zeros among them: every head's ReLU at 0), and
+    rounded to halves and to fours so that many ties straddle the
+    boundary (zeros of both signs among them): the same keys as
+    ``lax.top_k``, the lower position first among equals, never a key
+    after the query, ``min(t + 1, top)`` of them; the kernel, counting
+    over each block's causal prefix ``lanes`` keys at a time, is the
+    plain form bit for bit (its partial counts ``fold`` lanes wide)."""
+    q, k, w = _index_operands(2)
+    scores = sa.indexer_scores(q, k, w, first, force=False)
+    if quantum is not None:
+        scores = jnp.round(scores / quantum) * quantum
+    sel = sa.topk_select(scores, first, top, force=False)
+    mask = _mask(scores, sel, first)
+    np.testing.assert_array_equal(mask, _top_k_mask(scores, first, top))
+    query_at, _ = _positions(first)
+    np.testing.assert_array_equal(mask.sum(1),
+                                  np.minimum(query_at[:, 0] + 1, top))
+    kernel = sa.topk_select_kernel(scores, first, top=top, block=8,
+                                   lanes=lanes, fold=fold, interpret=True)
+    np.testing.assert_array_equal(np.asarray(kernel), np.asarray(sel))
+    # ``last`` is the query's own position wherever no tie straddles
+    tied = _tied_by_hand(scores, first, top)
+    np.testing.assert_array_equal(np.asarray(sel[:, 1]) < query_at[:, 0],
+                                  tied)
+    if (first, quantum) == (0, 0.5):
+        by_block = tied.reshape(-1, 8).any(1)
+        assert by_block.any() and not by_block.all(), by_block
+
+
 @pytest.mark.parametrize("quantum", [None, 0.5, 4.0])
-def test_the_selection_is_top_k_exactly_ties_included(quantum):
-    """Scores as drawn, and rounded to halves and to fours so that many
-    ties straddle the boundary (zeros of both signs among them): the
-    same keys as ``lax.top_k``, the lower position first among equals,
-    never a key after the query, ``min(t + 1, top)`` of them."""
+@pytest.mark.parametrize("force", [False, "interpret"])
+def test_the_count_of_tied_queries_is_the_hand_count(force, quantum):
+    """``tied_queries`` — what ``sparse.tied_queries`` sums — against the
+    queries with ties on both sides of ``lax.top_k``'s last key, on the
+    plain path and the kernel's."""
     q, k, w = _index_operands(2)
     scores = sa.indexer_scores(q, k, w, FIRST, force=False)
     if quantum is not None:
         scores = jnp.round(scores / quantum) * quantum
-    sel = sa.topk_select(scores, FIRST, TOP, force=False)
-    mask = _mask(scores, sel)
-    np.testing.assert_array_equal(mask, _top_k_mask(scores))
-    query_at, _ = _positions()
-    np.testing.assert_array_equal(mask.sum(1),
-                                  np.minimum(query_at[:, 0] + 1, TOP))
-    kernel = sa.topk_select_kernel(scores, FIRST, top=TOP, block=8,
-                                   interpret=True)
-    np.testing.assert_array_equal(np.asarray(kernel), np.asarray(sel))
+    sel = sa.topk_select(scores, FIRST, TOP, force=force)
+    want = int(_tied_by_hand(scores).sum())
+    assert int(sa.tied_queries(sel, FIRST)) == want
+    if quantum is not None:
+        assert want > 0
+
+
+@pytest.mark.parametrize("lanes, fold", [(24, 8), (32, 12)])
+def test_chunks_must_divide_the_row_and_folds_the_chunk(lanes, fold):
+    scores = jnp.zeros((Q, T))
+    with pytest.raises(ValueError, match=f"chunks of {lanes} counted {fold}"):
+        sa.topk_select_kernel(scores, 0, top=TOP, lanes=lanes, fold=fold,
+                              interpret=True)
 
 
 def test_the_selection_with_every_score_equal_takes_the_first_keys():

@@ -201,17 +201,20 @@ def test_the_nth_placeholder_takes_the_nth_token():
 
 @pytest.mark.parametrize("query_chunk", [32, 8])
 def test_float32_trunk_is_the_reference(program_weights, rows, want,
-                                        query_chunk):
+                                        query_chunk, tied_by_hand):
     """Float32 at 'highest' over the same numbers, the queries in one
-    chunk or four: the order of the sums alone differs."""
+    chunk or four: the order of the sums alone differs.  The queries
+    whose selection took the tie passes are those counted by hand."""
     got = vl_trunk.apply(as_float32(program_weights), *rows, TOY,
                          precision=HIGHEST, query_chunk=query_chunk)
     assert got["features"].shape == (3, 32)
     assert gap(got["features"], want) < 1e-5
     t = TOY["sequence_length"]
+    counts = np.asarray(got["sparse_counts"])
+    assert counts.shape == (3, 2, 4)
     np.testing.assert_array_equal(
-        np.asarray(got["sparse_counts"])[0, 0],
-        [t * (t + 1) // 2, 1 + 2 + 3 + 4 * (t - 3), t * t])
+        counts[0, 0, :3], [t * (t + 1) // 2, 1 + 2 + 3 + 4 * (t - 3), t * t])
+    assert counts[..., 3].sum() == tied_by_hand()
     np.testing.assert_array_equal(np.asarray(got["vision_counts"]),
                                   [[FRAMES, FRAMES * 16, FRAMES * 4]] * 3)
 
@@ -246,6 +249,7 @@ def test_the_program_through_tftransformer(program_weights, rows, want):
     assert counters["vision.patches"] == 3 * FRAMES * 16
     assert counters["vision.spliced_tokens"] == 3 * FRAMES * 4
     assert counters["sparse.selected_pairs"] == 3 * 2 * 122
+    assert 0 <= counters["sparse.tied_queries"] <= 3 * 2 * 32
     assert "moe.tokens" not in counters
 
 
