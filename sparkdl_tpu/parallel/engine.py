@@ -6,14 +6,19 @@ Here instead: ONE jit-compiled XLA program per (model, batch-shape,
 sharding policy), params resident on device (replicated via NamedSharding
 — the broadcast analog — or tensor-parallel-sharded across the mesh's
 ``model`` axis via partition rules, ISSUE 14), batch rows sharded over
-the mesh's data axis, and a fixed padded batch shape so XLA never
-recompiles (SURVEY.md §7 hard part #4).
+the mesh's data axis, and a fixed plan of padded batch shapes so XLA
+never recompiles (SURVEY.md §7 hard part #4).
 
 Throughput design:
-  * fixed ``device_batch_size`` (rounded up to a multiple of the data-axis
-    size) — one compile, reused forever;
-  * the tail batch is zero-padded and trimmed on the host after gather, so
-    ragged input never poisons shapes;
+  * a fixed plan of batch shapes (:func:`batch_buckets`: a quarter, a half
+    and the whole ``device_batch_size``, each rounded up to a multiple of
+    the data-axis size) — each compiled once, at its first dispatch, and
+    reused forever;
+  * a chunk is cut into pieces of ONE bucket, the one that dispatches the
+    fewest rows (a dispatch counted as one row more), and its last piece
+    is zero-padded and trimmed on the host after gather, so ragged input
+    never poisons shapes and a tail does not pay for a whole batch of
+    rows;
   * dispatch is async with a bounded in-flight window (double buffering):
     the next batch's host->device transfer overlaps the current batch's
     compute, while device residency stays O(window x batch) regardless of
@@ -27,7 +32,8 @@ Throughput design:
 from __future__ import annotations
 
 import time as time_lib
-from typing import Any, Callable, Dict, Iterable, Iterator, Optional
+from typing import (Any, Callable, Dict, Iterable, Iterator, List, Optional,
+                    Sequence)
 
 import numpy as np
 
@@ -211,6 +217,24 @@ from sparkdl_tpu.utils.cache import BoundedCache
 _JIT_CACHE = BoundedCache(cap=32)
 
 
+class _Programs:
+    """A dispatch jit and the executables compiled from it: per weights
+    signature (tree, shapes, dtypes, shardings), a map from batch
+    signature to ``jit.lower(...).compile()``.  ``_JIT_CACHE`` holds one
+    per jit, so every engine that shares the jit dispatches the same
+    executable for the same shapes, and a program compiled ahead by one
+    engine is never traced again by another."""
+
+    def __init__(self, jit):
+        self.jit = jit
+        self.lock = named_lock("engine.programs")
+        self._by_weights: Dict[tuple, Dict[tuple, Any]] = {}
+
+    def executables(self, weights_signature: tuple) -> Dict[tuple, Any]:
+        with self.lock:
+            return self._by_weights.setdefault(weights_signature, {})
+
+
 def clear_engine_jit_cache() -> None:
     _JIT_CACHE.clear()
 
@@ -253,6 +277,33 @@ def effective_device_batch(device_batch_size: int, mesh) -> int:
     b = max(1, int(device_batch_size))
     rem = b % dp
     return b + (dp - rem) if rem else b
+
+
+def batch_buckets(max_batch_size: int, mesh,
+                  bucket_sizes: Optional[Sequence[int]] = None) -> List[int]:
+    """THE plan of compiled batch shapes for dispatches of up to
+    ``max_batch_size`` rows: ``bucket_sizes`` (by default a quarter, a
+    half and the whole batch — three shapes cover a light tail, a half
+    one and a full batch without one program per row count), validated,
+    each rounded up by :func:`effective_device_batch`, and de-duplicated
+    (two sizes that round alike are one program).  An
+    :class:`InferenceEngine` dispatches each chunk in one bucket of its
+    plan; ``serving.server.bucket_plan`` is this function, so the serving
+    path and the batch path cannot drift apart.  For example, on
+    one chip 8 gives [2, 4, 8], 1024 [256, 512, 1024], 2 [1, 2], 1 [1]."""
+    max_batch_size = max(1, int(max_batch_size))
+    if bucket_sizes is None:
+        buckets = [max(1, max_batch_size // 4), max(1, max_batch_size // 2),
+                   max_batch_size]
+    else:
+        buckets = [int(b) for b in bucket_sizes]
+    if not buckets or min(buckets) < 1:
+        raise ValueError(f"bucket_sizes must be positive, got {buckets}")
+    if max(buckets) < max_batch_size:
+        raise ValueError(
+            f"largest bucket ({max(buckets)}) must cover "
+            f"max_batch_size ({max_batch_size})")
+    return sorted({effective_device_batch(b, mesh) for b in buckets})
 
 
 def build_dispatch_jit(fn: Callable, mesh, donate_batch: bool,
@@ -726,6 +777,22 @@ class InferenceEngine:
     ``fn`` must be jit-traceable with a leading batch axis on ``batch`` and
     on every output leaf (outputs may be a single array or a pytree).
 
+    Batch shapes: the engine's plan ``buckets`` is :func:`batch_buckets`
+    of its ``device_batch_size`` and mesh (``bucket_sizes`` as that
+    function takes it; the serving tier gives each of its bucket engines
+    its one bucket).  Each input chunk is dispatched in ONE bucket
+    (``_piece_rows``): cut into pieces of it, the last padded to it, so a
+    chunk's dispatches are all one program.  Every dispatch goes through
+    an executable of ``jit.lower(...).compile()``, kept per signature in
+    a map every engine of the same jit and weights shares.  A bucket's
+    program compiles at its first dispatch, never before: an engine only
+    ever sent whole batches compiles one program.  At the first dispatch
+    of a bucket smaller than the whole batch, the whole batch's program
+    is compiled too (not run), so a warm call of a tail's size readies
+    both programs a stream of whole batches and that tail dispatches.
+    Chunks whose size changes from call to call compile each new bucket
+    once: at most two programs beyond the whole batch's.
+
     Weight sharding (ISSUE 14): ``partition_rules`` (a ``(regex,
     PartitionSpec)`` rule list or a ``mesh -> rules`` factory — see
     ``mesh.match_partition_rules`` / ``mesh.default_partition_rules``)
@@ -744,6 +811,7 @@ class InferenceEngine:
     def __init__(self, fn: Callable, variables: Any, *,
                  mesh=None,
                  device_batch_size: int = 64,
+                 bucket_sizes: Optional[Sequence[int]] = None,
                  compute_dtype: Optional[Any] = None,
                  output_host_dtype: Optional[Any] = None,
                  donate_batch: bool = False,
@@ -774,6 +842,7 @@ class InferenceEngine:
             logger.info("device_batch_size rounded up to %d (multiple of "
                         "%d-way data axis)", b, self.data_parallel)
         self.device_batch_size = b
+        self.buckets = batch_buckets(b, self.mesh, bucket_sizes)
         self.metrics = metrics if metrics is not None else Metrics()
         # Fetch device outputs in their compute dtype and cast on the HOST:
         # a bf16 model result upcast to f32 on device carries no extra
@@ -888,13 +957,21 @@ class InferenceEngine:
                     tuple(self.mesh.devices.shape), bool(donate_batch),
                     self.sharding_digest)
         key = (id(fn),) + mesh_key
-        compiled = _JIT_CACHE.get(key)
-        jit_cached = compiled is not None
+        programs = _JIT_CACHE.get(key)
+        jit_cached = programs is not None
         if not jit_cached:
-            compiled = build_dispatch_jit(fn, self.mesh, donate_batch,
-                                          param_shardings=self.param_shardings)
-            _JIT_CACHE.put(key, compiled)
-        self._compiled = compiled
+            programs = _Programs(build_dispatch_jit(
+                fn, self.mesh, donate_batch,
+                param_shardings=self.param_shardings))
+            _JIT_CACHE.put(key, programs)
+        self._compiled = programs.jit
+        import jax
+
+        leaves, tree = jax.tree_util.tree_flatten(self.variables)
+        self._executables = programs.executables((tree,) + tuple(
+            (a.shape, a.dtype, getattr(a, "sharding", None))
+            for a in leaves))
+        self._programs_lock = programs.lock
         return jit_cached
 
     # -- low level ---------------------------------------------------------
@@ -1044,26 +1121,77 @@ class InferenceEngine:
 
     def run_padded(self, batch):
         """Run one already-padded device batch (array or pytree of arrays
-        sharing the leading batch axis); returns device output(s)."""
-        import jax
-
-        if self._leaves(batch) != self.device_batch_size:
+        sharing the leading batch axis) whose rows are one of the
+        engine's ``buckets``; returns device output(s)."""
+        rows = self._leaves(batch)
+        if rows not in self.buckets:
             raise ValueError(
-                f"run_padded expects batch of {self.device_batch_size}, "
-                f"got {self._leaves(batch)}")
+                f"run_padded expects a batch of one of {self.buckets} "
+                f"rows, got {rows}")
+        if rows < self.device_batch_size:
+            self.metrics.incr("engine.bucket_dispatches")
 
         # span covers H2D + async launch only (the call returns as soon
         # as the dispatch is enqueued); the device wait is bracketed by
         # whichever stage forces the result (pipeline.gather / _trim)
         def attempt():
-            with get_tracer().span("engine.dispatch",
-                                   rows=self.device_batch_size):
+            with get_tracer().span("engine.dispatch", rows=rows):
                 x = self._h2d(batch, self._batch_sharding)
-                return self._compiled(self.variables, x)
+                return self._executable(x)(self.variables, x)
 
         return self._run_dispatch(attempt)
 
-    def _pad(self, chunk):
+    def _executable(self, x):
+        """The executable that dispatches the device batch ``x``, from
+        the map every engine of this jit and these weights shares.  At
+        the first dispatch of a signature (tree, shapes, dtypes) it is
+        lowered and compiled here, inside the caller's
+        ``engine.dispatch`` span, which parents its compile spans.  Where
+        that is a bucket below the whole batch, the whole batch's program
+        (same trailing shapes and dtypes) is compiled too, without
+        running it: a warm call of a tail's size readies both programs a
+        job of whole batches and that tail dispatches."""
+        import jax
+
+        leaves, tree = jax.tree_util.tree_flatten(x)
+
+        def signature(rows):
+            return (tree,) + tuple(((rows,) + a.shape[1:], a.dtype)
+                                   for a in leaves)
+
+        key = signature(leaves[0].shape[0])
+        exe = self._executables.get(key)
+        if exe is not None:
+            return exe
+        with self._programs_lock:
+            if key not in self._executables:
+                self._executables[key] = self._compiled.lower(
+                    self.variables, x).compile()
+            full = signature(self.device_batch_size)
+            if full not in self._executables:
+                spec = jax.tree_util.tree_unflatten(tree, [
+                    jax.ShapeDtypeStruct(shape, dtype,
+                                         sharding=self._batch_sharding)
+                    for shape, dtype in full[1:]])
+                self._executables[full] = self._compiled.lower(
+                    self.variables, spec).compile()
+            return self._executables[key]
+
+    def _piece_rows(self, n: int) -> int:
+        """The one bucket a chunk of ``n`` rows is cut into and every
+        piece of it padded to: the bucket that dispatches the fewest
+        rows, padding included, with each dispatch counted as one row
+        more — so a smaller shape is taken only where the padding it
+        saves outweighs the dispatches it adds; ties go to the larger
+        bucket.  With the plan [2, 4, 8], 20 rows run as five pieces of
+        4 (25 against 27 for 8 + 8 + 4-padded-to-8), 4 rows as one piece
+        of 4, 13 as 8 and 5 padded to 8; a chunk of whole batches stays
+        whole."""
+        return min(reversed(self.buckets), key=lambda b: -(-n // b) * (b + 1))
+
+    def _pad(self, chunk, bucket: int):
+        """``chunk`` padded with zero rows to ``bucket`` rows; a chunk
+        that fills it is returned as it is, not copied."""
         import jax
 
         n = self._leaves(chunk)
@@ -1073,9 +1201,9 @@ class InferenceEngine:
         # (engine.rows + engine.pad_rows)`) and bench lines can stamp
         # it next to the lockfile's analytic bounds
         self.metrics.incr("engine.rows", n)
-        if n == self.device_batch_size:
+        if n == bucket:
             return chunk
-        pad_rows = self.device_batch_size - n
+        pad_rows = bucket - n
         self.metrics.incr("engine.pad_rows", pad_rows)
 
         def pad_leaf(a):
@@ -1088,7 +1216,10 @@ class InferenceEngine:
         import jax
 
         def gather(a):
-            host = np.asarray(a[:n])
+            # fetched whole and cut on the host: slicing on the device
+            # would compile a program for each (bucket, rows) pair, and
+            # a tail's first one would compile inside a measured run
+            host = np.asarray(a)[:n]
             # cast float->float only: integer/bool leaves (e.g. argmax
             # ids) must never be silently floated.  ml_dtypes narrow
             # floats (bf16/f8) register as kind 'V', not np.floating —
@@ -1124,14 +1255,14 @@ class InferenceEngine:
         each observe their own span.
 
         Host-memory contract: the pipelined path (the default)
-        PREALLOCATES the output — the one compiled program fixes the leaf
-        shapes, so after the first gathered chunk the ``[n, ...]`` result
-        is allocated once and every later chunk is copied into it.  Peak
-        host residency is the output plus O(window + depth) chunks
-        (``pipeline=False`` concatenates a per-chunk list, which
-        transiently doubles the output).  Either way the OUTPUT
-        materializes in host RAM — route multi-million-row frames through
-        ``map_batches`` instead.
+        PREALLOCATES the output — the one per-batch program fixes the
+        leaves' trailing shapes, so after the first gathered chunk the
+        ``[n, ...]`` result is allocated once and every later chunk is
+        copied into it.  Peak host residency is the output plus
+        O(window + depth) chunks (``pipeline=False`` concatenates a
+        per-chunk list, which transiently doubles the output).  Either
+        way the OUTPUT materializes in host RAM — route multi-million-row
+        frames through ``map_batches`` instead.
 
         Chunks run through ``map_batches``' bounded in-flight window, so
         device residency is O(window x device_batch) for any input, and
@@ -1162,7 +1293,7 @@ class InferenceEngine:
                     k = self._leaves(part)
                     if out is None:
                         # leaf trailing shapes are fixed by the one
-                        # compiled program: preallocate [n, ...] per leaf
+                        # per-batch program: preallocate [n, ...] per leaf
                         # and stream chunks straight in
                         out = jax.tree_util.tree_map(
                             lambda a: np.empty((n,) + a.shape[1:], a.dtype),
@@ -1203,16 +1334,18 @@ class InferenceEngine:
 
     def _iter_pieces(self, batches: Iterable[Any]) -> Iterator[tuple]:
         """THE host-prepare sequence of both the calling-thread path and
-        the runner's prepare stage: slice chunks into device-batch pieces
-        and pad them; yields ``(n_rows, padded_piece)`` in dispatch order."""
+        the runner's prepare stage: cut each chunk into pieces of its one
+        bucket (``_piece_rows``) and pad the last; yields ``(n_rows,
+        padded_piece)`` in dispatch order."""
         import jax
 
         for chunk in batches:
             chunk = jax.tree_util.tree_map(np.asarray, chunk)
             n = self._leaves(chunk)
-            for off in range(0, n, self.device_batch_size):
-                piece = self._slice(chunk, off, self.device_batch_size)
-                yield self._leaves(piece), self._pad(piece)
+            bucket = self._piece_rows(n)
+            for off in range(0, n, bucket):
+                piece = self._slice(chunk, off, bucket)
+                yield self._leaves(piece), self._pad(piece, bucket)
 
     def _map_batches_serial(self, batches: Iterable[Any],
                             window: int = 2) -> Iterator[Any]:

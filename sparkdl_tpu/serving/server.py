@@ -91,13 +91,6 @@ def _resolve_model(model, variables, featurize: bool):
                     f"fn(variables, batch)")
 
 
-def _default_buckets(max_batch_size: int) -> List[int]:
-    """Quarter / half / full batch — three compiled shapes cover light,
-    medium, and saturated traffic without per-count recompiles."""
-    b = max(1, int(max_batch_size))
-    return sorted({max(1, b // 4), max(1, b // 2), b})
-
-
 def bucket_plan(max_batch_size: int,
                 bucket_sizes: Optional[Sequence[int]] = None,
                 mesh=None) -> List[int]:
@@ -108,22 +101,14 @@ def bucket_plan(max_batch_size: int,
     batch were two engine objects compiling ONE shape.  This is the
     enumeration hook ``analysis.program`` walks to audit every serving
     program chip-free; the server itself builds its engines from the
-    same plan so the audited set cannot drift from the served set."""
-    from sparkdl_tpu.parallel.engine import (effective_device_batch,
+    same plan so the audited set cannot drift from the served set.  The
+    plan is the engine's own (``parallel.engine.batch_buckets``), so the
+    batch path pads its tails to the same shapes."""
+    from sparkdl_tpu.parallel.engine import (batch_buckets,
                                              resolve_engine_mesh)
 
-    max_batch_size = max(1, int(max_batch_size))
-    buckets = (list(bucket_sizes) if bucket_sizes is not None
-               else _default_buckets(max_batch_size))
-    if not buckets or any(int(b) < 1 for b in buckets):
-        raise ValueError(f"bucket_sizes must be positive, got {buckets}")
-    buckets = sorted(int(b) for b in buckets)
-    if buckets[-1] < max_batch_size:
-        raise ValueError(
-            f"largest bucket ({buckets[-1]}) must cover "
-            f"max_batch_size ({max_batch_size})")
-    mesh = resolve_engine_mesh(mesh)
-    return sorted({effective_device_batch(b, mesh) for b in buckets})
+    return batch_buckets(max_batch_size, resolve_engine_mesh(mesh),
+                         bucket_sizes)
 
 
 class _Once:
@@ -526,6 +511,10 @@ class Server:
                     else self._host_variables,
                     mesh=first.mesh if first is not None else self._mesh,
                     device_batch_size=bucket,
+                    # one shape an engine: the micro-batch was already
+                    # cut to this bucket, and only the server's plan is
+                    # audited and warmed
+                    bucket_sizes=[bucket],
                     compute_dtype=(None if first is not None
                                    else self._compute_dtype),
                     output_host_dtype=self._output_host_dtype,
@@ -1176,23 +1165,19 @@ class Server:
 
     def executable_state(self) -> Dict[int, Dict[str, Any]]:
         """Per-bucket compiled-program identity: the ``id()`` of the
-        bucket engine's shared ``jax.jit`` object and that object's
-        executable-cache size.  Two servers over the SAME fn (a fleet
-        entry's v1 and v2) report equal ``jit_id`` per bucket, and a
-        hot-swap that truly reuses the compiled executable leaves
-        ``executables`` unchanged — the no-recompile proof
-        ``serving.fleet.rollout`` asserts at promote time."""
+        bucket engine's shared ``jax.jit`` object and the number of
+        executables compiled from it for the engine's weights signature
+        (the map every engine of that jit and signature dispatches
+        from).  Two servers over the SAME fn (a fleet entry's v1 and v2)
+        report equal ``jit_id`` per bucket, and a hot-swap that truly
+        reuses the compiled executable leaves ``executables`` unchanged
+        — the no-recompile proof ``serving.fleet.rollout`` asserts at
+        promote time."""
         with self._engine_lock:
             engines = dict(self._engines)
-        out: Dict[int, Dict[str, Any]] = {}
-        for b, eng in sorted(engines.items()):
-            compiled = eng._compiled
-            try:
-                n_exec = int(compiled._cache_size())
-            except (AttributeError, TypeError):  # older jax: identity only
-                n_exec = None
-            out[b] = {"jit_id": id(compiled), "executables": n_exec}
-        return out
+        return {b: {"jit_id": id(eng._compiled),
+                    "executables": len(eng._executables)}
+                for b, eng in sorted(engines.items())}
 
     def stats(self) -> Dict[str, float]:
         """Snapshot of the serving metrics (counters, gauges, latency

@@ -68,11 +68,15 @@ def test_engine_rounds_batch_to_data_axis(setup):
 
 def test_engine_ragged_tail_is_trimmed(setup):
     variables, x, ref = setup
-    # 45 rows, batch 32 -> chunks of 32 and 13 (padded to 32, trimmed)
+    # 45 rows, batch 32 on the 8-way data axis (buckets 8, 16, 32) ->
+    # the chunk runs in 16s: 16, 16 and 13 (padded to 16, trimmed)
     eng = InferenceEngine(_fn, variables, device_batch_size=32)
+    assert eng.buckets == [8, 16, 32]
     out = eng(x)
     assert out.shape[0] == 45
     np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6)
+    assert eng.metrics.counters["engine.rows"] == 45
+    assert eng.metrics.counters["engine.pad_rows"] == 16 - 13
 
 
 def test_engine_streaming_window(setup):
@@ -307,3 +311,160 @@ def test_placed_variables_are_taken_not_copied():
             placed.sharding, 1)
     x = np.arange(12, dtype=np.float32).reshape(3, 4)
     np.testing.assert_allclose(eng(x), x + 1.0)
+
+
+# -- the bucket plan: a short piece runs in the smallest bucket that holds it
+
+def _one_chip():
+    return get_mesh(num_devices=1)
+
+
+@pytest.mark.parametrize("batch,data_axis,want", [
+    (8, 1, [2, 4, 8]), (1024, 1, [256, 512, 1024]), (2, 1, [1, 2]),
+    (1, 1, [1]), (32, 8, [8, 16, 32]), (8, 8, [8]), (6, 4, [4, 8])])
+def test_batch_buckets_is_the_plan_of_both_paths(setup, batch, data_axis,
+                                                 want):
+    """Quarter, half and whole batch, each rounded up to the data axis:
+    the engine's plan, and the serving plan is the same function."""
+    from sparkdl_tpu.parallel.engine import batch_buckets
+    from sparkdl_tpu.serving import bucket_plan
+
+    variables, _, _ = setup
+    mesh = get_mesh(num_devices=data_axis)
+    assert batch_buckets(batch, mesh) == want
+    assert bucket_plan(batch, mesh=mesh) == want
+    eng = InferenceEngine(_fn, variables, device_batch_size=batch, mesh=mesh)
+    assert eng.buckets == want
+
+
+@pytest.mark.parametrize("n,bucket", [(1, 2), (2, 2), (3, 4), (4, 4),
+                                      (5, 8), (7, 8), (8, 8)])
+def test_a_piece_pads_to_the_smallest_bucket_that_holds_it(setup, n,
+                                                           bucket):
+    """A chunk of at most a whole batch runs in the smallest bucket that
+    holds it; ``_pad`` pads it there (a piece that fills its bucket is the
+    caller's array, not a copy), and the real rows equal those of the same
+    rows padded to the whole batch."""
+    variables, x, _ = setup
+    eng = InferenceEngine(_fn, variables, device_batch_size=8,
+                          mesh=_one_chip())
+    assert eng._piece_rows(n) == bucket
+    piece = x[:n]
+    padded = eng._pad(piece, bucket)
+    assert padded.shape == (bucket, 12)
+    assert not padded[n:].any()
+    if n == bucket:
+        assert padded is piece
+    got = eng._force_part(n, eng.run_padded(padded))
+    whole = np.pad(piece, [(0, 8 - n), (0, 0)])
+    want = eng._force_part(n, eng.run_padded(whole))
+    assert got.shape == (n, 5)
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("batch,n,bucket", [
+    (8, 20, 4),      # 5 x 4 (25) against 8 + 8 + 4-padded-to-8 (27)
+    (8, 19, 4),      # 4 x 4 + 3 padded to 4
+    (8, 13, 8),      # 8 + 5 padded to 8 (18) against 4 x 4 (20)
+    (8, 24, 8),      # whole batches stay whole
+    (8, 4, 4), (1024, 512, 512), (1024, 1536, 512), (1024, 2048, 1024),
+    (4, 10, 4),      # 4 + 4 + 2-padded-to-4 (15) ties 5 x 2: the larger
+    (64, 6432, 64),  # 32 rows of padding do not pay 100 more dispatches
+    (2, 1, 1), (2, 5, 2), (1, 3, 1)])
+def test_a_chunk_runs_in_one_bucket(setup, batch, n, bucket):
+    """``_piece_rows``: the bucket whose pieces dispatch the fewest rows,
+    a dispatch counted as one row; ties to the larger bucket."""
+    variables, _, _ = setup
+    eng = InferenceEngine(_fn, variables, device_batch_size=batch,
+                          mesh=_one_chip())
+    assert eng._piece_rows(n) == bucket
+
+
+def test_a_chunks_pieces_are_one_program(setup):
+    """20 rows at batch 8 go out as five pieces of 4, each the 4-row
+    program, the last one full: no row of padding."""
+    variables, x, ref = setup
+    eng = InferenceEngine(_fn, variables, device_batch_size=8,
+                          mesh=_one_chip())
+    pieces = list(eng._iter_pieces(iter([x[:20]])))
+    assert [n for n, _ in pieces] == [4] * 5
+    assert {p.shape for _, p in pieces} == {(4, 12)}
+    assert eng.metrics.counters.get("engine.pad_rows", 0) == 0
+
+
+def test_run_padded_refuses_a_row_count_outside_the_plan(setup):
+    variables, x, _ = setup
+    eng = InferenceEngine(_fn, variables, device_batch_size=8,
+                          mesh=_one_chip())
+    with pytest.raises(ValueError, match=r"one of \[2, 4, 8\]"):
+        eng.run_padded(x[:3])
+
+
+def _compile_seconds():
+    from sparkdl_tpu.parallel import compile_cache
+
+    st = compile_cache.stats()
+    return st["trace_s"] + st["lower_s"] + st["backend_s"]
+
+
+def test_a_warm_tail_readies_the_whole_batch_without_running_it(setup):
+    """A warm call of a tail's size compiles the tail's bucket AND the
+    whole batch's program (compiled ahead, never run): the calls of that
+    tail and of whole batches that follow compile nothing at all, in this
+    engine or in a second one that shares its jit and weights."""
+    variables, x, ref = setup
+    fn = lambda v, b: _fn(v, b)  # noqa: E731 (a jit of its own)
+    eng = InferenceEngine(fn, variables, device_batch_size=8,
+                          mesh=_one_chip())
+    dispatched = []
+    run = eng.run_padded
+    eng.run_padded = lambda b: (dispatched.append(len(b)), run(b))[1]
+    np.testing.assert_allclose(eng(x[:4]), ref[:4], rtol=1e-5, atol=1e-6)
+    assert dispatched == [4]                  # the whole batch never ran
+    assert sorted(k[1][0][0] for k in eng._executables) == [4, 8]
+    before = _compile_seconds()
+    np.testing.assert_allclose(eng(x[:20]), ref[:20], rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(eng(x[:24]), ref[:24], rtol=1e-5, atol=1e-6)
+    assert dispatched == [4] * 6 + [8] * 3
+    other = InferenceEngine(fn, variables, device_batch_size=8,
+                            mesh=_one_chip())
+    np.testing.assert_allclose(other(x[:24]), ref[:24], rtol=1e-5,
+                               atol=1e-6)
+    assert _compile_seconds() == before
+    assert other._executables is eng._executables
+    assert eng._compiled._cache_size() == 0   # every dispatch is an executable
+
+
+def test_an_engine_sent_whole_batches_compiles_one_program(setup):
+    """No bucket compiles before it is sent: whole pieces only -> one
+    program, nothing compiled ahead."""
+    variables, x, ref = setup
+    eng = InferenceEngine(lambda v, b: _fn(v, b), variables,
+                          device_batch_size=8, mesh=_one_chip())
+    np.testing.assert_allclose(eng(x[:16]), ref[:16], rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(eng(x[16:40]), ref[16:40], rtol=1e-5,
+                               atol=1e-6)
+    assert len(eng._executables) == 1
+    assert eng.metrics.counters.get("engine.bucket_dispatches", 0) == 0
+
+
+@pytest.mark.parametrize("rows,pad_rows,bucket_dispatches", [
+    (20, 0, 5),     # 5 x 4
+    (19, 1, 5),     # 4 x 4 + 3 padded to 4
+    (17, 3, 5),     # 4 x 4 + 1 padded to 4
+    (13, 3, 0),     # 8 + 5 padded to 8: the whole batch
+    (3, 1, 1),      # 3 padded to 4
+    (24, 0, 0)])
+def test_bucket_counters(setup, rows, pad_rows, bucket_dispatches):
+    """``engine.rows`` counts real rows, ``engine.pad_rows`` bucket − rows,
+    ``engine.bucket_dispatches`` the dispatches of a bucket smaller than
+    the whole batch."""
+    variables, x, ref = setup
+    eng = InferenceEngine(_fn, variables, device_batch_size=8,
+                          mesh=_one_chip())
+    np.testing.assert_allclose(eng(x[:rows]), ref[:rows], rtol=1e-5,
+                               atol=1e-6)
+    counters = eng.metrics.counters
+    assert counters["engine.rows"] == rows
+    assert counters.get("engine.pad_rows", 0) == pad_rows
+    assert counters.get("engine.bucket_dispatches", 0) == bucket_dispatches

@@ -59,15 +59,15 @@ def _engine_is_the_defaults(monkeypatch, default_jaxpr):
 
     eng = get_cached_engine(Holder(), mf, device_batch_size=8)
     runners, launches = [], []
-    runner, program = engine_mod.PipelinedRunner, eng._compiled
+    runner, program = engine_mod.PipelinedRunner, eng._executable
 
     def counted(*args, **kwargs):
         runners.append(runner(*args, **kwargs))
         return runners[-1]
 
     monkeypatch.setattr(engine_mod, "PipelinedRunner", counted)
-    monkeypatch.setattr(eng, "_compiled", lambda v, x: (
-        launches.append(x.shape), program(v, x))[1])
+    monkeypatch.setattr(eng, "_executable", lambda x: (
+        launches.append(x.shape), program(x))[1])
     x = rng.normal(size=(24, 6)).astype(np.float32)
     got = np.concatenate(list(eng.map_batches([x])))
     np.testing.assert_allclose(got, x @ mf.variables["w"], rtol=1e-5,

@@ -213,6 +213,18 @@ def test_one_trunk_block_program_compiles_for_v5e(v5e_chip, monkeypatch):
     engine's dispatch program (kernel paths on): it compiles, holds both
     kernels, and a dispatch of 8 rows x 4096 ids fits the chip beside
     its weights."""
+    _compile_one_trunk_block(v5e_chip, monkeypatch, rows=8)
+
+
+def test_one_trunk_block_program_compiles_for_v5e_at_the_tail_bucket(
+        v5e_chip, monkeypatch):
+    """The same at 4 rows: the bucket a job's tail of 4 rows dispatches
+    in since the engine pads a piece to the smallest bucket of its plan
+    (8 gives 2, 4, 8)."""
+    _compile_one_trunk_block(v5e_chip, monkeypatch, rows=4)
+
+
+def _compile_one_trunk_block(v5e_chip, monkeypatch, rows):
     import json
     import os
 
@@ -236,7 +248,7 @@ def test_one_trunk_block_program_compiles_for_v5e(v5e_chip, monkeypatch):
                                jax.random.PRNGKey(0))
     fn = hybrid_trunk.model_function(config, {}).fn
     compiled = build_dispatch_jit(fn, mesh, donate_batch=False).lower(
-        variables, jax.ShapeDtypeStruct((8, 4096), np.int32)).compile()
+        variables, jax.ShapeDtypeStruct((rows, 4096), np.int32)).compile()
     text = compiled.as_text()
     assert f"%{ssd.NAME}." in text and f"%{attention.NAME}." in text
     mem = compiled.memory_analysis()
