@@ -34,12 +34,9 @@ SEPCONV_DONATE_REASON = (
     "chained padded-flat activations; callers reuse the input "
     "(residual adds), so donation would corrupt the residual source")
 
-#: Canonical kernel audit shapes: Xception middle flow (sepconv), entry
-#: flow block under row tiling, MobileNetV2 inverted-residual tail.
+#: Canonical kernel audit shape: Xception's middle flow.
 _KERNEL_SHAPES = {
     "sepconv": dict(b=8, h=19, w=19, c=728, f=728),
-    "sepconv_tiled": dict(b=8, h=74, w=74, c=256, f=256, th=8),
-    "mbconv": dict(b=8, h=28, w=28, c=192, f=32),
 }
 
 
@@ -479,10 +476,10 @@ def train_step_specs(batch_rows: int = 32, feature_dim: int = 2048,
 
 
 def sepconv_kernel_specs() -> List[ProgramSpec]:
-    """The fused Pallas kernel jits (``ops/sepconv.py``) at their
-    canonical Xception/MobileNetV2 shapes, lowered through the pallas
-    INTERPRETER (``interpret=True``) so the fingerprint is chip-free.
-    No mesh/sharding (kernels shard through the caller's program) and a
+    """The fused Pallas kernel jit (``ops/sepconv.py``) at its canonical
+    Xception shape, lowered through the pallas INTERPRETER
+    (``interpret=True``) so the fingerprint is chip-free.  No
+    mesh/sharding (kernels shard through the caller's program) and a
     recorded donation exemption: the flat activations chain."""
 
     def build_sepconv():
@@ -502,57 +499,14 @@ def sepconv_kernel_specs() -> List[ProgramSpec]:
                         pre_relu=True, post_relu=False,
                         interpret=True), args
 
-    def build_tiled():
-        import jax
-        import jax.numpy as jnp
-
-        from sparkdl_tpu.ops.sepconv import (_fused_sepconv_tpu_tiled,
-                                             flat_rows, flat_width)
-
-        s = _KERNEL_SHAPES["sepconv_tiled"]
-        lo = flat_rows(s["h"], s["th"]) * flat_width(s["w"])
-        args = (jax.ShapeDtypeStruct((s["b"], lo, s["c"]), jnp.bfloat16),
-                jax.ShapeDtypeStruct((3, 3, s["c"]), jnp.bfloat16),
-                jax.ShapeDtypeStruct((s["c"], s["f"]), jnp.bfloat16),
-                jax.ShapeDtypeStruct((s["f"],), jnp.float32),
-                jax.ShapeDtypeStruct((s["f"],), jnp.float32))
-        return _Partial(_fused_sepconv_tpu_tiled, h=s["h"], w=s["w"],
-                        th=s["th"], pre_relu=True, post_relu=False,
-                        interpret=True), args
-
-    def build_mbconv():
-        import jax
-        import jax.numpy as jnp
-
-        from sparkdl_tpu.ops.sepconv import _fused_mbconv_tpu, flat_width
-
-        s = _KERNEL_SHAPES["mbconv"]
-        lo = (s["h"] + 2) * flat_width(s["w"])
-        args = (jax.ShapeDtypeStruct((s["b"], lo, s["c"]), jnp.bfloat16),
-                jax.ShapeDtypeStruct((3, 3, s["c"]), jnp.bfloat16),
-                jax.ShapeDtypeStruct((s["c"], s["f"]), jnp.bfloat16),
-                jax.ShapeDtypeStruct((s["c"],), jnp.float32),
-                jax.ShapeDtypeStruct((s["f"],), jnp.float32))
-        return _Partial(_fused_mbconv_tpu, h=s["h"], w=s["w"],
-                        interpret=True), args
-
     base = dict(kind="kernel", donate=(),
                 donate_reason=SEPCONV_DONATE_REASON,
                 compute_dtype="bfloat16")
     s1 = _KERNEL_SHAPES["sepconv"]
-    s2 = _KERNEL_SHAPES["sepconv_tiled"]
-    s3 = _KERNEL_SHAPES["mbconv"]
     return [
         ProgramSpec(name=f"kernel/sepconv/{s1['h']}x{s1['w']}x{s1['c']}",
                     build=build_sepconv, batch_rows=s1["b"],
                     group="kernel/sepconv", **base),
-        ProgramSpec(
-            name=f"kernel/sepconv_tiled/{s2['h']}x{s2['w']}x{s2['c']}",
-            build=build_tiled, batch_rows=s2["b"],
-            group="kernel/sepconv_tiled", **base),
-        ProgramSpec(name=f"kernel/mbconv/{s3['h']}x{s3['w']}x{s3['c']}",
-                    build=build_mbconv, batch_rows=s3["b"],
-                    group="kernel/mbconv", **base),
     ]
 
 

@@ -15,7 +15,7 @@ the environment provides them, otherwise architecture-faithful random init).
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import numpy as np
@@ -36,9 +36,6 @@ class ModelSpec:
     feature_size: int                          # featurizer-cut dimensionality
     preprocess_mode: str                       # see models.preprocess
     keras_app: str                             # keras.applications attr name
-    # () -> str tag when module_builder reads process env; caches keyed
-    # on the model name fold it in (model_variant_key).
-    variant_key_fn: Optional[Callable[[], str]] = None
 
     @property
     def preprocess(self):
@@ -185,37 +182,18 @@ def _populate():
     _registry.register(ModelSpec(
         name="VGG19", module_builder=VGG19, input_size=(224, 224),
         feature_size=4096, preprocess_mode="caffe", keras_app="VGG19"))
-    def _resnet_variant():
-        # one helper for the whole family: a second ResNet knob must
-        # change the tag for ResNet50/101/152 together (the InceptionV3
-        # combined-tag lesson)
-        return "fsc" if _rn_fused_shortcut_enabled() else ""
-
     # ResNet50 (reference) + deeper keras siblings (beyond the
     # reference's five): same module, deeper stage tables, same by-name
-    # importer and knobs.  SPARKDL_RN_FUSED_SHORTCUT=1 fuses each
-    # downsample block's shortcut+reduce 1x1 convs at inference
-    # (resnet.py); off until measured on hardware.
+    # importer.
     for _depth, _builder in ((50, ResNet50), (101, ResNet101),
                              (152, ResNet152)):
         _registry.register(ModelSpec(
-            name=f"ResNet{_depth}",
-            module_builder=(lambda b=_builder:
-                            b(fused_shortcut=_rn_fused_shortcut_enabled())),
+            name=f"ResNet{_depth}", module_builder=_builder,
             input_size=(224, 224), feature_size=2048,
-            preprocess_mode="caffe", keras_app=f"ResNet{_depth}",
-            variant_key_fn=_resnet_variant))
-    def _xception_builder():
-        # SPARKDL_XC_TILED=1 routes entry blocks 2-3 through the
-        # row-tiled pallas kernel — measured -24% whole-model, so the
-        # default keeps them on XLA (xception.py tiled_entry / PERF.md)
-        return Xception(tiled_entry=_xc_tiled_enabled())
-
+            preprocess_mode="caffe", keras_app=f"ResNet{_depth}"))
     _registry.register(ModelSpec(
-        name="Xception", module_builder=_xception_builder,
-        input_size=(299, 299),
-        feature_size=2048, preprocess_mode="tf", keras_app="Xception",
-        variant_key_fn=lambda: "tiled" if _xc_tiled_enabled() else ""),
+        name="Xception", module_builder=Xception, input_size=(299, 299),
+        feature_size=2048, preprocess_mode="tf", keras_app="Xception"),
         xception_auto_order)
     _registry.register(ModelSpec(
         name="InceptionV3", module_builder=InceptionV3,
@@ -224,17 +202,10 @@ def _populate():
         inception_import_order)
     # Beyond the reference's five: edge/efficiency-class backbones (see
     # mobilenet.py / efficientnet.py).
-    def _mobilenet_builder():
-        # SPARKDL_MNV2_FUSED=1 routes stride-1 inverted-residual tails
-        # through the fused pallas kernel (mobilenet.py); off until
-        # measured on hardware
-        return MobileNetV2(fused_inference=_mnv2_fused_enabled())
-
     _registry.register(ModelSpec(
-        name="MobileNetV2", module_builder=_mobilenet_builder,
+        name="MobileNetV2", module_builder=MobileNetV2,
         input_size=(224, 224), feature_size=1280, preprocess_mode="tf",
-        keras_app="MobileNetV2",
-        variant_key_fn=lambda: "fused" if _mnv2_fused_enabled() else ""))
+        keras_app="MobileNetV2"))
     # The input Normalization layer is auto-named by keras ("normalization",
     # "normalization_1", ... per session build count), so it imports by
     # creation order as a fallback when the by-name match misses.
@@ -255,37 +226,6 @@ SUPPORTED_MODELS = _registry.names()
 
 def get_model_spec(name: str) -> ModelSpec:
     return _registry.get(name)
-
-
-def _env_flag(name: str, default: bool) -> bool:
-    """Truthy env knob: unset or empty -> ``default``; "0"/"false" (any
-    case) -> False; anything else -> True."""
-    import os
-
-    raw = os.environ.get(name, "").lower()
-    if raw == "":
-        return default
-    return raw not in ("0", "false")
-
-
-def _xc_tiled_enabled() -> bool:
-    return _env_flag("SPARKDL_XC_TILED", False)
-
-
-def _rn_fused_shortcut_enabled() -> bool:
-    return _env_flag("SPARKDL_RN_FUSED_SHORTCUT", False)
-
-
-def _mnv2_fused_enabled() -> bool:
-    return _env_flag("SPARKDL_MNV2_FUSED", False)
-
-
-def model_variant_key(name: str) -> str:
-    """Environment-dependent build-variant tag for ``name`` (its spec's
-    ``variant_key_fn``).  Cache owners fold it into their keys, so that a
-    flag flipped mid-process rebuilds instead of serving the old build."""
-    spec = _registry.get(name)
-    return spec.variant_key_fn() if spec.variant_key_fn is not None else ""
 
 
 def import_keras_weights(name: str, keras_model, variables: dict) -> dict:

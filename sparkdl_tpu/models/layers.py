@@ -99,8 +99,7 @@ class SeparableConv2D(nn.Module):
                 h=fused_flat["h"], w=fused_flat["w"],
                 pre_relu=fused_flat.get("pre_relu", False),
                 post_relu=fused_flat.get("post_relu", False),
-                force=fused_flat.get("force"),
-                row_tile=fused_flat.get("row_tile"))
+                force=fused_flat.get("force"))
         dtype = self.dtype or x.dtype
         import jax.lax as lax
 
@@ -146,28 +145,18 @@ class BNAffine(nn.Module):
 
 
 class KernelParam(nn.Module):
-    """Variable-tree twin of ``nn.Conv``: declares the identical
-    ``kernel`` (and, with ``use_bias``, ``bias``) params — same names,
-    shapes, inits — and returns them instead of convolving.  Lets a
-    parent fuse several branch convs into one wider conv
-    (models/inception.py fused heads, models/resnet.py fused shortcut)
-    while keeping the per-branch variable tree interchangeable with the
-    plain path."""
+    """Variable-tree twin of a bias-free ``nn.Conv``: declares the
+    identical ``kernel`` param — same name, shape, init — and returns it
+    instead of convolving.  Lets a parent fuse several branch convs into
+    one wider conv (models/inception.py fused heads) while keeping the
+    per-branch variable tree interchangeable with the plain path."""
 
     shape: Tuple[int, ...]
-    use_bias: bool = False
-    # "depthwise_kernel" twins DepthwiseConv2D instead of nn.Conv
-    param_name: str = "kernel"
 
     @nn.compact
     def __call__(self):
-        kernel = self.param(self.param_name, nn.initializers.lecun_normal(),
-                            self.shape)
-        if not self.use_bias:
-            return kernel
-        bias = self.param("bias", nn.initializers.zeros,
-                          (self.shape[-1],))
-        return kernel, bias
+        return self.param("kernel", nn.initializers.lecun_normal(),
+                          self.shape)
 
 
 def fold_bn_into_conv(kernel, scale, shift, bias=None):
@@ -175,9 +164,8 @@ def fold_bn_into_conv(kernel, scale, shift, bias=None):
     ``(conv(x, k) + b) * s + t == conv(x, k*s) + (b*s + t)`` (conv is
     linear).  Returns ``(K, B)`` — K cast back to the kernel's dtype so a
     bf16 program stays bf16 (fold math in f32), B in f32 for the caller
-    to cast at the add.  Shared by every fused-conv path
-    (models/inception.py fused heads, models/resnet.py fused shortcut)
-    so precision/dtype fixes cannot diverge between them."""
+    to cast at the add (models/inception.py fused heads, which pass no
+    ``bias``: the zero's ``b*s`` stays in their traced program)."""
     K = (kernel.astype(jnp.float32) * scale).astype(kernel.dtype)
     b = bias.astype(jnp.float32) if bias is not None else jnp.float32(0)
     return K, b * scale + shift

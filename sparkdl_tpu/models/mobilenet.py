@@ -37,23 +37,11 @@ def _pad_correct(x):
 
 
 class MobileNetV2(nn.Module):
-    """``fused_inference``: stride-1 inverted-residual blocks run their
-    depthwise+BN+relu6+project+BN tail as ONE pallas kernel
-    (``ops/sepconv.py fused_mbconv_flat``) with the expand conv as a
-    masked matmul in the same PADDED-FLAT layout, so whole stride-1
-    stages chain with zero repacking (the Xception middle-flow pattern,
-    which measured +12%).  Identical math and variable tree
-    (KernelParam/BNAffine twins).  OFF by default until measured —
-    enable with ``SPARKDL_MNV2_FUSED=1`` (registry builder)."""
-
     num_classes: int = 1000
-    fused_inference: bool = False
 
     @nn.compact
     def __call__(self, x: jnp.ndarray, train: bool = False,
                  features: bool = False, logits: bool = False) -> jnp.ndarray:
-        fused = self.fused_inference and not train
-
         def bn(name):
             return nn.BatchNorm(use_running_average=not train,
                                 momentum=0.999, epsilon=1e-3, name=name)
@@ -64,61 +52,12 @@ class MobileNetV2(nn.Module):
                     use_bias=False, name="Conv1")(x)
         x = _relu6(bn("bn_Conv1")(x))
 
-        if fused:
-            from sparkdl_tpu.models.layers import (BNAffine, KernelParam,
-                                                   fold_bn_into_conv)
-            from sparkdl_tpu.ops.sepconv import (fused_mbconv_flat,
-                                                 halo_mask, pad_to_flat,
-                                                 unflatten)
-
-        xf = None  # padded-flat state for a run of stride-1 blocks
         block_id = 0
         for t, c, n, s in _BLOCKS:
             for i in range(n):
                 stride = s if i == 0 else 1
                 prefix = ("expanded_conv" if block_id == 0
                           else f"block_{block_id}")
-                if fused and stride == 1:
-                    if xf is None:
-                        h, w = x.shape[1], x.shape[2]
-                        work_dt = x.dtype
-                        xf = pad_to_flat(x, h, w)
-                        mask = halo_mask(h, w)
-                    cin = xf.shape[-1]
-                    inp_f = xf
-                    if t != 1:
-                        ke = KernelParam((1, 1, cin, cin * t),
-                                         name=f"{prefix}_expand")()
-                        se, te = BNAffine(epsilon=1e-3,
-                                          name=f"{prefix}_expand_BN")(
-                            cin * t)
-                        Ke, Be = fold_bn_into_conv(ke, se, te)
-                        y = xf.astype(Ke.dtype) @ Ke.reshape(cin, cin * t)
-                        y = (jnp.clip(y + Be.astype(y.dtype), 0.0, 6.0)
-                             * mask.astype(y.dtype))
-                    else:
-                        y = xf
-                    cdw = y.shape[-1]
-                    kd = KernelParam((3, 3, cdw, 1),
-                                     param_name="depthwise_kernel",
-                                     name=f"{prefix}_depthwise")()
-                    sd, td = BNAffine(epsilon=1e-3,
-                                      name=f"{prefix}_depthwise_BN")(cdw)
-                    Kd, Bd = fold_bn_into_conv(kd.reshape(3, 3, cdw),
-                                               sd, td)
-                    kp = KernelParam((1, 1, cdw, c),
-                                     name=f"{prefix}_project")()
-                    sp, tp = BNAffine(epsilon=1e-3,
-                                      name=f"{prefix}_project_BN")(c)
-                    Kp, Bp = fold_bn_into_conv(kp, sp, tp)
-                    yf = fused_mbconv_flat(y, Kd, Kp.reshape(cdw, c),
-                                           Bd, Bp, h, w).astype(work_dt)
-                    xf = yf + inp_f if cin == c else yf
-                    block_id += 1
-                    continue
-                if xf is not None:  # leaving a flat run (stride-2 block)
-                    x = unflatten(xf, h, w)
-                    xf = None
                 cin = x.shape[-1]
                 inp = x
                 if t != 1:
@@ -138,9 +77,6 @@ class MobileNetV2(nn.Module):
                 if stride == 1 and cin == c:
                     x = x + inp
                 block_id += 1
-        if xf is not None:
-            x = unflatten(xf, h, w)
-            xf = None
 
         x = nn.Conv(1280, (1, 1), use_bias=False, name="Conv_1")(x)
         x = _relu6(bn("Conv_1_bn")(x))

@@ -31,63 +31,27 @@ def _bn(name: str, train: bool) -> nn.BatchNorm:
 
 class BottleneckBlock(nn.Module):
     """Keras ``residual_block_v1``: 1x1 -> 3x3 -> 1x1 with a (possibly
-    projected) shortcut; stride lives on the first 1x1 conv (classic v1).
-
-    ``fused_shortcut``: at inference, downsample blocks run the 1x1
-    projection shortcut and the 1x1 reduce conv — which read the SAME
-    input at the SAME stride — as ONE wider conv (kernels/biases
-    concatenated along output channels, inference BN folded in), then
-    split.  Identical math and variable tree (``KernelParam``/
-    ``BNAffine`` twins — the pattern that bought +8.6% on InceptionV3's
-    branch heads)."""
+    projected) shortcut; stride lives on the first 1x1 conv (classic v1)."""
 
     filters: int
     stride: int = 1
     conv_shortcut: bool = True
     prefix: str = ""
-    fused_shortcut: bool = False
 
     @nn.compact
     def __call__(self, x: jnp.ndarray, train: bool = False) -> jnp.ndarray:
-        from sparkdl_tpu.models.layers import (BNAffine, KernelParam,
-                                               fold_bn_into_conv)
-
         p = self.prefix
-        f4 = 4 * self.filters
-        if self.conv_shortcut and self.fused_shortcut and not train:
-            cin = x.shape[-1]
-            k0, b0 = KernelParam((1, 1, cin, f4), use_bias=True,
-                                 name=f"{p}_0_conv")()
-            s0, t0 = BNAffine(epsilon=BN_EPS, name=f"{p}_0_bn")(f4)
-            k1, b1 = KernelParam((1, 1, cin, self.filters), use_bias=True,
-                                 name=f"{p}_1_conv")()
-            s1, t1 = BNAffine(epsilon=BN_EPS, name=f"{p}_1_bn")(
-                self.filters)
-            K0, B0 = fold_bn_into_conv(k0, s0, t0, bias=b0)
-            K1, B1 = fold_bn_into_conv(k1, s1, t1, bias=b1)
-            kdt = K0.dtype
-            K = jnp.concatenate([K0, K1], axis=-1)
-            B = jnp.concatenate([B0, B1])
-            import jax.lax as lax
-
-            z = lax.conv_general_dilated(
-                x.astype(kdt), K, (self.stride, self.stride), "SAME",
-                dimension_numbers=("NHWC", "HWIO", "NHWC"))
-            z = (z + B.astype(z.dtype)).astype(x.dtype)
-            shortcut = z[..., :f4]
-            y = nn.relu(z[..., f4:])
+        if self.conv_shortcut:
+            shortcut = nn.Conv(4 * self.filters, (1, 1),
+                               strides=(self.stride, self.stride),
+                               name=f"{p}_0_conv")(x)
+            shortcut = _bn(f"{p}_0_bn", train)(shortcut)
         else:
-            if self.conv_shortcut:
-                shortcut = nn.Conv(f4, (1, 1),
-                                   strides=(self.stride, self.stride),
-                                   name=f"{p}_0_conv")(x)
-                shortcut = _bn(f"{p}_0_bn", train)(shortcut)
-            else:
-                shortcut = x
-            y = nn.Conv(self.filters, (1, 1),
-                        strides=(self.stride, self.stride),
-                        name=f"{p}_1_conv")(x)
-            y = nn.relu(_bn(f"{p}_1_bn", train)(y))
+            shortcut = x
+        y = nn.Conv(self.filters, (1, 1),
+                    strides=(self.stride, self.stride),
+                    name=f"{p}_1_conv")(x)
+        y = nn.relu(_bn(f"{p}_1_bn", train)(y))
         y = nn.Conv(self.filters, (3, 3), padding="SAME",
                     name=f"{p}_2_conv")(y)
         y = nn.relu(_bn(f"{p}_2_bn", train)(y))
@@ -111,10 +75,6 @@ class ResNet50(nn.Module):
 
     num_classes: int = 1000
     stages: Tuple[Tuple[int, int, int], ...] = RESNET_STAGES[50]
-    # fuse each downsample block's shortcut+reduce 1x1s at inference
-    # (BottleneckBlock docstring); OFF until measured on hardware —
-    # enable with SPARKDL_RN_FUSED_SHORTCUT=1 (registry builder)
-    fused_shortcut: bool = False
 
     @nn.compact
     def __call__(self, x: jnp.ndarray, train: bool = False,
@@ -131,7 +91,6 @@ class ResNet50(nn.Module):
                     stride=stride if b == 1 else 1,
                     conv_shortcut=(b == 1),
                     prefix=f"conv{stage_idx}_block{b}",
-                    fused_shortcut=self.fused_shortcut,
                     name=f"conv{stage_idx}_block{b}")(x, train=train)
         x = global_avg_pool(x)  # 2048-d featurizer cut
         if features:

@@ -40,9 +40,9 @@ def xception_auto_order():
     return order
 
 
-def _pick_row_tile(h: int, w: int, channels: int):
-    """Row tile when the whole-image padded-flat working set would exceed
-    VMEM; None = whole-image kernel.  Budget calibrated on hardware: 37^2
+def _fits_vmem(h: int, w: int, channels: int) -> bool:
+    """Whether the whole-image padded-flat working set fits VMEM, so the
+    kernel can take the block.  Budget calibrated on hardware: 37^2
     x 728ch (1.14M position-channels, block4 at the native 299^2 input)
     compiles and runs; 74^2 x 256ch (1.56M) does not fit — so the
     threshold sits just above the known-good point and the decision
@@ -50,9 +50,7 @@ def _pick_row_tile(h: int, w: int, channels: int):
     non-299 input sizes too)."""
     from sparkdl_tpu.ops.sepconv import flat_width
 
-    if (h + 2) * flat_width(w) * channels <= 1_200_000:
-        return None
-    return 16
+    return (h + 2) * flat_width(w) * channels <= 1_200_000
 
 
 class Xception(nn.Module):
@@ -66,13 +64,6 @@ class Xception(nn.Module):
 
     num_classes: int = 1000
     fused_inference: Optional[bool] = None
-    # entry blocks 2-3 (147^2/74^2) through the ROW-TILED kernel
-    # (ops/sepconv.py).  Measured round 5 and retired: whole-model -24%
-    # (2341 vs 3086 img/s) — the pad/unflatten repacking around 2-layer
-    # blocks dominates, and XLA's own sepconv lowering at 147^2 is within
-    # 3% of the kernel per-layer (PERF.md "Row-tiled sepconv").  Kept
-    # off-by-default behind SPARKDL_XC_TILED=1 with parity tests.
-    tiled_entry: bool = False
 
     def _use_fused(self, train: bool) -> bool:
         if train:
@@ -122,12 +113,11 @@ class Xception(nn.Module):
             return nn.relu(y) if relu else y
 
         def sep(x, filters, name, pre_relu=False, post_relu=False,
-                flat_hw=None, row_tile=None):
+                flat_hw=None):
             """sepconv + BN (+ neighboring ReLUs).  When ``fused`` and a
-            ``flat_hw`` is given, x is PADDED-FLAT [N,rows*Wp,C] and the
-            whole stack runs as one pallas kernel (``row_tile`` selects
-            the row-tiled variant for VMEM-oversized spatial shapes);
-            otherwise the plain NHWC conv/BN modules run (XLA path)."""
+            ``flat_hw`` is given, x is PADDED-FLAT [N,(H+2)*Wp,C] and the
+            whole stack runs as one pallas kernel; otherwise the plain
+            NHWC conv/BN modules run (XLA path)."""
             if fused and flat_hw is not None:
                 s, t = BNAffine(epsilon=1e-3, name=f"{name}_bn")(filters)
                 h, w = flat_hw
@@ -135,8 +125,7 @@ class Xception(nn.Module):
                                        name=name)(
                     x, fused_flat=dict(scale=s, shift=t, h=h, w=w,
                                        pre_relu=pre_relu,
-                                       post_relu=post_relu,
-                                       row_tile=row_tile))
+                                       post_relu=post_relu))
             if pre_relu:
                 x = nn.relu(x)
             x = SeparableConv2D(filters, (3, 3), use_bias=False, name=name)(x)
@@ -155,23 +144,20 @@ class Xception(nn.Module):
         x = bn_act(x, "block1_conv2_bn", relu=True)
 
         # Entry-flow residual blocks (block2 has no leading relu — upstream
-        # quirk preserved).  Fused mode routes ALL entry blocks through
-        # the kernel: block4 (37x37) fits VMEM whole; blocks 2-3 (147/74
-        # spatial — whose padded-flat working set exceeds VMEM) use the
-        # ROW-TILED kernel generation (ops/sepconv.py — VERDICT r4 #1).
+        # quirk preserved).  Fused mode routes block4 (37x37) through the
+        # kernel; blocks 2-3 (147/74 spatial), whose padded-flat working
+        # set exceeds VMEM, stay on XLA.
         for i, f in _ENTRY_BLOCKS:
             residual = nn.Conv(f, (1, 1), strides=(2, 2), padding="SAME",
                                use_bias=False, name=f"shortcut{i}_conv")(x)
             residual = bn_act(residual, f"shortcut{i}_bn")
             h, w = x.shape[1], x.shape[2]
-            needs_tile = _pick_row_tile(h, w, max(x.shape[-1], f))
-            use_flat = fused and (needs_tile is None or self.tiled_entry)
-            if use_flat:
-                xf = pad_to_flat(x, h, w, row_tile=needs_tile)
+            if fused and _fits_vmem(h, w, max(x.shape[-1], f)):
+                xf = pad_to_flat(x, h, w)
                 xf = sep(xf, f, f"block{i}_sepconv1", pre_relu=i > 2,
-                         flat_hw=(h, w), row_tile=needs_tile)
+                         flat_hw=(h, w))
                 xf = sep(xf, f, f"block{i}_sepconv2", pre_relu=True,
-                         flat_hw=(h, w), row_tile=needs_tile)
+                         flat_hw=(h, w))
                 x = unflatten(xf, h, w)
             else:
                 x = sep(x, f, f"block{i}_sepconv1", pre_relu=i > 2)
@@ -182,7 +168,7 @@ class Xception(nn.Module):
         # Middle flow: 8 identity blocks of three sepconvs.  In fused mode
         # the whole flow CHAINS in padded-flat layout — the kernel's output
         # halo contract means zero repacking passes between the 24 layers.
-        mid_fits = _pick_row_tile(x.shape[1], x.shape[2], 728) is None
+        mid_fits = _fits_vmem(x.shape[1], x.shape[2], 728)
         if fused and mid_fits:
             h, w = x.shape[1], x.shape[2]
             xf = pad_to_flat(x, h, w)
@@ -206,7 +192,7 @@ class Xception(nn.Module):
                            use_bias=False, name="shortcut13_conv")(x19)
         residual = bn_act(residual, "shortcut13_bn")
         h, w = x19.shape[1], x19.shape[2]
-        if fused and mid_fits and _pick_row_tile(h, w, 1024) is None:
+        if fused and mid_fits and _fits_vmem(h, w, 1024):
             xf = sep(xf, 728, "block13_sepconv1", pre_relu=True,
                      flat_hw=(h, w))
             xf = sep(xf, 1024, "block13_sepconv2", pre_relu=True,
@@ -218,7 +204,7 @@ class Xception(nn.Module):
         x = nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
         x = x + residual
 
-        if fused and _pick_row_tile(x.shape[1], x.shape[2], 2048) is None:
+        if fused and _fits_vmem(x.shape[1], x.shape[2], 2048):
             h = x.shape[1]
             xf = pad_to_flat(x, h, x.shape[2])
             xf = sep(xf, 1536, "block14_sepconv1", post_relu=True,

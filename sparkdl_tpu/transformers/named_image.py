@@ -22,7 +22,7 @@ import pyarrow as pa
 from sparkdl_tpu.frame import list_column, list_values_nbytes
 from sparkdl_tpu.image.io import arrowStructsToBatch
 from sparkdl_tpu.image.schema import imageArrayToStruct, imageSchema
-from sparkdl_tpu.models import get_model_spec, load_model, model_variant_key
+from sparkdl_tpu.models import get_model_spec, load_model
 from sparkdl_tpu.models.imagenet import decode_predictions
 from sparkdl_tpu.obs.trace import get_tracer
 from sparkdl_tpu.param.converters import SparkDLTypeConverters
@@ -39,7 +39,7 @@ logger = get_logger(__name__)
 # Process-wide caches: zoo weights load once, engines compile once per
 # (model, purpose, batch).  The analog of the reference broadcasting one
 # GraphDef per stage rather than per partition.
-_MODEL_CACHE: Dict[tuple, tuple] = {}
+_MODEL_CACHE: Dict[str, tuple] = {}
 _ENGINE_CACHE: Dict[tuple, InferenceEngine] = {}
 
 
@@ -49,11 +49,9 @@ def clear_model_caches():
 
 
 def _cached_model(name: str):
-    # key includes the env-dependent build variant (model_variant_key)
-    key = (name, model_variant_key(name))
-    if key not in _MODEL_CACHE:
-        _MODEL_CACHE[key] = load_model(name)
-    return _MODEL_CACHE[key]
+    if name not in _MODEL_CACHE:
+        _MODEL_CACHE[name] = load_model(name)
+    return _MODEL_CACHE[name]
 
 
 def set_zoo_model(name: str, module, variables) -> None:
@@ -63,7 +61,7 @@ def set_zoo_model(name: str, module, variables) -> None:
     through the one process cache this fills.  Engines built on the
     model's earlier weights are dropped."""
     name = get_model_spec(name).name
-    _MODEL_CACHE[(name, model_variant_key(name))] = (module, variables)
+    _MODEL_CACHE[name] = (module, variables)
     for key in [k for k in _ENGINE_CACHE if k[0] == name]:
         del _ENGINE_CACHE[key]
 
@@ -180,7 +178,7 @@ def _zoo_engine(name: str, featurize: bool, batch_size: int) -> InferenceEngine:
     f32 end-to-end and the parity oracles are f32.
     """
     cdt_name = zoo_compute_dtype_name()
-    key = (name, model_variant_key(name), featurize, batch_size, cdt_name)
+    key = (name, featurize, batch_size, cdt_name)
     eng = _ENGINE_CACHE.get(key)
     if eng is None:
         import jax.numpy as jnp

@@ -73,7 +73,7 @@ def tiny_resnet(monkeypatch):
     from sparkdl_tpu.models import get_model_spec
     from sparkdl_tpu.transformers import named_image as ni
 
-    monkeypatch.setitem(ni._MODEL_CACHE, ("ResNet50", ""),
+    monkeypatch.setitem(ni._MODEL_CACHE, "ResNet50",
                         (_TinyZooModule(), {}))
     ni._ENGINE_CACHE.clear()
     yield get_model_spec("ResNet50")

@@ -219,7 +219,7 @@ def image_frame(tmp_path_factory):
 def _zoo_stage(cls, image_frame, monkeypatch):
     from sparkdl_tpu.transformers import named_image as ni
 
-    monkeypatch.setitem(ni._MODEL_CACHE, ("ResNet50", ""),
+    monkeypatch.setitem(ni._MODEL_CACHE, "ResNet50",
                         (_TinyZooModule(), {}))
     monkeypatch.setattr(ni, "_ENGINE_CACHE", {})
     stage = cls(inputCol="image", outputCol="out", modelName="ResNet50",

@@ -256,41 +256,3 @@ def test_inception_fused_heads_parity():
     b = np.asarray(jax.jit(lambda v, xx: fh.apply(
         v, xx, train=False, features=True))(v0, x))
     np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
-
-
-def test_resnet_fused_shortcut_parity(monkeypatch):
-    """ResNet50's fused shortcut+reduce conv (downsample blocks) is the
-    same function as the per-conv model on the same variables, with an
-    identical variable tree; the registry env knob gates and keys it."""
-    import jax
-
-    from sparkdl_tpu.models import get_model_spec, model_variant_key
-    from sparkdl_tpu.models.resnet import ResNet50
-
-    base = ResNet50(num_classes=4, fused_shortcut=False)
-    fused = ResNet50(num_classes=4, fused_shortcut=True)
-    rng = np.random.default_rng(5)
-    x = rng.normal(0, 1, size=(2, 96, 96, 3)).astype(np.float32)
-    v0 = jax.jit(lambda r, xx: base.init(r, xx, train=False))(
-        jax.random.PRNGKey(0), x)
-    v1 = jax.eval_shape(lambda: fused.init(jax.random.PRNGKey(0), x,
-                                           train=False))
-    assert (jax.tree_util.tree_structure(v0)
-            == jax.tree_util.tree_structure(v1))
-    a = np.asarray(jax.jit(lambda v, xx: base.apply(
-        v, xx, train=False, features=True))(v0, x))
-    b = np.asarray(jax.jit(lambda v, xx: fused.apply(
-        v, xx, train=False, features=True))(v0, x))
-    np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
-    # train mode takes the plain branch and updates batch_stats
-    out, mut = fused.apply(v0, x, train=True, features=True,
-                           mutable=["batch_stats"])
-    assert "batch_stats" in mut
-
-    spec = get_model_spec("ResNet50")
-    monkeypatch.delenv("SPARKDL_RN_FUSED_SHORTCUT", raising=False)
-    assert spec.build().fused_shortcut is False   # off until measured
-    assert model_variant_key("ResNet50") == ""
-    monkeypatch.setenv("SPARKDL_RN_FUSED_SHORTCUT", "1")
-    assert spec.build().fused_shortcut is True
-    assert model_variant_key("ResNet50") == "fsc"

@@ -22,6 +22,8 @@ SHAPES = [
     (19, 19, 32, 40),   # middle-flow class (728->728 at full scale)
     (10, 10, 24, 48),   # exit-flow class (post_relu)
     (12, 9, 16, 16),    # non-square, w+2 already a sublane multiple
+    (13, 11, 16, 16),   # odd height and width
+    (12, 9, 16, 24),    # non-square with c != f
 ]
 
 
@@ -105,156 +107,6 @@ def test_kernel_chain_interpreted(rng):
     r2 = sepconv_reference(r1, dwk2, pw2, s2, t2, True)
     np.testing.assert_allclose(got, np.asarray(r2, np.float32),
                                rtol=0.1, atol=0.08)
-
-
-TILED_SHAPES = [
-    (13, 11, 16, 16, 5),   # rows 15 = 3 tiles of 5
-    (19, 19, 32, 40, 7),   # rows 21 = 3 tiles of 7; c != f
-    (12, 9, 16, 24, 7),    # rows round UP 14 -> 21 (bottom pad rows)
-]
-
-
-@pytest.mark.parametrize("h,w,c,f,th", TILED_SHAPES)
-@pytest.mark.parametrize("pre_relu,post_relu", [(True, False),
-                                                (False, True)])
-def test_tiled_kernel_parity_interpreted(rng, h, w, c, f, th, pre_relu,
-                                         post_relu):
-    """The row-tiled kernel generation (VERDICT r4 #1: the 147^2/74^2
-    entry-flow shapes whose whole image exceeds VMEM) == jax reference,
-    including clamped edge-tile halos, rows rounded up to the tile, and
-    the zeroed-halo output contract."""
-    x = jnp.asarray(rng.normal(size=(2, h, w, c)), jnp.float32)
-    dwk, pw, scale, shift = _mats(rng, c, f)
-    xf = pad_to_flat(x, h, w, row_tile=th)
-    rows = xf.shape[1] // flat_width(w)
-    assert rows % th == 0 and rows >= h + 2
-    got_f = fused_sepconv_flat(xf, dwk, pw, scale, shift, h, w,
-                               pre_relu, post_relu, force="interpret",
-                               row_tile=th)
-    ref_f = fused_sepconv_flat(xf, dwk, pw, scale, shift, h, w,
-                               pre_relu, post_relu, force=False)
-    assert got_f.shape == ref_f.shape
-    got = np.asarray(unflatten(got_f, h, w), np.float32)
-    ref = np.asarray(unflatten(ref_f, h, w), np.float32)
-    np.testing.assert_allclose(got, ref, rtol=0.08, atol=0.05)
-    # halo/pad contract: everything outside the h x w interior is zero
-    wp = flat_width(w)
-    grid = np.asarray(got_f, np.float32).reshape(2, rows, wp, f)
-    assert np.all(grid[:, 0] == 0) and np.all(grid[:, h + 1:] == 0)
-    assert np.all(grid[:, :, 0] == 0) and np.all(grid[:, :, w + 1:] == 0)
-
-
-def test_tiled_kernel_chain_interpreted(rng):
-    """Chained tiled kernels with no repacking == two reference layers —
-    the entry-flow blocks' sepconv1 -> sepconv2 pattern."""
-    h, w, c, th = 13, 13, 16, 5
-    x = jnp.asarray(rng.normal(size=(2, h, w, c)), jnp.float32)
-    dwk1, pw1, s1, t1 = _mats(rng, c, c)
-    dwk2, pw2, s2, t2 = _mats(rng, c, c)
-    xf = pad_to_flat(x, h, w, row_tile=th)
-    a = fused_sepconv_flat(xf, dwk1, pw1, s1, t1, h, w, False, False,
-                           force="interpret", row_tile=th)
-    b = fused_sepconv_flat(a, dwk2, pw2, s2, t2, h, w, True, False,
-                           force="interpret", row_tile=th)
-    got = np.asarray(unflatten(b, h, w), np.float32)
-    r1 = sepconv_reference(x, dwk1, pw1, s1, t1, False)
-    r2 = sepconv_reference(r1, dwk2, pw2, s2, t2, True)
-    np.testing.assert_allclose(got, np.asarray(r2, np.float32),
-                               rtol=0.1, atol=0.08)
-
-
-@pytest.mark.parametrize("h,w,c,f", [(13, 11, 16, 24), (14, 14, 48, 32)])
-def test_mbconv_kernel_parity_interpreted(rng, h, w, c, f):
-    """The fused MobileNet inverted-residual tail kernel (depthwise ->
-    +BN-shift -> relu6 -> 1x1 project -> +BN-shift, scales pre-folded)
-    == the jax reference, incl. the zero-halo output contract."""
-    from sparkdl_tpu.ops.sepconv import fused_mbconv_flat
-
-    x = jnp.asarray(rng.normal(size=(2, h, w, c)), jnp.float32)
-    dwk = jnp.asarray(rng.normal(0, 0.3, (3, 3, c)), jnp.float32)
-    pw = jnp.asarray(rng.normal(0, 0.1, (c, f)), jnp.float32)
-    mid = jnp.asarray(rng.normal(0, 0.5, (c,)), jnp.float32)
-    sh = jnp.asarray(rng.normal(0, 0.2, (f,)), jnp.float32)
-    xf = pad_to_flat(x, h, w)
-    got_f = fused_mbconv_flat(xf, dwk, pw, mid, sh, h, w,
-                              force="interpret")
-    ref_f = fused_mbconv_flat(xf, dwk, pw, mid, sh, h, w, force=False)
-    got = np.asarray(unflatten(got_f, h, w), np.float32)
-    ref = np.asarray(unflatten(ref_f, h, w), np.float32)
-    np.testing.assert_allclose(got, ref, rtol=0.08, atol=0.05)
-    wp = flat_width(w)
-    grid = np.asarray(got_f, np.float32).reshape(2, h + 2, wp, f)
-    assert np.all(grid[:, 0] == 0) and np.all(grid[:, -1] == 0)
-    assert np.all(grid[:, :, 0] == 0) and np.all(grid[:, :, w + 1:] == 0)
-
-
-def test_mobilenet_fused_matches_unfused(rng, monkeypatch):
-    """Model-level parity for MobileNetV2(fused_inference=True): the
-    flat-stage chaining (masked expand matmul + fused tail + residuals in
-    flat layout) matches the plain module from the same variables, with
-    an identical variable tree; the registry env knob gates and keys
-    the variant."""
-    import jax
-
-    from sparkdl_tpu.models import get_model_spec, model_variant_key
-    from sparkdl_tpu.models.mobilenet import MobileNetV2
-
-    x = jnp.asarray(rng.random((2, 96, 96, 3)) * 2 - 1, jnp.float32)
-    m0 = MobileNetV2(num_classes=5, fused_inference=False)
-    m1 = MobileNetV2(num_classes=5, fused_inference=True)
-    v0 = m0.init(jax.random.PRNGKey(0), x, train=False)
-    v1 = jax.eval_shape(lambda: m1.init(jax.random.PRNGKey(0), x,
-                                        train=False))
-    assert (jax.tree_util.tree_structure(v0)
-            == jax.tree_util.tree_structure(v1))
-    a = np.asarray(m0.apply(v0, x, train=False, features=True))
-    b = np.asarray(m1.apply(v0, x, train=False, features=True))
-    np.testing.assert_allclose(b, a, rtol=0.05, atol=0.02)
-    # train mode takes the plain branch (BN needs batch stats)
-    out, mut = m1.apply(v0, x, train=True, features=True,
-                        mutable=["batch_stats"])
-    assert "batch_stats" in mut
-
-    spec = get_model_spec("MobileNetV2")
-    monkeypatch.delenv("SPARKDL_MNV2_FUSED", raising=False)
-    assert spec.build().fused_inference is False  # off until measured
-    assert model_variant_key("MobileNetV2") == ""
-    monkeypatch.setenv("SPARKDL_MNV2_FUSED", "1")
-    assert spec.build().fused_inference is True
-    assert model_variant_key("MobileNetV2") == "fused"
-
-
-def test_xception_tiled_entry_wiring(rng, monkeypatch):
-    """Model-level wiring of the row-tiled entry path: with
-    ``tiled_entry=True`` the entry blocks route through
-    ``pad_to_flat(row_tile=...)`` and still match the plain module graph
-    from the same variables, and the registry env gate builds/keys the
-    variant.  (Kernel math itself is parity-pinned in the tiled-kernel
-    tests; on CPU this exercises the flat plumbing via the reference
-    fallback, including the rounded-rows layout.)"""
-    import jax
-
-    from sparkdl_tpu.models import get_model_spec, model_variant_key
-    from sparkdl_tpu.models.xception import Xception, _pick_row_tile
-
-    # the 224x224 input makes block2 h=111 exceed the VMEM budget, so the
-    # tiled path (rows rounded up to the tile) actually engages
-    assert _pick_row_tile(111, 111, 128) is not None
-    x = jnp.asarray(rng.random((1, 224, 224, 3)) * 2 - 1, jnp.float32)
-    m0 = Xception(num_classes=3, fused_inference=False)
-    m1 = Xception(num_classes=3, fused_inference=True, tiled_entry=True)
-    v0 = m0.init(jax.random.PRNGKey(0), x, train=False)
-    f0 = np.asarray(m0.apply(v0, x, train=False, features=True))
-    f1 = np.asarray(m1.apply(v0, x, train=False, features=True))
-    np.testing.assert_allclose(f1, f0, rtol=0.05, atol=0.02)
-
-    spec = get_model_spec("Xception")
-    monkeypatch.delenv("SPARKDL_XC_TILED", raising=False)
-    assert spec.build().tiled_entry is False      # retired: off by default
-    assert model_variant_key("Xception") == ""
-    monkeypatch.setenv("SPARKDL_XC_TILED", "1")
-    assert spec.build().tiled_entry is True
-    assert model_variant_key("Xception") == "tiled"
 
 
 def test_xception_fused_matches_unfused(rng):

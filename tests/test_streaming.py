@@ -51,7 +51,7 @@ def fake_resnet(monkeypatch):
                 dim, dtype=jnp.float32)[None, :] * 1e-4
 
     spec = get_model_spec("ResNet50")
-    monkeypatch.setitem(ni._MODEL_CACHE, ("ResNet50", ""), (_Tiny(), {}))
+    monkeypatch.setitem(ni._MODEL_CACHE, "ResNet50", (_Tiny(), {}))
     ni._ENGINE_CACHE.clear()
     yield spec
     ni._ENGINE_CACHE.clear()

@@ -1,8 +1,11 @@
-"""The batch path has one route, and the environment cannot fork it.
+"""The batch path has one route, each zoo model one build, and the
+environment cannot fork them.
 
-Four switches used to (``SPARKDL_S2D_STEM``, ``SPARKDL_FUSED_HEADS``,
-``SPARKDL_PIPELINE``, ``SPARKDL_BATCHES_PER_DISPATCH``): set, they are
-now ignored, and the names the package still reads are listed here, so
+Four switches used to fork the batch path (``SPARKDL_S2D_STEM``,
+``SPARKDL_FUSED_HEADS``, ``SPARKDL_PIPELINE``,
+``SPARKDL_BATCHES_PER_DISPATCH``) and three more the builds of
+Xception, MobileNetV2 and ResNet50 (``REMOVED``): set, they are now
+ignored, and the names the package still reads are listed here, so
 that the next one is a deliberate edit.
 """
 
@@ -14,7 +17,7 @@ import numpy as np
 import pytest
 
 from sparkdl_tpu.graph.function import ModelFunction
-from sparkdl_tpu.models import get_model_spec, model_variant_key
+from sparkdl_tpu.models import get_model_spec
 from sparkdl_tpu.parallel import engine as engine_mod
 from sparkdl_tpu.parallel.engine import get_cached_engine
 from sparkdl_tpu.transformers.named_image import zoo_model_fn
@@ -22,17 +25,21 @@ from sparkdl_tpu.transformers.named_image import zoo_model_fn
 PACKAGE = pathlib.Path(__file__).resolve().parent.parent / "sparkdl_tpu"
 
 
-def _inception_jaxpr() -> str:
-    """The featurizer's program as the zoo engine would compile it, over
-    an abstract batch: no weights, no device."""
+def _zoo_jaxpr(model: str) -> str:
+    """A zoo featurizer's program as the zoo engine would compile it,
+    over an abstract batch: no weights, no device."""
     import jax
 
-    spec = get_model_spec("InceptionV3")
+    spec = get_model_spec(model)
     h, w = spec.input_size
-    fn = zoo_model_fn("InceptionV3", featurize=True)
+    fn = zoo_model_fn(model, featurize=True)
     return str(jax.make_jaxpr(fn)(
         spec.abstract_variables(),
         jax.ShapeDtypeStruct((2, h, w, 3), np.uint8)))
+
+
+def _inception_jaxpr() -> str:
+    return _zoo_jaxpr("InceptionV3")
 
 
 @pytest.fixture(scope="module")
@@ -41,9 +48,19 @@ def default_jaxpr():
 
 
 def _model_is_the_defaults(monkeypatch, default_jaxpr):
-    assert model_variant_key("InceptionV3") == ""
-    assert get_model_spec("InceptionV3").variant_key_fn is None
     assert _inception_jaxpr() == default_jaxpr
+
+
+def _builds_the_default(model):
+    """The check for a switch that forked one zoo model's build: set, the
+    registry builds the same module, and the featurizer traces the same
+    program, as with every removed name unset."""
+    def check(monkeypatch, default_jaxpr):
+        built = get_model_spec(model).build(), _zoo_jaxpr(model)
+        for name, _, _ in REMOVED:
+            monkeypatch.delenv(name, raising=False)
+        assert built == (get_model_spec(model).build(), _zoo_jaxpr(model))
+    return check
 
 
 def _engine_is_the_defaults(monkeypatch, default_jaxpr):
@@ -82,6 +99,9 @@ REMOVED = [
     ("SPARKDL_FUSED_HEADS", "0", _model_is_the_defaults),
     ("SPARKDL_PIPELINE", "0", _engine_is_the_defaults),
     ("SPARKDL_BATCHES_PER_DISPATCH", "3", _engine_is_the_defaults),
+    ("SPARKDL_XC_TILED", "1", _builds_the_default("Xception")),
+    ("SPARKDL_MNV2_FUSED", "1", _builds_the_default("MobileNetV2")),
+    ("SPARKDL_RN_FUSED_SHORTCUT", "1", _builds_the_default("ResNet50")),
 ]
 
 
@@ -116,9 +136,6 @@ ENVIRONMENT = {
     "SPARKDL_ZOO_COMPUTE_DTYPE": "precision: float32 is the product's "
                                  "contract, bfloat16 the benchmark's cell",
     # debts with a date (ROADMAP.md, Design queue)
-    "SPARKDL_XC_TILED": "debt D3: a recorded loss, leaves with its kernel",
-    "SPARKDL_MNV2_FUSED": "debt D3: never measured, leaves with its kernel",
-    "SPARKDL_RN_FUSED_SHORTCUT": "debt D3: never measured",
     "SPARKDL_RAGGED": "debt D4: waits for the serving cells",
 }
 

@@ -7,11 +7,10 @@ Mosaic's refusals — a slice off the tiling, a block over the VMEM
 budget — fail here, in tier-1, instead of in the first chip run.
 Interpret-mode parity (``tests/test_ops_sepconv.py``) cannot see them.
 
-The shapes are not guessed: each model is traced abstractly with the
-kernel entry points replaced by recorders, so the cases ARE the calls
-the zoo makes (Xception's default program, its row-tiled entry variant,
-MobileNetV2's fused tail).  Nothing runs, so nothing here says anything
-about results or speed.
+The shapes are not guessed: Xception is traced abstractly with the
+kernel entry point replaced by a recorder, so the cases ARE the calls
+the zoo makes.  Nothing runs, so nothing here says anything about
+results or speed.
 """
 
 import os
@@ -28,8 +27,7 @@ from sparkdl_tpu.ops import sepconv
 
 BATCH = 32  # the audited serving plan's largest bucket
 
-_KERNELS = ("_fused_sepconv_tpu", "_fused_sepconv_tpu_tiled",
-            "_fused_mbconv_tpu")
+_KERNELS = ("_fused_sepconv_tpu",)
 
 
 def _trace_kernel_calls(module, input_hw):
@@ -67,15 +65,18 @@ def _trace_kernel_calls(module, input_hw):
 
 
 def _zoo_kernel_cases():
-    from sparkdl_tpu.models.mobilenet import MobileNetV2
+    """Each distinct shape of Xception's kernel calls, at both input
+    dtypes a call can bring: float32 (the model's activations at the
+    engine's default) and bfloat16 (a kernel's own output, or the
+    activations under a bfloat16 compute dtype)."""
     from sparkdl_tpu.models.xception import Xception
 
     cases = []
-    for module, hw in ((Xception(fused_inference=True), (299, 299)),
-                       (Xception(fused_inference=True, tiled_entry=True),
-                        (299, 299)),
-                       (MobileNetV2(fused_inference=True), (224, 224))):
-        for case in _trace_kernel_calls(module, hw):
+    for name, shapes, static, kw in _trace_kernel_calls(
+            Xception(fused_inference=True), (299, 299)):
+        (xf_shape, _), rest = shapes[0], shapes[1:]
+        for dtype in ("float32", "bfloat16"):
+            case = (name, ((xf_shape, dtype),) + rest, static, kw)
             if case not in cases:
                 cases.append(case)
     return cases
@@ -115,12 +116,12 @@ def v5e_chip():
 
 
 def test_enumeration_covers_every_kernel():
-    """The recorders saw all three kernels, at the zoo's count of
-    distinct shapes (a model edit that drops the kernel path would
+    """The recorder saw the kernel at Xception's 6 distinct shapes, each
+    at two input dtypes (a model edit that drops the kernel path would
     silently shrink the parametrisation below)."""
     names = [c[0] for c in KERNEL_CASES]
     assert set(names) == set(_KERNELS)
-    assert len(KERNEL_CASES) >= 17
+    assert len(KERNEL_CASES) == 12
 
 
 @pytest.mark.parametrize("case", KERNEL_CASES, ids=_case_id)

@@ -43,7 +43,7 @@ class _TinyZooModule:
 def fake_resnet(monkeypatch):
     spec = get_model_spec("ResNet50")
     module = _TinyZooModule(feature_size=spec.feature_size)
-    monkeypatch.setitem(ni._MODEL_CACHE, ("ResNet50", ""), (module, {}))
+    monkeypatch.setitem(ni._MODEL_CACHE, "ResNet50", (module, {}))
     # engines cache per (name, featurize, batch) — clear so the fake is used
     ni._ENGINE_CACHE.clear()
     yield spec
